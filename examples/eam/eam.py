@@ -26,9 +26,9 @@ import numpy as np
 _here = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(os.path.dirname(_here)))  # repo root
 
-from hydragnn_tpu.utils.platform import pin_platform_from_env
+from hydragnn_tpu.utils.platform import place_compile_cache
 
-pin_platform_from_env()  # honor JAX_PLATFORMS even under plugin images
+place_compile_cache()
 
 from hydragnn_tpu.api import create_dataloaders, train_with_loaders
 from hydragnn_tpu.data.container import ContainerDataset, ContainerWriter

@@ -136,9 +136,9 @@ def main(argv=None) -> int:
     parser.add_argument("--lr", type=float, default=0.02)
     args = parser.parse_args(argv)
 
-    from hydragnn_tpu.utils.platform import pin_platform_from_env
+    from hydragnn_tpu.utils.platform import place_compile_cache
 
-    pin_platform_from_env()
+    place_compile_cache()
     import jax
 
     from hydragnn_tpu.train import create_train_state, make_train_step, select_optimizer

@@ -24,9 +24,9 @@ _here = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, _here)
 sys.path.insert(0, os.path.dirname(os.path.dirname(_here)))  # repo root (no-install runs)
 
-from hydragnn_tpu.utils.platform import pin_platform_from_env
+from hydragnn_tpu.utils.platform import place_compile_cache
 
-pin_platform_from_env()  # honor JAX_PLATFORMS even under plugin images
+place_compile_cache()
 from create_configurations import create_dataset
 
 import hydragnn_tpu

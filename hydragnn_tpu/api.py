@@ -42,6 +42,7 @@ from hydragnn_tpu.utils.config import (
     save_config,
     update_config,
 )
+from hydragnn_tpu.utils.platform import check_backend
 from hydragnn_tpu.utils.print_utils import setup_log
 from hydragnn_tpu.utils.time_utils import Timer, print_timers
 
@@ -326,6 +327,10 @@ def run_training(
     All of it is inert under ``HYDRAGNN_TELEMETRY=0``."""
     config = load_config(config_file_or_dict)
     verbosity = config.get("Verbosity", {}).get("level", 0)
+    # the typed error (not a raw jax traceback) when the backend cannot
+    # come up or is not the one JAX_PLATFORMS names: a supervised child
+    # whose chip another process holds then fails fast (run_guard)
+    check_backend()
 
     timer = Timer("total_training")
     timer.start()

@@ -13,10 +13,9 @@ run:
   findings. ``flight.py`` is stdlib-only by design, so it is loaded
   standalone (``importlib``, no package import, no jax init).
 
-* **Machine JSON artifacts** (``BENCH_r*.json``, ``SCALING_*.json``,
-  ``MULTICHIP_*.json``, ``TUNE_TILES.json``,
-  ``BENCH_CI_BASELINE.json``) — per-kind schemas below
-  (``MACHINE_SCHEMAS``), derived from the writers (bench.py,
+* **Machine JSON artifacts** (``SCALING_*.json``, ``TUNE_TILES.json``,
+  ``BENCH_CI_BASELINE.json``, ``BENCH_FLEET.json``) — per-kind schemas
+  below (``MACHINE_SCHEMAS``), derived from the writers (
   tools/estimate_scaling.py, tools/tune_tiles.py, tools/bench_ci.py).
   The checks pin the fields downstream tools actually read; extra keys
   stay legal so a writer can grow its record without a lint dance.
@@ -62,47 +61,6 @@ def _require(data: Any, fields: Dict[str, tuple]) -> List[str]:
 _NUM = (int, float)
 
 
-def _check_bench(data: Any) -> List[str]:
-    """BENCH_r*.json: one TPU-attempt record (bench driver wrapper).
-    ``parsed`` is the bench.py metric block when the run got far enough
-    to print one, else null. A failed attempt (rc != 0) must be a
-    STRUCTURED failed-run record — ``status: "failed"``, the retry
-    count ``init_backend_with_retry`` burned, and a ``failure`` block
-    naming the stage and error type — not just a raw traceback tail
-    (BENCH_r05 is the committed example)."""
-    problems = _require(
-        data, {"n": (int,), "cmd": (str,), "rc": (int,), "tail": (str,)}
-    )
-    if problems:
-        return problems
-    parsed = data.get("parsed")
-    if parsed is not None:
-        if not isinstance(parsed, dict):
-            return [f"'parsed' is {type(parsed).__name__}, expected object or null"]
-        problems += [
-            f"parsed.{p}" for p in _require(
-                parsed, {"metric": (str,), "value": _NUM, "unit": (str,)}
-            )
-        ]
-    if data["rc"] != 0:
-        problems += _require(
-            data, {"status": (str,), "retries": (int,), "failure": (dict,)}
-        )
-        if isinstance(data.get("status"), str) and data["status"] != "failed":
-            problems.append(
-                f"rc={data['rc']} but status is {data['status']!r},"
-                " expected 'failed'"
-            )
-        if isinstance(data.get("failure"), dict):
-            problems += [
-                f"failure.{p}" for p in _require(
-                    data["failure"],
-                    {"stage": (str,), "error_type": (str,), "error": (str,)},
-                )
-            ]
-    return problems
-
-
 #: the five scenario rows bench_serve.py --fleet always records — a
 #: missing one means a chaos scenario silently did not run.
 _FLEET_SCENARIOS = (
@@ -142,20 +100,6 @@ def _check_fleet(data: Any) -> List[str]:
         if not isinstance(row, dict):
             problems.append(f"scenarios.{name} missing (chaos scenario not run)")
     return problems
-
-
-def _check_multichip(data: Any) -> List[str]:
-    """MULTICHIP_r*.json: one multi-chip attempt record."""
-    return _require(
-        data,
-        {
-            "n_devices": (int,),
-            "rc": (int,),
-            "ok": (bool,),
-            "skipped": (bool,),
-            "tail": (str,),
-        },
-    )
 
 
 def _check_scaling(data: Any) -> List[str]:
@@ -301,8 +245,6 @@ def _check_incident_manifest(data: Any) -> List[str]:
 #: Patterns with ZERO committed matches are themselves findings — these
 #: artifacts are evidence, and losing one silently is the failure mode.
 MACHINE_SCHEMAS: Dict[str, Tuple[str, Callable[[Any], List[str]]]] = {
-    "BENCH_r*.json": ("bench attempt record", _check_bench),
-    "MULTICHIP_r*.json": ("multi-chip attempt record", _check_multichip),
     "SCALING_*.json": ("scaling sweep/estimate", _check_scaling),
     "TUNE_TILES.json": ("kernel tile sweep", _check_tune_tiles),
     "BENCH_CI_BASELINE.json": ("CI perf baseline", _check_ci_baseline),

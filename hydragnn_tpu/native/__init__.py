@@ -10,6 +10,7 @@ fallback keeps every feature working where a compiler is unavailable;
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -32,16 +33,26 @@ _LOAD_FAILED = False
 HAVE_NATIVE = False
 
 
-def _build_library() -> Optional[str]:
-    so_path = os.path.join(_BUILD_DIR, "libhgc.so")
-    if os.path.exists(so_path) and all(
-        os.path.getmtime(so_path) >= os.path.getmtime(src) for src in _SRCS
-    ):
+def _source_digest() -> str:
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:16]
+
+
+def _build_library(build_dir: str = _BUILD_DIR) -> Optional[str]:
+    # The library's file name carries a hash of the sources it was built
+    # from: a stale binary, or one left on disk by another tree (the
+    # build dir is git-ignored, and a copied checkout keeps no mtimes),
+    # has a different name and is never loaded.
+    so_path = os.path.join(build_dir, f"libhgc-{_source_digest()}.so")
+    if os.path.exists(so_path):
         return so_path
-    os.makedirs(_BUILD_DIR, exist_ok=True)
+    os.makedirs(build_dir, exist_ok=True)
     # Build into a temp name + atomic rename: concurrent processes (pytest
     # workers, multi-process training) race to compile safely.
-    fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
+    fd, tmp = tempfile.mkstemp(suffix=".so", dir=build_dir)
     os.close(fd)
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
@@ -58,13 +69,22 @@ def _build_library() -> Optional[str]:
         return None
 
 
-def _load() -> Optional[ctypes.CDLL]:
+def load(build_dir: str = _BUILD_DIR) -> bool:
+    """Build (where it is not built yet) and bind the library now, and
+    say whether the native path is active. ``build_dir`` replaces
+    ``native/build`` for a caller that must not trust a binary lying in
+    the checkout (``chip_smoke.py`` names a fresh directory). No effect
+    once a library is bound or a build has failed."""
+    return _load(build_dir) is not None
+
+
+def _load(build_dir: str = _BUILD_DIR) -> Optional[ctypes.CDLL]:
     global _lib, _LOAD_FAILED, HAVE_NATIVE
     if _lib is not None:
         return _lib
     if _LOAD_FAILED:
         return None
-    so_path = _build_library()
+    so_path = _build_library(build_dir)
     if so_path is None:
         _LOAD_FAILED = True
         return None

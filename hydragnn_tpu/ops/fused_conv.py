@@ -80,11 +80,9 @@ from hydragnn_tpu.ops.segment_pallas import (
     BN,
     BW,
     CE,
-    _def_partition_compat,
     _interpret_mode,
     _kernel_eligible,
     _match_vma,
-    _sds,
     _vma_of,
     _window_plan_local,
     gather_rows_local_fast,
@@ -500,7 +498,7 @@ def _fused_kernel_call(x, senders, receivers, mask, w_cat, b_cat, rtab,
     operands = [_match_vma(o, vma) for o in operands]
     block_ptr = _match_vma(block_ptr, vma)
     plan = _match_vma(plan, vma)
-    out_sds = _sds((n_pad_out, hop), jnp.float32, vma=vma)
+    out_sds = jax.ShapeDtypeStruct((n_pad_out, hop), jnp.float32, vma=vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_blocks,),
@@ -589,8 +587,7 @@ def _get_partitioned_fused(layout: Tuple[str, ...]):
             parts.append(f"o{idx}")
         else:
             parts.append(f"p{idx} w{idx}")
-    _def_partition_compat(
-        op,
+    op.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule=", ".join(parts) + " -> n h",
@@ -1202,7 +1199,7 @@ def _stack_kernel_call(x, senders, receivers, mask, w_stack, b_stack,
     operands = [_match_vma(o, vma) for o in operands]
     block_ptr = _match_vma(block_ptr, vma)
     plan = _match_vma(plan, vma)
-    out_sds = _sds((n_pad_out, hp), jnp.float32, vma=vma)
+    out_sds = jax.ShapeDtypeStruct((n_pad_out, hp), jnp.float32, vma=vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_layers, n_blocks),

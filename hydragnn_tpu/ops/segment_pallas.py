@@ -82,40 +82,43 @@ from hydragnn_tpu.utils import knobs
 # cost of VMEM and wasted work on boundary blocks.
 
 
-def _tile_defaults() -> dict:
+_TUNE_TILES_PATH = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    "TUNE_TILES.json",
+)
+
+
+def _tile_defaults(path: str = _TUNE_TILES_PATH) -> dict:
     """Block/chunk defaults from the committed sweep table
     (``TUNE_TILES.json`` at the repo root, written by
     ``tools/tune_tiles.py --save``): ``{shape_tag: {device_kind:
     {"BN", "CE", "BCAST_CE"}}}``. Selection keys come from env —
     ``HYDRAGNN_TILE_SHAPE`` then ``HYDRAGNN_DEVICE_KIND``, each falling
     back to the table's ``"default"`` row — NOT from ``jax.devices()``:
-    importing this module must never trigger backend init ahead of the
-    platform pinning entry scripts rely on. The explicit
+    importing this module must never trigger backend init. The explicit
     ``HYDRAGNN_BN`` / ``HYDRAGNN_CE`` / ``HYDRAGNN_BCAST_CE`` env knobs
-    always win over the table; any read/parse failure falls back to the
-    baked r05-measured defaults, so a missing or mangled table can
-    never change kernel behavior."""
-    out = {"BN": 128, "CE": 512, "BCAST_CE": 1024}
-    try:
-        import json
+    always win over the table. A tree without the table (an installed
+    package) runs on the baked defaults and says so in ``"source"``; a
+    table that is there but cannot be read is an error, not a silent
+    change of tiles."""
+    import json
 
-        path = os.path.join(
-            os.path.dirname(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-            ),
-            "TUNE_TILES.json",
-        )
+    out = {"BN": 128, "CE": 512, "BCAST_CE": 1024, "source": "baked"}
+    try:
         with open(path) as f:
             table = json.load(f)
-        shape = knobs.get_str("HYDRAGNN_TILE_SHAPE", "default")
-        kind = knobs.get_str("HYDRAGNN_DEVICE_KIND", "default")
-        by_shape = table.get(shape) or table.get("default") or {}
-        entry = by_shape.get(kind) or by_shape.get("default") or {}
-        for k in out:
-            if k in entry:
-                out[k] = int(entry[k])
-    except Exception:
-        pass
+    except FileNotFoundError:
+        return out
+    except ValueError as exc:
+        raise ValueError(f"{path} is not valid JSON: {exc}") from exc
+    shape = knobs.get_str("HYDRAGNN_TILE_SHAPE", "default")
+    kind = knobs.get_str("HYDRAGNN_DEVICE_KIND", "default")
+    by_shape = table.get(shape) or table.get("default") or {}
+    entry = by_shape.get(kind) or by_shape.get("default") or {}
+    for k in ("BN", "CE", "BCAST_CE"):
+        if k in entry:
+            out[k] = int(entry[k])
+    out["source"] = "TUNE_TILES.json"
     return out
 
 
@@ -156,31 +159,20 @@ def xla_segment_ops():
 
 def _vma_of(*arrays) -> frozenset:
     """Union of the manual-mesh axes the given arrays vary over (empty
-    outside shard_map, and on jax versions without vma tracking)."""
-    from hydragnn_tpu.utils.jax_compat import typeof_vma
-
+    outside shard_map)."""
     out: frozenset = frozenset()
     for a in arrays:
-        out = out | typeof_vma(a)
+        out = out | jax.typeof(a).vma
     return out
 
 
 def _match_vma(x, vma: frozenset):
-    """Promote ``x`` to vary over ``vma`` (jax.lax.pvary) — constructed
-    operands (zero padding, window plans) otherwise arrive non-varying
-    inside shard_map with check_vma=True and fail the interpreter's
-    per-operand vma match. No-op on pre-vma jax."""
-    from hydragnn_tpu.utils.jax_compat import pvary, typeof_vma
-
-    return pvary(x, tuple(vma - typeof_vma(x)))
-
-
-def _sds(shape, dtype, vma: frozenset = frozenset()):
-    """ShapeDtypeStruct carrying vma where the jax version supports it
-    (utils/jax_compat.shape_dtype_struct)."""
-    from hydragnn_tpu.utils.jax_compat import shape_dtype_struct
-
-    return shape_dtype_struct(shape, dtype, vma)
+    """Promote ``x`` to vary over ``vma`` — constructed operands (zero
+    padding, window plans) otherwise arrive non-varying inside shard_map
+    with check_vma=True and fail the interpreter's per-operand vma
+    match."""
+    missing = tuple(vma - jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def pallas_available() -> bool:
@@ -422,7 +414,7 @@ def _csr_kernel_call(data, segment_ids, mask, num_segments, interpret, family):
     data = _match_vma(data, vma)
     recv = _match_vma(recv, vma)
     block_ptr = _match_vma(block_ptr, vma)
-    out_sds = _sds((n_pad, h), jnp.float32, vma=vma)
+    out_sds = jax.ShapeDtypeStruct((n_pad, h), jnp.float32, vma=vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
@@ -454,20 +446,6 @@ def _csr_kernel_call(data, segment_ids, mask, num_segments, interpret, family):
         ones, segment_ids, num_segments, indices_are_sorted=True
     )
     return outs[0][:num_segments], outs[1][:num_segments], cnt
-
-
-def _def_partition_compat(op, *, partition, infer_sharding_from_operands, sharding_rule):
-    """``def_partition`` across jax versions (utils/jax_compat): the
-    shardy ``sharding_rule`` spec only exists on newer jax; 0.4.x takes
-    the same partition/infer pair and uses classic GSPMD propagation."""
-    from hydragnn_tpu.utils.jax_compat import def_partition
-
-    def_partition(
-        op,
-        partition=partition,
-        infer_sharding_from_operands=infer_sharding_from_operands,
-        sharding_rule=sharding_rule,
-    )
 
 
 def _make_partitioned_op(family: bool, has_mask: bool):
@@ -521,8 +499,7 @@ def _make_partitioned_op(family: bool, has_mask: bool):
 
     ins = "e h, e" + (", e" if has_mask else "")
     outs = "n h, n h, n" if family else "n h"
-    _def_partition_compat(
-        op,
+    op.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule=f"{ins} -> {outs}",
@@ -643,7 +620,7 @@ def segment_sum_local_pallas(
     data = _match_vma(data, vma)
     ids = _match_vma(ids, vma)
     win = _match_vma(win.astype(jnp.int32), vma)
-    out_sds = _sds((n_pad, h), jnp.float32, vma=vma)
+    out_sds = jax.ShapeDtypeStruct((n_pad, h), jnp.float32, vma=vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_blocks,),
@@ -899,7 +876,7 @@ def _bcast_kernel_call(table, ids, interpret, sorted_ids=True):
     table = _match_vma(table, vma)
     recv = _match_vma(recv, vma)
     scal = _match_vma(scal, vma)
-    out_sds = _sds((e_pad, h), table.dtype, vma=vma)
+    out_sds = jax.ShapeDtypeStruct((e_pad, h), table.dtype, vma=vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_chunks,),
@@ -1006,8 +983,8 @@ def _gather_stats_call(table, ids, mask, k_group, interpret):
     mask_i = _match_vma(mask_i, vma)
     scal = _match_vma(scal, vma)
     rows = e // k_group
-    stats_sds = _sds((rows, 2 * h), jnp.float32, vma=vma)
-    both_sds = _sds((rows, 2 * h), table.dtype, vma=vma)
+    stats_sds = jax.ShapeDtypeStruct((rows, 2 * h), jnp.float32, vma=vma)
+    both_sds = jax.ShapeDtypeStruct((rows, 2 * h), table.dtype, vma=vma)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_chunks,),
@@ -1194,8 +1171,7 @@ def _make_partitioned_bcast():
         )
         return mesh, lower_fn, NamedSharding(mesh, P(edge_axis, None)), arg_sh
 
-    _def_partition_compat(
-        op,
+    op.def_partition(
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule="n h, e -> e h",
@@ -1660,7 +1636,7 @@ def _pna_bwd_kernels(v, receivers, mask, both, g_sum, g_sumsq, g_both,
     block_ptr = _match_vma(block_ptr, vma)
     cnt_both = pl.pallas_call(
         k1_kernel,
-        out_shape=_sds((n_pad_out, 2 * h), jnp.float32, vma=vma),
+        out_shape=jax.ShapeDtypeStruct((n_pad_out, 2 * h), jnp.float32, vma=vma),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_blocks,),
@@ -1710,7 +1686,7 @@ def _pna_bwd_kernels(v, receivers, mask, both, g_sum, g_sumsq, g_both,
     scal = _match_vma(scal, vma2)
     grad = pl.pallas_call(
         k2_kernel,
-        out_shape=_sds((e_pad, h), vd, vma=vma2),
+        out_shape=jax.ShapeDtypeStruct((e_pad, h), vd, vma=vma2),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_chunks,),

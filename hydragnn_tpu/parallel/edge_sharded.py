@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hydragnn_tpu.parallel.mesh import DATA_AXIS
 
-from hydragnn_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def shard_edges(

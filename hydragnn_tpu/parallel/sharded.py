@@ -44,7 +44,7 @@ from hydragnn_tpu.models.base import HydraModel, model_loss
 from hydragnn_tpu.parallel.mesh import DATA_AXIS
 from hydragnn_tpu.train.state import TrainState
 
-from hydragnn_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 # graftsync: thread-safe=GIL-atomic one-way False->True latch; a race costs one duplicate warning
 _warned_zero1_replicated = False

@@ -184,6 +184,25 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print("pilot.tune: injected train crash", file=sys.stderr)
         return 70
 
+    # This child is started by a server that holds its chip, and a chip
+    # belongs to one process at a time: on a one-chip host the backend
+    # cannot come up here. Say so and fail fast (config error: the
+    # supervisor does not burn its restarts on it) instead of crashing
+    # somewhere inside the first jit.
+    from hydragnn_tpu.utils.platform import BackendInitError, check_backend
+
+    try:
+        check_backend()
+    except BackendInitError as exc:
+        print(
+            f"pilot.tune: no backend for the fine-tune child: {exc}\n"
+            "pilot.tune: a chip belongs to one process at a time and the "
+            "serving parent holds it — give the tuner its own chip or host, "
+            "or run it with JAX_PLATFORMS=cpu",
+            file=sys.stderr,
+        )
+        return EXIT_CONFIG_ERROR
+
     shards = args.shards.split(",") if args.shards else None
     try:
         out = fine_tune(

@@ -219,7 +219,18 @@ def run_guard():
         raise SystemExit(exc.exit_code)
     except RuntimeError as exc:
         from hydragnn_tpu.utils.checkpoint import CheckpointFormatError
+        from hydragnn_tpu.utils.platform import BackendInitError
 
+        if isinstance(exc, BackendInitError):
+            # a chip belongs to one process at a time: a backend that
+            # does not come up is held by another process (a sibling of
+            # this supervised child, or its parent) or misconfigured —
+            # restarting into the same state cannot help
+            print(
+                f"run_guard: {exc} — one process per chip; fail-fast",
+                file=sys.stderr,
+            )
+            raise SystemExit(EXIT_CONFIG_ERROR)
         if isinstance(exc, CheckpointFormatError):
             # an upgrade refusal is deterministic — retrying cannot help
             traceback.print_exc()

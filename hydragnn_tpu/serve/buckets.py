@@ -166,6 +166,14 @@ class BucketCompileCache:
             return None
         return self._exec_cache.load(key, self._compat, label=f"bucket_{b.index}")
 
+    def _compile(self, b: Bucket):
+        lowered = self._forward.lower(self._variables, self._build_warm_batch(b))
+        if self._key(b) is None:
+            return lowered.compile()
+        from hydragnn_tpu.utils.exec_cache import compile_for_store
+
+        return compile_for_store(lowered)
+
     def _store_disk(self, b: Bucket, exe) -> None:
         key = self._key(b)
         if key is not None:
@@ -181,8 +189,7 @@ class BucketCompileCache:
                 # stays untouched (the exec-cache hit counter carries it)
                 self._compiled[b.index] = exe
                 continue
-            warm = self._build_warm_batch(b)
-            exe = self._forward.lower(self._variables, warm).compile()
+            exe = self._compile(b)
             self._compiled[b.index] = exe
             self._store_disk(b, exe)
             if self._metrics is not None:
@@ -210,8 +217,7 @@ class BucketCompileCache:
             exe = self._load_disk(bucket)
             hit_disk = exe is not None
             if exe is None:
-                warm = self._build_warm_batch(bucket)
-                exe = self._forward.lower(self._variables, warm).compile()
+                exe = self._compile(bucket)
             if self._post_rebind_gate:
                 self._canary_gate(exe, bucket)
             self._compiled[bucket.index] = exe

@@ -458,6 +458,21 @@ def _allgather_varlen(arr: np.ndarray) -> np.ndarray:
     return np.concatenate([gathered[p, : counts[p]] for p in range(len(counts))])
 
 
+def _stack_refusal(loader) -> Optional[str]:
+    """Materialize ``loader``'s device-resident stack (the loader caches
+    it, so epoch 0 does not pay twice). None when it did; else why not,
+    for the two anticipated causes — batches of unlike shape cannot
+    stack (ValueError), a split too large for device memory cannot be
+    resident. Anything else is a fault and raises."""
+    try:
+        loader.stacked_device_batches(0)
+    except (ValueError, jax.errors.JaxRuntimeError) as exc:
+        if not (isinstance(exc, ValueError) or "RESOURCE_EXHAUSTED" in str(exc)):
+            raise
+        return f"{type(exc).__name__}: {str(exc)[:160]}"
+    return None
+
+
 def _scan_auto_eligible(loader, partitioner=None) -> Tuple[bool, str]:
     """Is the whole-epoch scan dispatch the right DEFAULT here?
     (``Training.scan_epoch`` unset — an explicit true/false always
@@ -563,9 +578,9 @@ def train_validate_test(
     # Dispatch-mode resolution. ``Training.scan_epoch`` explicit
     # true/false always wins; UNSET defaults to the whole-epoch lax.scan
     # dispatch when eligible (_scan_auto_eligible: single-device mesh +
-    # device-resident stacked loader — it already wins 3x on qm9,
-    # BENCH_r04), with automatic fallback to per-step dispatch and the
-    # decision recorded in the flight manifest's ``dispatch_mode``.
+    # device-resident stacked loader), with automatic fallback to
+    # per-step dispatch and the decision recorded in the flight
+    # manifest's ``dispatch_mode``.
     scan_fn = scan_eval_fn = None
     loop_owned = train_step is None
     scan_cfg = training.get("scan_epoch")
@@ -581,14 +596,11 @@ def train_validate_test(
         if use_scan and float(training.get("watchdog_stall_s", 0) or 0) > 0:
             use_scan, dispatch_reason = False, "hang watchdog active"
         if use_scan:
-            # the stack must actually materialize (pad-plan/HBM limits):
-            # fall back instead of dying mid-run — the loader caches the
-            # stack, so epoch 0 does not pay this twice
-            try:
-                train_loader.stacked_device_batches(0)
-            except Exception as exc:
-                use_scan = False
-                dispatch_reason = f"stacking failed: {type(exc).__name__}"
+            # the stack must actually materialize, or the run goes
+            # per-step and says why
+            refusal = _stack_refusal(train_loader)
+            if refusal is not None:
+                use_scan, dispatch_reason = False, f"stacking failed: {refusal}"
     elif scan_cfg:
         use_scan, dispatch_reason = True, "Training.scan_epoch=true"
     else:
@@ -609,13 +621,10 @@ def train_validate_test(
         )
         if eval_step is None:  # a caller-supplied eval_step keeps priority
             scan_eval_fn = make_scan_eval(model)
-            if scan_auto:
-                # auto mode must not die on an unstackable VAL split —
-                # eval falls back to per-step, training stays scanned
-                try:
-                    val_loader.stacked_device_batches(0)
-                except Exception:
-                    scan_eval_fn = None
+            # auto mode must not die on an unstackable VAL split —
+            # eval falls back to per-step, training stays scanned
+            if scan_auto and _stack_refusal(val_loader) is not None:
+                scan_eval_fn = None
     # own_step: the loop built the default single-device PER-STEP train
     # step — the only mode with per-batch (state, batch) pairs on the
     # host (the diagnostics sampler's per-step granularity; scan mode

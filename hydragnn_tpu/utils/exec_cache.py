@@ -37,30 +37,43 @@ recorded as ``exec_cache`` flight-record events and ServeMetrics
 counters — a warm start that silently recompiles is a regression this
 observability exists to catch.
 
-DONATION GATE (the PR 1 correctness constraint): a deserialized
-DONATED executable is NOT trustworthy on this jax/jaxlib (0.4.x). The
-input/output aliasing baked into the binary round-trips, and trivial
-probes — and even bit-exact chained replays of the real train step in
-a clean process — pass; but executed inside a full training process
-(restored checkpoint, async diagnostics reads, eval jits live) the
-same executable intermittently corrupts memory: scrambled output
-pytrees (``nu`` subtrees swapping dict keys), scattered-NaN leaves,
-``Check failed: !tracked_device_buffer_`` aborts, segfaults. The
-repo's consumers therefore NEVER cache a donated program: the train
-loop and the bench drivers cache a donation-free twin of the step (a
-plain jit of the same body — one extra state-sized buffer while the
-cache is on), and serving forwards are donation-free already. The
-gate machinery stays as defense-in-depth for any caller that does
-pass ``donated=True``: :func:`donation_roundtrip_ok` — a one-time
-serialize/deserialize probe of a tiny donated function whose output
-must bit-match the fresh compile, persisted per environment
-fingerprint in the cache dir — plus a first-execution landing check
-in ``train/loop.py`` (the cached step's output ``step`` must be input
-``step + delta``). A failed (or injected:
+EXECUTION DEVICES (jax 0.9.0): ``deserialize_and_load`` takes
+``execution_devices`` and, when it is not given, loads the executable
+onto EVERY device of the backend — a single-device executable read back
+on a host with more than one device then fails at its first call
+(``Expected args to execute_sharded_on_local_devices to have N shards``).
+Each entry therefore records the ids of the devices its executable was
+compiled for, in assignment order, and a load hands exactly those
+devices back, so on the CPU backend the executable lands where the
+compile did. An entry whose devices do not all exist in this process is
+a ``layout_changed`` miss. NOT repaired: on the TPU (libtpu 0.0.34,
+four-chip host) a single-device executable compiled for a device other
+than the default is loaded onto the DEFAULT device whatever
+``execution_devices`` names, reports the named device all the same, and
+fails at its first call (``tools/chip_probe.py exec_cache``, PR 21).
+Every consumer in the repo compiles for the default device today; one
+that pins programs to other chips must not cache them until a load can
+carry the device assignment (JAX's own cache passes compile options to
+``deserialize_executable``; ``deserialize_and_load`` does not).
+
+DONATION GATE: a deserialized DONATED executable was not trustworthy on
+the jax this cache was written against — trivial probes passed while
+the real train step, executed inside a full training process,
+intermittently corrupted memory. Whether jax 0.9.0 still does that has
+not been established, so the rule stands: the repo's consumers NEVER
+cache a donated program. The train loop and the bench drivers cache a
+donation-free twin of the step (a plain jit of the same body — one
+extra state-sized buffer while the cache is on), and serving forwards
+are donation-free already. The gate machinery stays as
+defense-in-depth for any caller that does pass ``donated=True``:
+:func:`donation_roundtrip_ok` — a one-time serialize/deserialize probe
+of a tiny donated function whose output must bit-match the fresh
+compile, persisted per environment fingerprint in the cache dir — plus
+a first-execution landing check in ``train/loop.py`` (the cached step's
+output ``step`` must be input ``step + delta``). A failed (or injected:
 ``HYDRAGNN_INJECT_DONATION_CHECK_FAIL``) check evicts the entry and
 falls through to a live compile with a ``donation_check_failed`` miss
-reason. But a passing probe is necessary, not sufficient — which is
-exactly why the defaults above refuse donated caching outright.
+reason. A passing probe is necessary, not sufficient.
 """
 
 from __future__ import annotations
@@ -70,6 +83,7 @@ import json
 import os
 import pickle
 import sys
+import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 from hydragnn_tpu.utils import knobs
@@ -100,6 +114,60 @@ def _serialize_mod():
     except ImportError:
         pass
     return None
+
+
+_COMPILE_LOCK = threading.Lock()
+
+
+def compile_for_store(lowered):
+    """Compile ``lowered`` into an executable that :meth:`ExecCache.store`
+    can serialize.
+
+    On the CPU backend (jax 0.9.0) an executable that JAX read back from
+    its own persistent compilation cache does not survive a SECOND
+    serialization: the reloaded program fails at its first call with
+    ``NOT_FOUND: ... Function ... not found``. There the compile is made
+    with JAX's cache switched off. The switch is process-wide, so the
+    window is held under a lock, and a compile on another thread inside
+    it merely misses JAX's cache once. On the TPU a re-serialized
+    executable loads and runs (``tools/chip_probe.py reserialize``, PR
+    21), so nothing is switched and this is ``lowered.compile()``."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        return lowered.compile()
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with _COMPILE_LOCK:
+        if not jax.config.jax_enable_compilation_cache:
+            return lowered.compile()
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            return lowered.compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            cc.reset_cache()
+
+
+def _execution_devices(compiled):
+    """The devices ``compiled`` runs on, in assignment order — read off
+    the ``_unloaded_executable`` that ``serialize_executable.serialize``
+    itself pickles (no public accessor gives the order; a JAX that
+    moves it breaks ``serialize`` too, and :meth:`ExecCache.store`
+    reports either as ``store_failed``)."""
+    return list(compiled._executable._unloaded_executable.device_list)
+
+
+def _devices_by_id(device_ids):
+    """This process's devices for ``device_ids`` in that order, or None
+    when one of them does not exist here."""
+    import jax
+
+    by_id = {d.id: d for d in jax.devices()}
+    if not device_ids or any(i not in by_id for i in device_ids):
+        return None
+    return [by_id[i] for i in device_ids]
 
 
 def _sha256_hex(data: bytes) -> str:
@@ -297,9 +365,12 @@ def _run_donation_probe() -> bool:
             lambda s, x: (s + x, (s * x).sum()), donate_argnums=(0,)
         )
         a = jnp.arange(16.0, dtype=jnp.float32).reshape(4, 4)
-        compiled = g.lower(a, a).compile()
+        compiled = compile_for_store(g.lower(a, a))
         payload, in_tree, out_tree = se.serialize(compiled)
-        loaded = se.deserialize_and_load(payload, in_tree, out_tree)
+        loaded = se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=_execution_devices(compiled),
+        )
         s1, l1 = compiled(jnp.ones((4, 4), jnp.float32), a)
         s2, l2 = loaded(jnp.ones((4, 4), jnp.float32), a)
         return bool(
@@ -456,8 +527,13 @@ class ExecCache:
         if donated and not donation_roundtrip_ok(self.dir):
             self._evict(key, "donation_check_failed")
             return self._miss(key, "donation_check_failed", label=label)
+        devices = _devices_by_id(meta.get("device_ids"))
+        if devices is None:
+            return self._miss(key, "layout_changed", label=label)
         try:
-            exe = se.deserialize_and_load(payload, in_tree, out_tree)
+            exe = se.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=devices
+            )
         except Exception:
             self._evict(key, "corrupt")
             return self._miss(key, "corrupt", label=label)
@@ -490,7 +566,12 @@ class ExecCache:
             payload, in_tree, out_tree = se.serialize(compiled)
             data = pickle.dumps(
                 {
-                    "meta": {"compat": dict(compat), "label": label, "t": time.time()},
+                    "meta": {
+                        "compat": dict(compat),
+                        "label": label,
+                        "t": time.time(),
+                        "device_ids": [d.id for d in _execution_devices(compiled)],
+                    },
                     "payload": payload,
                     "in_tree": in_tree,
                     "out_tree": out_tree,
@@ -527,7 +608,7 @@ class ExecCache:
         exe = self.load(key, compat, donated=donated, label=label)
         if exe is not None:
             return exe, True, time.perf_counter() - t0
-        compiled = jitted.lower(*lower_args).compile()
+        compiled = compile_for_store(jitted.lower(*lower_args))
         if not donated or donation_roundtrip_ok(self.dir):
             self.store(key, compiled, compat, label=label)
         return compiled, False, time.perf_counter() - t0

@@ -330,8 +330,6 @@ KNOBS: Dict[str, Knob] = {
         _K("HYDRAGNN_TILE_SHAPE", "str", "default", "ops/segment_pallas.py",
            "Shape-tag selector into TUNE_TILES.json for block/chunk "
            "defaults."),
-        _K("HYDRAGNN_TPU_TESTS", "flag", None, "tests/test_tpu_chip.py",
-           "Opt into the real-chip TPU kernel suite (needs hardware)."),
         _K("HYDRAGNN_TRACE", "bool", "1", "obs/trace.py",
            "Per-request/step distributed tracing gate (within the "
            "process-wide HYDRAGNN_TELEMETRY gate): 0 disables tracing."),

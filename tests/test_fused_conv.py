@@ -270,7 +270,7 @@ def pytest_partitioned_fused_edge_sharded_mesh(monkeypatch):
 def pytest_fused_inside_shard_map(monkeypatch):
     """Inside shard_map (the DP train step) operands are already local;
     the partitioned fused op must lower to the plain kernel per device."""
-    from hydragnn_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     if len(jax.devices()) < 4:

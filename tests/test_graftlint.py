@@ -572,30 +572,6 @@ class TestArtifacts:
         )
         assert [f.message for f in findings] == ["flight artifact missing"]
 
-    def test_failed_bench_attempt_must_be_structured(self, tmp_path):
-        # rc != 0 with only a raw traceback tail is NOT a valid failed
-        # run record — it must carry status/retries/failure
-        art = tmp_path / "BENCH_r99.json"
-        bare = {"n": 9, "cmd": "python bench.py", "rc": 1, "tail": "boom",
-                "parsed": None}
-        art.write_text(json.dumps(bare))
-        findings = ARTIFACTS.validate_artifacts(REPO_ROOT, [str(art)])
-        msgs = " ".join(f.message for f in findings)
-        assert "status" in msgs and "retries" in msgs and "failure" in msgs
-        structured = dict(
-            bare,
-            status="failed",
-            retries=2,
-            failure={"stage": "backend_init", "error_type": "RuntimeError",
-                     "error": "UNAVAILABLE"},
-        )
-        art.write_text(json.dumps(structured))
-        assert ARTIFACTS.validate_artifacts(REPO_ROOT, [str(art)]) == []
-        # a wrong status string on a failed attempt is a finding too
-        art.write_text(json.dumps(dict(structured, status="ok")))
-        findings = ARTIFACTS.validate_artifacts(REPO_ROOT, [str(art)])
-        assert any("expected 'failed'" in f.message for f in findings)
-
     def test_fleet_record_requires_every_chaos_scenario(self, tmp_path):
         art = tmp_path / "BENCH_FLEET.json"
         record = {

@@ -6,7 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from hydragnn_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from hydragnn_tpu.ops import (
     segment_sum_family_pallas,
     segment_sum_family_xla,

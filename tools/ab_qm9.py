@@ -10,10 +10,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ["HYDRAGNN_LOCAL_MIN_ROWS"] = "0"  # the A/B decides by batch, not gate
 
-from hydragnn_tpu.utils.platform import pin_platform_from_env
-
-pin_platform_from_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

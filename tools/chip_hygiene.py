@@ -1,15 +1,14 @@
 """Chip hygiene: detect lingering accelerator-holding processes.
 
-The r05 bench died with a bare traceback whose proximate cause class —
-a previous run's process still holding the TPU when the next one tried
-to initialize — is invisible after the fact. This tool makes it a
-reported condition BEFORE it costs a round: it scans ``/proc`` for
+A chip belongs to one process at a time, and a backend init that finds
+it held fails at once (``utils/platform.py:check_backend``, no
+retry). The cause — a previous run's process still holding the TPU — is
+invisible after the fact. This tool makes it a reported condition
+BEFORE it costs a run: it scans ``/proc`` for
 processes holding accelerator device nodes (``/dev/accel*``,
 ``/dev/vfio/*``) or the libtpu lockfile, and prints ONE JSON line a
 driver or operator can parse. ``ci.sh`` runs it as an informational
-step; ``bench.py``'s retry-with-backoff
-(``utils/platform.py:init_backend_with_retry``) handles the transient
-window this tool diagnoses.
+step.
 
 Report only — nothing is killed. ``--fail-on-holders`` turns holders
 (other than this process tree) into exit code 1 for gating scripts.

@@ -100,7 +100,7 @@ def main(argv=None) -> int:
         "--artifacts",
         action="store_true",
         help="validate committed machine artifacts (flight JSONLs + "
-        "BENCH_r*/SCALING_*/MULTICHIP_*/TUNE_TILES/BENCH_CI_BASELINE "
+        "SCALING_*/TUNE_TILES/BENCH_CI_BASELINE/BENCH_FLEET "
         "JSON schemas) instead of linting source",
     )
     parser.add_argument(

@@ -122,7 +122,6 @@ def render_report(events: List[dict]) -> str:
             "start_epoch",
             "scan_epoch",
             "mixed_precision",
-            "init_retries",
         ):
             if key in man:
                 lines.append(f"  {key}: {_fmt(man[key])}")

@@ -9,10 +9,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from hydragnn_tpu.utils.platform import pin_platform_from_env
-
-pin_platform_from_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -18,10 +18,6 @@ def log(msg):
     print(f"[{time.time()-t0:7.1f}s] {msg}", flush=True)
 
 
-from hydragnn_tpu.utils.platform import pin_platform_from_env
-
-pin_platform_from_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -26,8 +26,6 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHILD = r"""
 import glob, os, shutil, sys, time
 sys.path.insert(0, %(here)r)
-from hydragnn_tpu.utils.platform import pin_platform_from_env
-pin_platform_from_env()
 import jax, jax.numpy as jnp, numpy as np
 from hydragnn_tpu.flagship import build_flagship
 from hydragnn_tpu.train import create_train_state, make_train_step, select_optimizer
