@@ -24,6 +24,7 @@ import numpy as np
 from hydragnn_tpu.data.ingest import load_raw_samples, prepare_dataset
 from hydragnn_tpu.data.loader import GraphLoader
 from hydragnn_tpu.models.create import create_model_config
+from hydragnn_tpu.obs.spans import drain, span
 from hydragnn_tpu.train import (
     create_train_state,
     make_eval_step,
@@ -186,99 +187,104 @@ def train_with_loaders(
     already-built loaders whose config has been through ``update_config``
     — the manual-wiring tail every reference example driver repeats
     (e.g. examples/qm9/qm9.py:66-95). Returns (model, state, history)."""
-    verbosity = config.get("Verbosity", {}).get("level", 0)
-    log_name = get_log_name_config(config)
-    setup_log(log_name, log_dir)
-    save_config(config, log_name, log_dir)
+    with span("setup.model_init"):
+        verbosity = config.get("Verbosity", {}).get("level", 0)
+        log_name = get_log_name_config(config)
+        setup_log(log_name, log_dir)
+        save_config(config, log_name, log_dir)
 
-    nn_config = config["NeuralNetwork"]
-    # Taken BEFORE any mesh is attached to the loaders, so the example is
-    # a host-local batch regardless of the distribution mode.
-    example = next(iter(train_loader))
-    multihost = jax.process_count() > 1
-    example_one = _example_for_init(example, device_stack)
+        nn_config = config["NeuralNetwork"]
+        # Taken BEFORE any mesh is attached to the loaders, so the example is
+        # a host-local batch regardless of the distribution mode.
+        example = next(iter(train_loader))
+        multihost = jax.process_count() > 1
+        example_one = _example_for_init(example, device_stack)
 
-    training = nn_config["Training"]
-    # Restart-supervisor resume (hydragnn_tpu/resilience/supervisor.py):
-    # a restarted child runs with HYDRAGNN_AUTO_RESUME=1 and picks up
-    # its own checkpoint via the ordinary continue/startfrom machinery.
-    from hydragnn_tpu.resilience import auto_resume_config
+        training = nn_config["Training"]
+        # Restart-supervisor resume (hydragnn_tpu/resilience/supervisor.py):
+        # a restarted child runs with HYDRAGNN_AUTO_RESUME=1 and picks up
+        # its own checkpoint via the ordinary continue/startfrom machinery.
+        from hydragnn_tpu.resilience import auto_resume_config
 
-    auto_resume_config(training, log_name, log_dir)
-    freeze = bool(nn_config["Architecture"].get("freeze_conv_layers"))
-    tx = select_optimizer(training, freeze_conv=freeze)
+        auto_resume_config(training, log_name, log_dir)
+        freeze = bool(nn_config["Architecture"].get("freeze_conv_layers"))
+        tx = select_optimizer(training, freeze_conv=freeze)
 
-    train_step = eval_step = eval_step_out = stats_step = None
-    # ONE sharding story (docs/PARALLELISM.md): the Partitioner owns the
-    # composed (data, fsdp, edge) mesh, the loader placement, the state
-    # layout (replicated / ZeRO-1 / FSDP), and every partitioned step.
-    from hydragnn_tpu.parallel import Partitioner
+        train_step = eval_step = eval_step_out = stats_step = None
+        # ONE sharding story (docs/PARALLELISM.md): the Partitioner owns the
+        # composed (data, fsdp, edge) mesh, the loader placement, the state
+        # layout (replicated / ZeRO-1 / FSDP), and every partitioned step.
+        from hydragnn_tpu.parallel import Partitioner
 
-    if multihost:
-        # Global mesh over every process's devices; each process feeds
-        # its shard of the logical batch (the reference's one-DDP-rank-
-        # per-GPU launch becomes one-process-per-host + a data mesh).
-        # Heterogeneous hosts can locally derive different widths
-        # (device_stack falls back to 1 when batch_size doesn't divide
-        # its local device count); meshes/batch shapes must agree
-        # everywhere or the collectives fail opaquely downstream, so the
-        # widths are validated BEFORE the partitioner builds its global
-        # mesh from them. Gather every process's (validity, width)
-        # BEFORE raising: if only some processes raised, the rest would
-        # block forever inside this collective.
-        from jax.experimental import multihost_utils
+        if multihost:
+            # Global mesh over every process's devices; each process feeds
+            # its shard of the logical batch (the reference's one-DDP-rank-
+            # per-GPU launch becomes one-process-per-host + a data mesh).
+            # Heterogeneous hosts can locally derive different widths
+            # (device_stack falls back to 1 when batch_size doesn't divide
+            # its local device count); meshes/batch shapes must agree
+            # everywhere or the collectives fail opaquely downstream, so the
+            # widths are validated BEFORE the partitioner builds its global
+            # mesh from them. Gather every process's (validity, width)
+            # BEFORE raising: if only some processes raised, the rest would
+            # block forever inside this collective.
+            from jax.experimental import multihost_utils
 
-        ok = device_stack in (1, jax.local_device_count())
-        info = np.asarray(
-            multihost_utils.process_allgather(
-                np.asarray([int(ok), device_stack], dtype=np.int64)
-            )
-        ).reshape(-1, 2)
-        if not info[:, 0].all():
-            bad = [int(s) for o, s in info.tolist() if not o]
-            raise ValueError(
-                "multi-host device_stack must be 1 or local_device_count; "
-                f"invalid widths across processes: {bad}"
-            )
-        stacks = info[:, 1]
-        if not (stacks == device_stack).all():
-            raise ValueError(
-                f"device_stack must agree across processes, got {stacks.tolist()}"
-            )
-    partitioner = Partitioner.from_config(
-        nn_config, device_stack=device_stack, multihost=multihost
-    )
-    if not partitioner.single_device or multihost:
-        model, variables = create_model_config(
-            nn_config, example_one, bn_axis_name=partitioner.bn_axis_name
+            ok = device_stack in (1, jax.local_device_count())
+            info = np.asarray(
+                multihost_utils.process_allgather(
+                    np.asarray([int(ok), device_stack], dtype=np.int64)
+                )
+            ).reshape(-1, 2)
+            if not info[:, 0].all():
+                bad = [int(s) for o, s in info.tolist() if not o]
+                raise ValueError(
+                    "multi-host device_stack must be 1 or local_device_count; "
+                    f"invalid widths across processes: {bad}"
+                )
+            stacks = info[:, 1]
+            if not (stacks == device_stack).all():
+                raise ValueError(
+                    f"device_stack must agree across processes, got {stacks.tolist()}"
+                )
+        partitioner = Partitioner.from_config(
+            nn_config, device_stack=device_stack, multihost=multihost
         )
-        for loader in (train_loader, val_loader, test_loader):
-            partitioner.attach_loader(loader)
-        state = create_train_state(variables, tx)
-        # place BEFORE restoring: the restore target then carries the run's
-        # real (FSDP/ZeRO-1) shardings, so orbax places shards directly and
-        # the msgpack path re-places onto them
-        state = partitioner.shard_init(state)
+        sharded = not partitioner.single_device or multihost
+        if sharded:
+            model, variables = create_model_config(
+                nn_config, example_one, bn_axis_name=partitioner.bn_axis_name
+            )
+            for loader in (train_loader, val_loader, test_loader):
+                partitioner.attach_loader(loader)
+            state = create_train_state(variables, tx)
+            # place BEFORE restoring: the restore target then carries the run's
+            # real (FSDP/ZeRO-1) shardings, so orbax places shards directly and
+            # the msgpack path re-places onto them
+            state = partitioner.shard_init(state)
+        else:
+            model, variables = create_model_config(nn_config, example_one)
+            state = create_train_state(variables, tx)
+    with span("setup.restore"):
         state = load_existing_model_config(state, training, log_dir)
-        compute_dtype = jax.numpy.bfloat16 if training.get("mixed_precision") else None
-        train_step = partitioner.shard_train_step(
-            model,
-            tx,
-            compute_dtype=compute_dtype,
-            remat=bool(training.get("remat", False)),
-        )
-        eval_step = partitioner.shard_eval_step(model)
-        eval_step_out = partitioner.shard_eval_step(model, with_outputs=True)
-        stats_step = partitioner.shard_stats_step(model)
-    else:
-        model, variables = create_model_config(nn_config, example_one)
-        state = create_train_state(variables, tx)
-        state = load_existing_model_config(state, training, log_dir)
+    if sharded:
+        with span("setup.step_builders"):
+            compute_dtype = jax.numpy.bfloat16 if training.get("mixed_precision") else None
+            train_step = partitioner.shard_train_step(
+                model,
+                tx,
+                compute_dtype=compute_dtype,
+                remat=bool(training.get("remat", False)),
+            )
+            eval_step = partitioner.shard_eval_step(model)
+            eval_step_out = partitioner.shard_eval_step(model, with_outputs=True)
+            stats_step = partitioner.shard_stats_step(model)
 
     if jax.process_index() == 0:
         from hydragnn_tpu.utils.print_utils import print_model
 
-        print_model(state.params, verbosity)
+        with span("setup.manifest"):
+            print_model(state.params, verbosity)
 
     viz = config.get("Visualization", {})
     state, history = train_validate_test(
@@ -325,12 +331,14 @@ def run_training(
     ``prometheus_dir`` additionally writes an atomic ``train.prom``
     textfile snapshot per epoch for a node-exporter textfile collector.
     All of it is inert under ``HYDRAGNN_TELEMETRY=0``."""
-    config = load_config(config_file_or_dict)
-    verbosity = config.get("Verbosity", {}).get("level", 0)
-    # the typed error (not a raw jax traceback) when the backend cannot
-    # come up or is not the one JAX_PLATFORMS names: a supervised child
-    # whose chip another process holds then fails fast (run_guard)
-    check_backend()
+    drain()  # spans an earlier run of this process left unflushed are not this run's
+    with span("setup.backend"):
+        config = load_config(config_file_or_dict)
+        verbosity = config.get("Verbosity", {}).get("level", 0)
+        # the typed error (not a raw jax traceback) when the backend cannot
+        # come up or is not the one JAX_PLATFORMS names: a supervised child
+        # whose chip another process holds then fails fast (run_guard)
+        check_backend()
 
     timer = Timer("total_training")
     timer.start()
@@ -338,10 +346,11 @@ def run_training(
     # that raised mid-training would otherwise poison every later
     # run_training in the process with "Timer already running"
     try:
-        device_stack = _choose_device_stack(config)
-        train_loader, val_loader, test_loader, config = prepare_loaders_and_config(
-            config, samples, device_stack=device_stack
-        )
+        with span("setup.data"):
+            device_stack = _choose_device_stack(config)
+            train_loader, val_loader, test_loader, config = prepare_loaders_and_config(
+                config, samples, device_stack=device_stack
+            )
         model, state, history = train_with_loaders(
             config,
             train_loader,

@@ -41,6 +41,7 @@ from hydragnn_tpu.obs.flight import (
     SCHEMA_VERSION,
     SUPPORTED_SCHEMA_VERSIONS,
     FlightRecorder,
+    epoch_phases,
     flight_record_warnings,
     read_flight_record,
     validate_flight_record,
@@ -72,7 +73,7 @@ from hydragnn_tpu.obs.podview import (
     straggler_spec,
     validate_podview_report,
 )
-from hydragnn_tpu.obs.spans import StepSpans
+from hydragnn_tpu.obs.spans import StepSpans, span
 from hydragnn_tpu.obs.trace import (
     RequestTrace,
     Tracer,
@@ -130,6 +131,7 @@ __all__ = [
     "SCHEMA_VERSION",
     "SUPPORTED_SCHEMA_VERSIONS",
     "FlightRecorder",
+    "epoch_phases",
     "flight_record_warnings",
     "read_flight_record",
     "validate_flight_record",
@@ -157,6 +159,7 @@ __all__ = [
     "straggler_spec",
     "validate_podview_report",
     "StepSpans",
+    "span",
     "RequestTrace",
     "Tracer",
     "export_flight_chrome",

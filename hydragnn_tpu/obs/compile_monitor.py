@@ -95,7 +95,7 @@ class CompileMonitor:
         # graftsync: guarded-by=compile_monitor.CompileMonitor._lock
         self.records: List[Tuple[float, str, float]] = []  # (t, event, dur)
         # graftsync: guarded-by=compile_monitor.CompileMonitor._lock
-        self._marks: Dict[str, int] = {}
+        self._marks: Dict[str, Tuple[int, float]] = {}  # (count, seconds)
         # graftsync: thread-safe=written only from the lifecycle-owning thread in start(); the dispatch thread only reads
         self.available = False
         # graftsync: thread-safe=written only from the lifecycle-owning thread in start()/stop()
@@ -149,12 +149,16 @@ class CompileMonitor:
     def mark(self, name: str) -> int:
         """Snapshot the current count under ``name``; returns it."""
         with self._lock:
-            self._marks[name] = self.count
+            self._marks[name] = (self.count, self.total_duration_s)
             return self.count
 
     def count_since(self, name: str) -> int:
         with self._lock:
-            return self.count - self._marks.get(name, 0)
+            return self.count - self._marks.get(name, (0, 0.0))[0]
+
+    def seconds_since(self, name: str) -> float:
+        with self._lock:
+            return self.total_duration_s - self._marks.get(name, (0, 0.0))[1]
 
     def snapshot(self) -> dict:
         with self._lock:

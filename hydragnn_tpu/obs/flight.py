@@ -50,6 +50,9 @@ SUPPORTED_SCHEMA_VERSIONS = (1, 2)
 # unknown extra fields always are.
 _REQUIRED: Dict[str, tuple] = {
     "run_start": ("manifest",),
+    # the program's set-up spans (obs/spans.py:span), flushed once before
+    # the first epoch: {name: {"s": seconds, "n": count, "parent": name}}
+    "setup": ("phases",),
     "epoch": ("epoch", "train_loss", "val_loss"),
     "compile": ("count",),
     "retry": ("attempt", "error"),
@@ -346,6 +349,22 @@ def read_flight_record(path: str) -> List[dict]:
                 continue  # truncated tail: expected for a crashed run
             events.append({"kind": "_unparseable", "line": line[:200]})
     return events
+
+
+def epoch_phases(events: List[dict]) -> Dict[int, Dict[str, dict]]:
+    """Each epoch's program spans (``obs/spans.py:span``), ``{epoch:
+    {name: {"s", "n", "parent"}}}``: the ``phases`` of its own ``epoch``
+    event, and the spans that closed after that event was written
+    (``epoch.record``, ``epoch.checkpoint``, ``epoch`` itself), which the
+    next event carries under their epoch number as ``phases_late``."""
+    out: Dict[int, Dict[str, dict]] = {}
+    for ev in events:
+        if ev.get("kind") == "epoch" and isinstance(ev.get("phases"), dict):
+            out.setdefault(ev["epoch"], {}).update(ev["phases"])
+        for late in ev.get("phases_late") or []:
+            if late.get("epoch") is not None:
+                out.setdefault(late["epoch"], {}).update(late.get("phases") or {})
+    return out
 
 
 def validate_flight_record(

@@ -295,7 +295,7 @@ def make_diagnostics_step(
         )
         return sum(jax.tree_util.tree_leaves(leaves), jnp.zeros((), jnp.float32))
 
-    def diag(state, batch) -> Dict[str, Any]:
+    def diagnostics_step(state, batch) -> Dict[str, Any]:
         # identical split to the train step body: the diagnosed gradient
         # is the gradient THIS step's update is built from
         _, dropout_rng = jax.random.split(state.rng)
@@ -351,7 +351,7 @@ def make_diagnostics_step(
             "update_ratio": update_norm / jnp.maximum(param_norm, 1e-30),
         }
 
-    return jax.jit(diag)
+    return jax.jit(diagnostics_step)
 
 
 class HeadDiagnostics:

@@ -15,15 +15,108 @@ Decomposes each epoch's steps into the three places wall time hides:
 This makes the "wall is several times device time" class of gap a
 measured, per-epoch number: ``epoch_snapshot`` feeds the
 flight recorder (``hydragnn_tpu/obs/flight.py``) and tensorboard.
-Sampled steps are wrapped in a ``jax.profiler`` trace annotation
-("obs.sampled_sync_step") so they are identifiable in XProf timelines
-captured by ``utils/profile.py:Profiler``.
+
+:func:`span` is the program's ONE span primitive (docs/OBSERVABILITY.md
+"Program spans"): every phase of set-up and of an epoch, and in
+per-step mode every loader wait and step, runs under one. A span is a
+``jax.profiler.TraceAnnotation`` — under any live capture it lies on the
+device trace's clock — and an entry (seconds, count, parent) in an
+in-memory table that the train loop flushes into the flight record at
+the end of set-up and at each epoch boundary. :func:`count` keeps
+counters beside it, flushed at the same boundaries.
 """
 
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
-from typing import Any, Iterable, Iterator, Optional
+from typing import Any, Dict, Iterable, Iterator, Optional
+
+import jax
+
+from hydragnn_tpu.obs.registry import telemetry_enabled
+from hydragnn_tpu.utils import syncdebug
+
+_LOCK = syncdebug.maybe_wrap(threading.Lock(), "spans._LOCK")
+_PHASES: Dict[str, list] = {}  # graftsync: guarded-by=spans._LOCK
+_COUNTS: Dict[str, float] = {}  # graftsync: guarded-by=spans._LOCK
+_OPEN = threading.local()  # .stack: names of this thread's open spans
+_NULL_SPAN = contextlib.nullcontext()
+
+
+class _Span:
+    __slots__ = ("name", "_annotation", "_parent", "_t0")
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
+        self.name = name
+        self._annotation = jax.profiler.TraceAnnotation(name, **attrs)
+
+    def __enter__(self) -> "_Span":
+        stack = getattr(_OPEN, "stack", None)
+        if stack is None:
+            stack = _OPEN.stack = []
+        self._parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        _OPEN.stack.pop()
+        with _LOCK:
+            entry = _PHASES.setdefault(self.name, [0.0, 0, self._parent])
+            entry[0] += seconds
+            entry[1] += 1
+
+
+def span(name: str, **attrs):
+    """Context manager around one phase of the program. Adds no host
+    sync. With ``HYDRAGNN_TELEMETRY=0`` it is one shared null context."""
+    if not telemetry_enabled():
+        return _NULL_SPAN
+    return _Span(name, attrs)
+
+
+def span_iter(iterable: Iterable, name: str) -> Iterator:
+    """Yield from ``iterable`` with each wait for its next item (a
+    loader's host batching and transfer) under the span ``name``."""
+    it = iter(iterable)
+    while True:
+        with span(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
+
+
+def count(name: str, n: float) -> None:
+    """Add ``n`` to the counter ``name`` (``graphs``, ``steps``)."""
+    if telemetry_enabled():
+        with _LOCK:
+            _COUNTS[name] = _COUNTS.get(name, 0) + n
+
+
+def drain() -> Dict[str, Dict[str, Any]]:
+    """The spans closed since the last drain, ``{name: {"s", "n",
+    "parent"}}``, and an empty table after it."""
+    with _LOCK:
+        phases = {
+            k: {"s": round(s, 6), "n": n, "parent": parent}
+            for k, (s, n, parent) in _PHASES.items()
+        }
+        _PHASES.clear()
+    return phases
+
+
+def drain_counts() -> Dict[str, float]:
+    with _LOCK:
+        counts = dict(_COUNTS)
+        _COUNTS.clear()
+    return counts
 
 
 class StepSpans:
@@ -89,7 +182,7 @@ class StepSpans:
     def timed_iter(self, iterable: Iterable) -> Iterator:
         """Yield from ``iterable``, accumulating the time this consumer
         spends blocked waiting for the next batch."""
-        it = iter(iterable)
+        it = span_iter(iterable, "train.loader_wait")
         while True:
             t0 = time.perf_counter()
             try:
@@ -102,6 +195,10 @@ class StepSpans:
     def step(self, fn, *args) -> Any:
         """Run one train step, recording dispatch time; inside the
         sampling window, fence the outputs and record device wait."""
+        with span("train.step", step=self.steps):
+            return self._step(fn, *args)
+
+    def _step(self, fn, *args) -> Any:
         t0 = time.perf_counter()
         if self._straggle_s:
             time.sleep(self._straggle_s)
@@ -117,11 +214,7 @@ class StepSpans:
             # is skipped outright, not deferred
             sampling = not capture_active()
         if sampling:
-            import jax
-
-            from hydragnn_tpu.utils.profile import trace_annotation
-
-            with trace_annotation("obs.sampled_sync_step"):
+            with span("obs.sampled_sync_step"):
                 out = fn(*args)
                 t1 = time.perf_counter()
                 jax.block_until_ready(out)

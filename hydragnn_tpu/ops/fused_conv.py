@@ -511,6 +511,7 @@ def _fused_kernel_call(x, senders, receivers, mask, w_cat, b_cat, rtab,
         out_shape=out_sds,
         grid_spec=grid_spec,
         interpret=interpret,
+        name="fused_conv",
     )(block_ptr, plan, *operands)
     return out[:num_segments]
 
@@ -1212,6 +1213,7 @@ def _stack_kernel_call(x, senders, receivers, mask, w_stack, b_stack,
         out_shape=out_sds,
         grid_spec=grid_spec,
         interpret=interpret,
+        name="fused_conv_stack",
     )(block_ptr, plan, *operands)
     return out[:num_segments]
 

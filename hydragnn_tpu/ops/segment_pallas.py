@@ -434,6 +434,7 @@ def _csr_kernel_call(data, segment_ids, mask, num_segments, interpret, family):
         out_shape=[out_sds] * n_out,
         grid_spec=grid_spec,
         interpret=interpret,
+        name="csr_family" if family else "csr_sum",
     )(block_ptr, data, recv[None, :])
     if not family:
         return outs[0][:num_segments]
@@ -640,6 +641,7 @@ def segment_sum_local_pallas(
         out_shape=[out_sds],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="segment_sum_local",
     )(win, data, ids[None, :])
     return out[:num_segments]
 
@@ -896,6 +898,7 @@ def _bcast_kernel_call(table, ids, interpret, sorted_ids=True):
         out_shape=out_sds,
         grid_spec=grid_spec,
         interpret=interpret,
+        name="bcast_gather",
     )(scal, table, recv[None, :])
     return out[:e]
 
@@ -1008,6 +1011,7 @@ def _gather_stats_call(table, ids, mask, k_group, interpret):
         out_shape=[stats_sds, both_sds],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="gather_stats",
     )(scal, table, recv[None, :], mask_i[None, :])
     return stats, both
 
@@ -1645,6 +1649,7 @@ def _pna_bwd_kernels(v, receivers, mask, both, g_sum, g_sumsq, g_both,
             scratch_shapes=scratch,
         ),
         interpret=interpret,
+        name="pna_bwd_tie_count",
     )(block_ptr, *operands)[:num_segments]
 
     # ---- node-level shares, stacked table ----
@@ -1699,6 +1704,7 @@ def _pna_bwd_kernels(v, receivers, mask, both, g_sum, g_sumsq, g_both,
             ],
         ),
         interpret=interpret,
+        name="pna_bwd_grad",
     )(scal, *operands2)
     return grad[:e]
 

@@ -223,7 +223,7 @@ def make_dp_edge_train_step(
 
     from hydragnn_tpu.parallel.sharded import _state_sharding
 
-    def step(state, batch):
+    def train_step(state, batch):
         # this step vmaps the model over the data axis; the Pallas
         # segment ops' custom_partitioning wrapper has no vmap batching
         # rule, so trace the whole body on the XLA segment path (the
@@ -288,7 +288,7 @@ def make_dp_edge_train_step(
         )
         return new_state, loss, tasks
 
-    return jax.jit(step, donate_argnums=(0,))
+    return jax.jit(train_step, donate_argnums=(0,))
 
 
 def make_dp_edge_eval_step(model, mesh: Mesh, with_outputs: bool = False):
@@ -303,7 +303,7 @@ def make_dp_edge_eval_step(model, mesh: Mesh, with_outputs: bool = False):
     from hydragnn_tpu.models.base import model_loss as _model_loss
     from hydragnn_tpu.ops.segment_pallas import xla_segment_ops
 
-    def step(state, batch):
+    def eval_step(state, batch):
         with xla_segment_ops():
             return _body(state, batch)
 
@@ -329,7 +329,9 @@ def make_dp_edge_eval_step(model, mesh: Mesh, with_outputs: bool = False):
             return loss, tasks, flat
         return loss, tasks
 
-    return jax.jit(step)
+    if with_outputs:
+        eval_step.__name__ = "eval_step_outputs"
+    return jax.jit(eval_step)
 
 
 def make_dp_edge_stats_step(model, mesh: Mesh):
@@ -339,7 +341,7 @@ def make_dp_edge_stats_step(model, mesh: Mesh):
     averaged over the stacked sub-batches."""
     from hydragnn_tpu.ops.segment_pallas import xla_segment_ops
 
-    def step(state, batch):
+    def bn_stats_step(state, batch):
         with xla_segment_ops():
             def per_shard(batch_d):
                 _, mutated = model.apply(
@@ -355,7 +357,7 @@ def make_dp_edge_stats_step(model, mesh: Mesh):
             new_stats = jax.tree_util.tree_map(lambda s: s.mean(axis=0), stats)
             return state.replace(batch_stats=new_stats)
 
-    return jax.jit(step)
+    return jax.jit(bn_stats_step)
 
 
 def edge_sharded_gin_layer(

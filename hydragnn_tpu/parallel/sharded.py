@@ -242,7 +242,7 @@ def make_sharded_train_step(
         check_vma=False,
     )
 
-    def step(state: TrainState, batch: GraphBatch):
+    def train_step(state: TrainState, batch: GraphBatch):
         rng, dropout_rng = jax.random.split(state.rng)
         grads, new_stats, loss, tasks = sharded_grads(
             state.params, state.batch_stats, dropout_rng, batch
@@ -266,7 +266,7 @@ def make_sharded_train_step(
         )
         return new_state, loss, tasks
 
-    return jax.jit(step, donate_argnums=(0,))
+    return jax.jit(train_step, donate_argnums=(0,))
 
 
 def make_sharded_stats_step(
@@ -298,11 +298,11 @@ def make_sharded_stats_step(
         check_vma=False,
     )
 
-    def step(state: TrainState, batch: GraphBatch):
+    def bn_stats_step(state: TrainState, batch: GraphBatch):
         new_stats = fn(state.params, state.batch_stats, batch)
         return state.replace(batch_stats=new_stats)
 
-    return jax.jit(step)
+    return jax.jit(bn_stats_step)
 
 
 def make_sharded_eval_step(
@@ -344,11 +344,13 @@ def make_sharded_eval_step(
         check_vma=False,
     )
 
-    def step(state: TrainState, batch: GraphBatch):
+    def eval_step(state: TrainState, batch: GraphBatch):
         res = fn(state.params, state.batch_stats, batch)
         if with_outputs:
             loss, tasks, outputs = res
             return loss, tasks, list(outputs)
         return res
 
-    return jax.jit(step)
+    if with_outputs:
+        eval_step.__name__ = "eval_step_outputs"
+    return jax.jit(eval_step)

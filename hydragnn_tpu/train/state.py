@@ -7,6 +7,12 @@ step per batch. Here the whole step is ONE jitted function over a
 update + BatchNorm running-stat update, compiled once (fixed batch shapes
 come from the loader's pad plan). Head indexing does not exist — targets
 are already a dict-of-heads on the batch.
+
+The name of each function handed to ``jax.jit`` is its compiled program's
+name (``jit_train_scan_epoch``, ``jit_train_step``, ``jit_eval_scan``,
+``jit_eval_step``, ``jit_eval_step_outputs``, ``jit_bn_stats_step``;
+``parallel/`` uses the same ones): a profiler trace's ``XLA Modules`` line
+tells the programs apart by it, and ``benchmark/program_spans.py`` reads it.
 """
 
 from __future__ import annotations
@@ -91,7 +97,7 @@ def _train_step_body(
     """The un-jitted per-batch training body shared by the jitted
     single-step path and the scan-over-epoch path."""
 
-    def step(state: TrainState, batch: GraphBatch):
+    def train_step(state: TrainState, batch: GraphBatch):
         rng, dropout_rng = jax.random.split(state.rng)
 
         def loss_fn(params):
@@ -127,7 +133,7 @@ def _train_step_body(
         )
         return new_state, loss, tasks
 
-    return step
+    return train_step
 
 
 def _guarded_step_body(
@@ -151,7 +157,7 @@ def _guarded_step_body(
     metrics (which also zero the batch's count) stay clean.
     """
 
-    def step(state: TrainState, batch: GraphBatch, consec: jnp.ndarray):
+    def train_step(state: TrainState, batch: GraphBatch, consec: jnp.ndarray):
         rng, dropout_rng = jax.random.split(state.rng)
 
         def loss_fn(params):
@@ -203,7 +209,7 @@ def _guarded_step_body(
             badf,
         )
 
-    return step
+    return train_step
 
 
 def make_train_step(
@@ -294,7 +300,7 @@ def make_scan_epoch(
             model, tx, compute_dtype=compute_dtype, remat=remat
         )
 
-        def epoch_guarded(
+        def train_scan_epoch_guarded(
             state: TrainState, stacked: GraphBatch, order: jnp.ndarray,
             consec: jnp.ndarray,
         ):
@@ -310,11 +316,11 @@ def make_scan_epoch(
             )
             return state, losses, tasks, counts, bads, consec
 
-        return jax.jit(epoch_guarded, donate_argnums=(0,))
+        return jax.jit(train_scan_epoch_guarded, donate_argnums=(0,))
 
     body = _train_step_body(model, tx, compute_dtype=compute_dtype, remat=remat)
 
-    def epoch(state: TrainState, stacked: GraphBatch, order: jnp.ndarray):
+    def train_scan_epoch(state: TrainState, stacked: GraphBatch, order: jnp.ndarray):
         # Scan over the PERMUTATION, dynamic-indexing one batch out of the
         # closed-over stack per iteration: a full permuted copy of the
         # train split as scan xs would double the feature's HBM footprint.
@@ -326,7 +332,7 @@ def make_scan_epoch(
         state, (losses, tasks, counts) = jax.lax.scan(scan_body, state, order)
         return state, losses, tasks, counts
 
-    return jax.jit(epoch, donate_argnums=(0,))
+    return jax.jit(train_scan_epoch, donate_argnums=(0,))
 
 
 def make_scan_eval(
@@ -346,11 +352,11 @@ def make_scan_eval(
         loss, tasks = model_loss(model.cfg, outputs, batch)
         return state, (loss, jnp.stack(tasks), batch.graph_mask.sum().astype(jnp.float32))
 
-    def evaluate(state: TrainState, stacked: GraphBatch):
+    def eval_scan(state: TrainState, stacked: GraphBatch):
         _, (losses, tasks, counts) = jax.lax.scan(scan_body, state, stacked)
         return losses, tasks, counts
 
-    return jax.jit(evaluate)
+    return jax.jit(eval_scan)
 
 
 def make_stats_step(model: HydraModel) -> Callable[[TrainState, GraphBatch], TrainState]:
@@ -364,7 +370,7 @@ def make_stats_step(model: HydraModel) -> Callable[[TrainState, GraphBatch], Tra
     while eval-mode metrics diverge), so a few frozen-parameter passes
     make eval faithful."""
 
-    def step(state: TrainState, batch: GraphBatch):
+    def bn_stats_step(state: TrainState, batch: GraphBatch):
         # dropout OFF (train=False), BatchNorm in batch-stats mode
         # (bn_train=True): eval statistics must be estimated under the
         # same deterministic forward eval itself uses
@@ -377,7 +383,7 @@ def make_stats_step(model: HydraModel) -> Callable[[TrainState, GraphBatch], Tra
         )
         return state.replace(batch_stats=mutated["batch_stats"])
 
-    return jax.jit(step)
+    return jax.jit(bn_stats_step)
 
 
 def make_eval_step(
@@ -388,7 +394,7 @@ def make_eval_step(
     reference's ``model.eval()`` validate/test passes
     (train_validate_test.py:374-443)."""
 
-    def step(state: TrainState, batch: GraphBatch):
+    def eval_step(state: TrainState, batch: GraphBatch):
         outputs = model.apply(
             {"params": state.params, "batch_stats": state.batch_stats},
             batch,
@@ -399,4 +405,6 @@ def make_eval_step(
             return loss, jnp.stack(tasks), outputs
         return loss, jnp.stack(tasks)
 
-    return jax.jit(step)
+    if with_outputs:
+        eval_step.__name__ = "eval_step_outputs"
+    return jax.jit(eval_step)

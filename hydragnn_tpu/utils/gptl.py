@@ -4,8 +4,8 @@ The reference ships ``gptl4py_dummy`` (reference:
 hydragnn/utils/gptl4py_dummy.py:1-64), a drop-in no-op mirror of the
 gptl4py HPC timing library so instrumented code runs unchanged off
 Summit. Same pattern here: every gptl4py symbol is a no-op, and the
-nvtx-range helper maps to ``jax.profiler.TraceAnnotation`` so ranges
-show up in TPU profiler traces when one is active.
+nvtx-range helper is the program's own span (``obs/spans.py:span``), so
+ranges show up in TPU profiler traces when one is active.
 
     import hydragnn_tpu.utils.gptl as gp
     gp.initialize()
@@ -15,8 +15,6 @@ show up in TPU profiler traces when one is active.
 """
 
 from __future__ import annotations
-
-import contextlib
 
 
 def initialize() -> int:  # gptl4py_dummy.initialize
@@ -59,17 +57,12 @@ def pr_summary_file(fname: str, comm=None) -> int:
     return 0
 
 
-@contextlib.contextmanager
 def nvtx_range(name: str):
-    """Device trace span (the reference wraps nvtx.range_push/pop)."""
-    try:
-        import jax
+    """Device trace span (the reference wraps nvtx.range_push/pop): one
+    more name for ``obs/spans.py:span``."""
+    from hydragnn_tpu.obs.spans import span
 
-        annotation = jax.profiler.TraceAnnotation(name)
-    except ImportError:  # pragma: no cover
-        annotation = contextlib.nullcontext()
-    with annotation:
-        yield
+    return span(name)
 
 
 # decorator form, mirroring gptl4py's profile decorator usage

@@ -163,8 +163,11 @@ def trace_annotation(name: str):
     """Named span inside jitted/host code for the profiler timeline — the
     analog of torch.profiler.record_function spans
     (reference: train_validate_test.py:349-358) and the gptl4py/nvtx shim
-    (reference: hydragnn/utils/gptl4py_dummy.py)."""
-    return jax.profiler.TraceAnnotation(name)
+    (reference: hydragnn/utils/gptl4py_dummy.py). One more name for
+    ``obs/spans.py:span``, the one place that opens an annotation."""
+    from hydragnn_tpu.obs.spans import span
+
+    return span(name)
 
 
 def scan_slope_ms(make_chain, k1: int, k2: int) -> float:

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from hydragnn_tpu.obs import (
+    epoch_phases,
     BACKEND_COMPILE_EVENT,
     CompileMonitor,
     FlightRecorder,
@@ -233,6 +234,196 @@ def test_disabled_spans_add_no_per_step_work(monkeypatch):
     assert spans.epoch_snapshot() is None
     # disabled() returns the shared singleton: no per-epoch allocation
     assert StepSpans.disabled() is StepSpans.disabled()
+
+
+# ---------------------------------------------------------------------------
+# the span primitive (obs/spans.py:span)
+# ---------------------------------------------------------------------------
+
+
+def test_span_takes_its_parent_from_nesting():
+    from hydragnn_tpu.obs import spans
+
+    spans.drain()
+    with spans.span("outer", epoch=3):
+        with spans.span("inner"):
+            time.sleep(0.002)
+        with spans.span("inner"):
+            pass
+    spans.count("graphs", 5)
+    spans.count("graphs", 2)
+    phases = spans.drain()
+    assert phases["outer"]["parent"] is None and phases["outer"]["n"] == 1
+    assert phases["inner"] == {"s": phases["inner"]["s"], "n": 2, "parent": "outer"}
+    assert phases["outer"]["s"] >= phases["inner"]["s"] >= 0.002
+    assert spans.drain_counts() == {"graphs": 7}
+    assert spans.drain() == {} and spans.drain_counts() == {}  # flushed means gone
+
+    # each thread nests on its own stack
+    seen = {}
+
+    def other():
+        with spans.span("in_thread"):
+            pass
+        seen.update(spans.drain())
+
+    with spans.span("outer"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=10)
+    assert not t.is_alive() and seen["in_thread"]["parent"] is None
+    spans.drain()
+
+
+def test_span_is_one_shared_null_context_when_telemetry_is_off(monkeypatch):
+    from hydragnn_tpu.obs import spans
+
+    spans.drain()
+    monkeypatch.setenv("HYDRAGNN_TELEMETRY", "0")
+    assert spans.span("a") is spans.span("b", step=1)
+    with spans.span("a"):
+        spans.count("graphs", 1)
+    assert spans.drain() == {} and spans.drain_counts() == {}
+
+
+def test_span_reaches_no_host_sync(monkeypatch):
+    """A span may sit around any phase of the hot path: nothing it runs
+    waits for the device."""
+    import inspect
+
+    import jax
+
+    from hydragnn_tpu.obs import spans
+    from hydragnn_tpu.utils import gptl, profile
+
+    def _boom(*a, **kw):  # pragma: no cover - must never run
+        raise AssertionError("a span must not sync")
+
+    monkeypatch.setattr(jax, "block_until_ready", _boom)
+    monkeypatch.setattr(jax, "device_get", _boom)
+    for opener in (spans.span, profile.trace_annotation, gptl.nvtx_range):
+        with opener("phase"):
+            pass
+    assert spans.drain()["phase"]["n"] == 3  # the two shims ARE the primitive
+    source = "".join(inspect.getsource(f) for f in (spans._Span, spans.span, spans.count, spans.drain))
+    for sync in ("block_until_ready", "device_get", "asarray", ".item("):
+        assert sync not in source
+
+
+@pytest.mark.parametrize(
+    "mode,batch_size",
+    # 5 does not divide over the suite's 8 virtual devices: one device;
+    # 8 does: the sharded steps api.py builds, dispatched per step
+    [("scan_epoch", 5), ("per_step", 5), ("per_step", 8)],
+    ids=["scan_epoch", "per_step", "per_step_mesh"],
+)
+def test_run_training_records_setup_and_epoch_phases(tmp_path, mode, batch_size):
+    import glob
+
+    from hydragnn_tpu.api import run_training
+    from hydragnn_tpu.data.synthetic import deterministic_graph_data
+    from hydragnn_tpu.flagship import flagship_config
+
+    log_dir = str(tmp_path / "logs") + "/"
+    cfg = flagship_config(hidden_dim=8, num_conv_layers=2, batch_size=batch_size, num_epoch=3)
+    training = cfg["NeuralNetwork"]["Training"]
+    training["checkpoint_every"] = 2
+    if mode == "per_step" and batch_size == 5:
+        training["scan_epoch"] = False
+    samples = deterministic_graph_data(
+        number_configurations=80,
+        unit_cell_x_range=(2, 3),
+        unit_cell_y_range=(2, 3),
+        unit_cell_z_range=(2, 3),
+        seed=0,
+    )
+    run_training(cfg, samples=samples, log_dir=log_dir)
+
+    (path,) = glob.glob(log_dir + "*/flight.jsonl")
+    assert validate_flight_record(path, require_complete=True) == []
+    events = read_flight_record(path)
+    man = [e for e in events if e["kind"] == "run_start"][0]["manifest"]
+    assert man["dispatch_mode"]["mode"] == mode
+
+    (setup,) = [e for e in events if e["kind"] == "setup"]
+    top = {k for k, p in setup["phases"].items() if p["parent"] is None}
+    assert {"setup.backend", "setup.data", "setup.model_init", "setup.restore", "setup.step_builders",
+            "setup.tensorboard", "setup.drift_reference", "setup.graftcheck", "setup.exec_cache",
+            "setup.manifest"} <= top
+    assert all(p["s"] >= 0 and p["n"] >= 1 for p in setup["phases"].values())
+    if mode == "scan_epoch":
+        assert setup["phases"]["setup.stack_splits"]["parent"] == "setup.step_builders"
+
+    epochs = [e for e in events if e["kind"] == "epoch"]
+    assert [e["epoch"] for e in epochs] == [0, 1, 2]
+    plan = man["pad_plans"]["train"]
+    by_epoch = epoch_phases(events)
+    inside_train = (
+        {"train.stack", "train.dispatch", "train.sync"}
+        if mode == "scan_epoch"
+        else {"train.loader_wait", "train.step", "train.sync"}
+    )
+    for ev in epochs:
+        assert ev["graphs"] == plan["num_samples"] and ev["steps"] == plan["num_batches"]
+        assert ev["compiles"]["seconds"] >= 0
+        phases = by_epoch[ev["epoch"]]
+        assert inside_train <= {k for k, p in phases.items() if p["parent"] == "epoch.train"}
+        if mode == "per_step":
+            assert phases["train.step"]["n"] == plan["num_batches"]
+        children = {k: p for k, p in phases.items() if p["parent"] == "epoch"}
+        assert {"epoch.train", "epoch.validate", "epoch.test", "epoch.head_quality",
+                "epoch.diag_snapshot", "epoch.record"} <= set(children)
+        assert ("epoch.checkpoint" in children) == (ev["epoch"] == 1)
+        wall = phases["epoch"]["s"]
+        assert sum(p["s"] for p in children.values()) == pytest.approx(wall, rel=0.05)
+    assert epochs[1]["compiles"]["count"] == 0 and epochs[1]["compiles"]["seconds"] == 0
+
+    # tools/obs_report.py prints them: the set-up tree, a row an epoch, the mean tree
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "tools"))
+    try:
+        import obs_report
+    finally:
+        sys.path.pop(0)
+
+    report = obs_report.render_report(events)
+    assert "== setup phases ==" in report and "setup.model_init" in report
+    assert "== epoch phases (ms) ==" in report
+    assert "mean of 2 whole epoch(s) after the first" in report and "    epoch.train" in report
+
+
+def test_programs_are_lowered_under_their_own_names(tiny_flagship):
+    """The compiled module's name is what a profiler trace's ``XLA
+    Modules`` line shows: each program of the train, eval and diagnostics
+    paths has one of its own."""
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.obs.introspect import make_diagnostics_step
+    from hydragnn_tpu.train import create_train_state, make_eval_step, make_train_step, select_optimizer
+    from hydragnn_tpu.train.state import make_scan_epoch, make_scan_eval, make_stats_step
+
+    config, model, variables, loader = tiny_flagship
+    tx = select_optimizer(config["NeuralNetwork"]["Training"])
+    state = create_train_state(variables, tx)
+    batch = next(iter(loader))
+    stacked = loader.stacked_device_batches(0)
+    order = jnp.arange(len(loader), dtype=jnp.int32)
+    consec = jnp.zeros((), jnp.int32)
+    programs = {
+        "jit_train_step": make_train_step(model, tx).lower(state, batch),
+        "jit_train_step ": make_train_step(model, tx, guard_nonfinite=True).lower(state, batch, consec),
+        "jit_train_scan_epoch": make_scan_epoch(model, tx).lower(state, stacked, order),
+        "jit_train_scan_epoch_guarded": make_scan_epoch(model, tx, guard_nonfinite=True).lower(
+            state, stacked, order, consec),
+        "jit_eval_scan": make_scan_eval(model).lower(state, stacked),
+        "jit_eval_step": make_eval_step(model).lower(state, batch),
+        "jit_eval_step_outputs": make_eval_step(model, with_outputs=True).lower(state, batch),
+        "jit_bn_stats_step": make_stats_step(model).lower(state, batch),
+        "jit_diagnostics_step": make_diagnostics_step(model, tx).lower(state, batch),
+    }
+    for name, lowered in programs.items():
+        assert f"module @{name.strip()} " in lowered.as_text()[:200], name
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,7 @@ if _REPO not in sys.path:  # runnable as `python tools/obs_report.py`
 
 from hydragnn_tpu.obs.flight import (  # noqa: E402
     FAULT_KINDS,
+    epoch_phases,
     flight_record_warnings,
     read_flight_record,
     validate_flight_record,
@@ -101,9 +102,64 @@ def _exec_cache_summary(events: List[dict]) -> Optional[str]:
     return line
 
 
+def _phase_tree(phases: Dict[str, dict], scale: float, unit: str) -> List[str]:
+    """Program spans as an indented tree, children under their parent."""
+
+    def walk(name: str, depth: int) -> List[str]:
+        p = phases[name]
+        row = f"  {'  ' * depth + name:32s} {p['s'] * scale:10.3f} {unit}  x{p['n']}"
+        below = sorted((k for k, q in phases.items() if q.get("parent") == name), key=lambda k: -phases[k]["s"])
+        return [row] + [r for k in below for r in walk(k, depth + 1)]
+
+    roots = sorted((k for k, p in phases.items() if p.get("parent") not in phases), key=lambda k: -phases[k]["s"])
+    return [r for k in roots for r in walk(k, 0)]
+
+
+def render_phases(events: List[dict]) -> List[str]:
+    """The program's spans (``obs/spans.py:span``): where set-up went,
+    each epoch's phases, and what lies inside them on average."""
+    lines: List[str] = []
+    setup = _first(events, "setup")
+    if setup and setup.get("phases"):
+        lines.append("== setup phases ==")
+        lines += _phase_tree(setup["phases"], 1.0, "s ")
+    by_epoch = epoch_phases(events)
+    if not by_epoch:
+        return lines
+    counts = {e["epoch"]: e for e in events if e.get("kind") == "epoch"}
+    cols = sorted(
+        {k for ph in by_epoch.values() for k, p in ph.items() if p.get("parent") == "epoch"},
+        key=lambda k: -sum(ph.get(k, {}).get("s", 0.0) for ph in by_epoch.values()),
+    )
+    lines.append("== epoch phases (ms) ==")
+    lines.append("  " + " ".join([f"{'ep':>4}", f"{'epoch':>9}"] + [f"{c.split('.', 1)[1][:13]:>13}" for c in cols]
+                                 + [f"{'graphs':>8}", f"{'steps':>6}"]))
+    for ep in sorted(by_epoch):
+        ph = by_epoch[ep]
+        cells = [f"{ep:>4}", f"{_ms(ph.get('epoch')):>9}"] + [f"{_ms(ph.get(c)):>13}" for c in cols]
+        ev = counts.get(ep, {})
+        lines.append("  " + " ".join(cells + [f"{ev.get('graphs', '-'):>8}", f"{ev.get('steps', '-'):>6}"]))
+    # whole epochs only: one cut short by a graceful stop has no ``epoch`` span
+    whole = [ph for _, ph in sorted(by_epoch.items()) if "epoch" in ph]
+    steady = whole[1:] or whole or list(by_epoch.values())
+    mean: Dict[str, dict] = {}
+    for ph in steady:
+        for k, p in ph.items():
+            m = mean.setdefault(k, {"s": 0.0, "n": 0, "parent": p.get("parent")})
+            m["s"] += p["s"] / len(steady)
+            m["n"] = max(m["n"], p["n"])
+    lines.append(f"== inside an epoch (mean of {len(steady)} whole epoch(s) after the first, max count) ==")
+    lines += _phase_tree(mean, 1e3, "ms")
+    return lines
+
+
+def _ms(phase: Optional[dict]) -> str:
+    return "-" if not phase else f"{phase['s'] * 1e3:.1f}"
+
+
 def render_report(events: List[dict]) -> str:
-    """One run's story as text: manifest, epoch table, incidents,
-    summary."""
+    """One run's story as text: manifest, set-up and epoch phases, epoch
+    table, incidents, summary."""
     lines: List[str] = []
     start = _first(events, "run_start")
     if start:
@@ -148,12 +204,13 @@ def render_report(events: List[dict]) -> str:
                 f"{_fmt(e.get('train_loss'), 6):>13} "
                 f"{_fmt(e.get('val_loss'), 6):>13} "
                 f"{_fmt(e.get('lr'), 4):>9} "
-                f"{st.get('steps', '-'):>6} "
+                f"{st.get('steps', e.get('steps', '-')):>6} "
                 f"{_fmt(st.get('data_wait_s', '-'), 4):>12} "
                 f"{_fmt(st.get('dispatch_s', '-'), 4):>11} "
                 f"{_fmt(st.get('device_wait_ms_mean', '-'), 4):>10} "
                 f"{comp.get('count', '-'):>8}{flag}"
             )
+    lines += render_phases(events)
     ecache = _exec_cache_summary(events)
     if ecache:
         lines.append("== exec cache ==")
@@ -184,7 +241,7 @@ def render_report(events: List[dict]) -> str:
     else:
         lines.append("== run_end ==")
         for k, v in end.items():
-            if k in ("v", "kind", "t", "rank", "metrics", "timers"):
+            if k in ("v", "kind", "t", "rank", "metrics", "timers", "phases_late"):
                 continue
             lines.append(f"  {k}: {_fmt(v)}")
         for k, t in (end.get("timers") or {}).items():
