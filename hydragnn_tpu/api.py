@@ -32,6 +32,7 @@ from hydragnn_tpu.train import (
     test_epoch,
     train_validate_test,
 )
+from hydragnn_tpu.train.loop import scan_dispatch_planned
 from hydragnn_tpu.utils.checkpoint import (
     load_existing_model,
     load_existing_model_config,
@@ -103,7 +104,8 @@ def create_dataloaders(
     """Per-split loaders over prepared sample lists (the reference's
     ``create_dataloaders``, hydragnn/preprocess/load_data.py:226-283; the
     DistributedSampler role is played by num_shards/shard_rank)."""
-    training = config["NeuralNetwork"]["Training"]
+    nn_config = config["NeuralNetwork"]
+    training = nn_config["Training"]
     bs = int(training["batch_size"])
     nproc, rank = jax.process_count(), jax.process_index()
     kw = dict(
@@ -113,7 +115,18 @@ def create_dataloaders(
         cache_device_batches=bool(training.get("cache_device_batches", False)),
         scan_reshuffle_every=int(training.get("scan_reshuffle_every", 0)),
     )
-    train_loader = GraphLoader(train, bs, shuffle=True, **kw)
+    # A run that will train through the whole-epoch scan only ever permutes
+    # the ORDER of the train batches, so the train loader can cut its pad
+    # plan to the batches that exist. The plan must be final here (the
+    # example batch, introspection and the executable cache read shapes
+    # before the loop resolves its dispatch mode), so ask what the loop
+    # will ask, of the topology the partitioner will be built from (a
+    # sharded run brings its own step and never scans).
+    from hydragnn_tpu.parallel.partitioner import ParallelConfig
+
+    topology = ParallelConfig.from_config(nn_config, device_stack, multihost=nproc > 1)
+    scans, _ = scan_dispatch_planned(nn_config, topology.single_device)
+    train_loader = GraphLoader(train, bs, shuffle=True, fixed_membership=scans, **kw)
     val_loader = GraphLoader(val, bs, **kw)
     test_loader = GraphLoader(test, bs, **kw)
     return train_loader, val_loader, test_loader

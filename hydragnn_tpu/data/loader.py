@@ -6,8 +6,16 @@ concerns drive the design:
 
   - every batch in a loader has the SAME padded (nodes, edges, graphs)
     shape, so the jitted train step compiles exactly once;
-  - the pad plan is computed from the dataset up front (worst-case batch
-    composition), not per batch;
+  - the pad plan is computed from the dataset up front, not per batch.
+    Where batch membership is FIXED (a loader that does not shuffle, or
+    one that only permutes the order of the same ``samples[b*bs:(b+1)*bs]``
+    chunks: ``cache_device_batches``, or ``fixed_membership`` for the
+    scan-epoch path) the batches that will ever exist are known at
+    construction, and the plan is the largest of them
+    (``plan == "fixed_membership"``). A loader that re-draws membership
+    every epoch cannot know its batches, and pads to the worst case:
+    the ``batch_size`` largest graphs of the dataset in one batch
+    (``plan == "worst_case"``, :func:`pad_plan_for`);
   - per-epoch shuffling is seeded (epoch number = reference
     ``sampler.set_epoch``, train_validate_test.py:113-115);
   - multi-host sharding = stride-sharding the sample list per process
@@ -34,26 +42,42 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _plan_for_totals(
+    nodes: int, edges: int, graphs: int, node_multiple: int, edge_multiple: int
+) -> tuple:
+    """(n_node_pad, n_edge_pad, n_graph_pad) for a batch of ``graphs``
+    graphs holding ``nodes`` nodes and ``edges`` edges: one padding node
+    and one padding graph beyond them, rounded to the multiples."""
+    return (
+        _round_up(nodes + 1, node_multiple),
+        max(_round_up(edges + 1, edge_multiple), edge_multiple),
+        graphs + 1,
+    )
+
+
 def pad_plan_for(
     samples: Sequence[GraphSample],
     batch_size: int,
     node_multiple: int = 16,
     edge_multiple: int = 8,
 ) -> tuple:
-    """Static (n_node_pad, n_edge_pad, n_graph_pad) covering any batch of
+    """Static (n_node_pad, n_edge_pad, n_graph_pad) covering ANY batch of
     ``batch_size`` samples drawn from ``samples``.
 
     Worst case is the ``batch_size`` largest graphs landing in one batch;
-    bounding by that keeps every epoch's batches one compiled shape.
+    bounding by that keeps every epoch's batches one compiled shape
+    whatever the shuffle draws. The serving ladder
+    (:func:`bucket_pad_plans`) rests on this guarantee; a ``GraphLoader``
+    whose membership is fixed plans tighter (:func:`_largest_batch`).
     """
     nodes = sorted((s.num_nodes for s in samples), reverse=True)
     edges = sorted((s.num_edges for s in samples), reverse=True)
-    worst_nodes = sum(nodes[:batch_size])
-    worst_edges = sum(edges[:batch_size])
-    return (
-        _round_up(worst_nodes + 1, node_multiple),
-        max(_round_up(worst_edges + 1, edge_multiple), edge_multiple),
-        batch_size + 1,
+    return _plan_for_totals(
+        sum(nodes[:batch_size]),
+        sum(edges[:batch_size]),
+        batch_size,
+        node_multiple,
+        edge_multiple,
     )
 
 
@@ -130,6 +154,21 @@ class GraphLoader:
         per-epoch host batching + H2D transfer from the hot loop — the win
         is large when the host->device link is slow — at the cost of
         coarser shuffling (batch membership is fixed after epoch 0).
+      fixed_membership: the run will consume this shuffling loader
+        through ``stacked_device_batches`` (scan-epoch dispatch), which
+        permutes batch ORDER only. The loader then keeps membership fixed
+        on EVERY path — ``__iter__`` draws the same
+        ``samples[b*bs:(b+1)*bs]`` chunks in an epoch-seeded order, so a
+        run that falls back to per-step dispatch meets no other batch —
+        and cuts its pad plan to those batches. Without effect when
+        ``scan_reshuffle_every`` asks for membership-level reshuffling.
+
+    ``plan`` says which pad plan the loader got: ``"fixed_membership"``
+    (no shuffle, ``cache_device_batches`` or ``fixed_membership``, and
+    ``scan_reshuffle_every`` 0: the largest batch that will be built, over
+    every shard and sub-batch) or ``"worst_case"`` (:func:`pad_plan_for`).
+    ``real_nodes_max`` / ``real_edges_max`` / ``aligned_edges_max`` are
+    the sums the plan was cut to.
     """
 
     def __init__(
@@ -149,6 +188,7 @@ class GraphLoader:
         scan_reshuffle_every: int = 0,
         dense_slots: bool | int = True,
         run_align: bool | int = True,
+        fixed_membership: bool = False,
     ):
         if device_stack > 1 and batch_size % device_stack != 0:
             raise ValueError(
@@ -160,12 +200,9 @@ class GraphLoader:
         # runs the same number of jitted steps — required for cross-host
         # collectives to stay in lockstep.
         n = len(self.all_samples)
-        if num_shards > 1 and n > 0:
-            per_shard = math.ceil(n / num_shards)
-            idx = [(shard_rank + k * num_shards) % n for k in range(per_shard)]
-            self.samples = [self.all_samples[i] for i in idx]
-        else:
-            self.samples = list(self.all_samples)
+        self.samples = [
+            self.all_samples[i] for i in _shard_indices(n, num_shards, shard_rank)
+        ]
         self.batch_size = batch_size
         self.shuffle = shuffle
         self.seed = seed
@@ -173,6 +210,12 @@ class GraphLoader:
         self.drop_last = drop_last
         self.cache_device_batches = cache_device_batches
         self.scan_reshuffle_every = scan_reshuffle_every
+        # Membership is fixed when every path builds the same
+        # samples[b*bs:(b+1)*bs] chunks in every epoch; the batches that
+        # will exist are then known here, and the plan is cut to them.
+        self.fixed_membership = not shuffle or (
+            scan_reshuffle_every == 0 and (cache_device_batches or fixed_membership)
+        )
         # an explicit argument wins; HYDRAGNN_NUM_PREFETCH sets the default
         if prefetch is None:
             raw = knobs.get_str("HYDRAGNN_NUM_PREFETCH", "2")
@@ -193,9 +236,24 @@ class GraphLoader:
         self._epoch = 0
         sub = batch_size // device_stack
         # Pad plan from the FULL dataset, not the local shard: all hosts
-        # must compile identical batch shapes.
-        self.pad_nodes, self.pad_edges, self.pad_graphs = pad_plan_for(
-            self.all_samples, sub, node_multiple, edge_multiple
+        # must compile identical batch shapes. The largest (sub-)batch is
+        # the ``sub`` largest graphs in one batch where membership is
+        # re-drawn (pad_plan_for's worst case), and the largest chunk of
+        # ANY shard where it is fixed.
+        drawn = None
+        if self.fixed_membership:
+            take = len(self) * batch_size  # drop_last never draws the rest
+            drawn = [_shard_indices(n, num_shards, r)[:take] for r in range(num_shards)]
+
+        self.real_nodes_max = _largest_batch(
+            [s.num_nodes for s in self.all_samples], sub, drawn
+        )
+        self.real_edges_max = _largest_batch(
+            [s.num_edges for s in self.all_samples], sub, drawn
+        )
+        self.aligned_edges_max = None
+        self.pad_nodes, self.pad_edges, self.pad_graphs = _plan_for_totals(
+            self.real_nodes_max, self.real_edges_max, sub, node_multiple, edge_multiple
         )
         # dense slot count = dataset max in-degree (static across batches
         # AND hosts — derived from the full dataset like the pad plan).
@@ -224,7 +282,7 @@ class GraphLoader:
         # K = 8 whenever the dense map is off (they answer the same
         # scatter-cost problem; dense wins for tight degree
         # distributions, run-align for wide ones) and the dataset has
-        # edges. The pad plan widens to the ALIGNED worst case. An int
+        # edges. The pad plan widens to the ALIGNED largest batch. An int
         # pins K; False/0 disables.
         if run_align is True:
             self.run_align = 8 if self.dense_slots is None else 0
@@ -240,8 +298,8 @@ class GraphLoader:
             if aligned is None:
                 self.run_align = 0  # no edge_index anywhere — nothing to align
             else:
-                sub = batch_size // device_stack
-                worst = sorted(aligned, reverse=True)[:sub]
+                self.aligned_edges_max = _largest_batch(aligned, sub, drawn)
+                need = max(self.aligned_edges_max + 1, self.pad_edges)
                 # Align the edge pad so the Pallas kernel grids divide it
                 # evenly at BOTH scales they run on — E rows (gathers /
                 # local sums) and E/K rows (pre-reduced segment ops).
@@ -260,7 +318,7 @@ class GraphLoader:
 
                 grid_mult = self.run_align * _kernel_ce
                 mult = math.lcm(edge_multiple, self.run_align)
-                if max(sum(worst) + 1, self.pad_edges) >= 8 * grid_mult:
+                if need >= 8 * grid_mult:
                     mult = math.lcm(edge_multiple, grid_mult)
                     # The fused gather+stats kernel additionally needs
                     # E % _BCAST_CE == 0 and _BCAST_CE % K == 0
@@ -282,9 +340,7 @@ class GraphLoader:
                             RuntimeWarning,
                             stacklevel=2,
                         )
-                self.pad_edges = _round_up(
-                    max(sum(worst) + 1, self.pad_edges), mult
-                )
+                self.pad_edges = _round_up(need, mult)
         # Local-window block target: sized to the DATASET's mean graph
         # (capped by the [B, H] VMEM accumulator), so one kernel block
         # covers whole graphs and large graphs don't re-scan their edge
@@ -300,6 +356,10 @@ class GraphLoader:
         # landed, and larger blocks cost VMEM for nothing
         self.win_block_rows = min(512, _round_up(max(mean_nodes, 128), 128))
         self._dicts = samples_to_graph_dicts(self.samples)
+
+    @property
+    def plan(self) -> str:
+        return "fixed_membership" if self.fixed_membership else "worst_case"
 
     def set_epoch(self, epoch: int) -> None:
         self._epoch = epoch
@@ -355,13 +415,48 @@ class GraphLoader:
         stamp in ``train/loop.py``) peek here so loader wrappers that
         count ``__iter__`` draws (epoch schedulers, fault-injection
         harnesses) only ever see real epochs."""
-        order = self._order()
-        return self._place(self._make_batch(order[: self.batch_size]))
+        if self.shuffle and self.fixed_membership:
+            first = self._chunks()[0]
+        else:
+            first = self._order()[: self.batch_size]
+        return self._place(self._make_batch(first))
+
+    def _batch_order(self) -> np.ndarray:
+        """This epoch's order of the fixed ``samples[b*bs:(b+1)*bs]``
+        chunks. ``cache_device_batches`` keeps its draw: an epoch-seeded
+        permutation of ALL its batches, a partial one landing anywhere.
+        A ``fixed_membership`` loader permutes its full batches (the
+        draw of ``train_epoch_scan`` when there is no partial batch)
+        and keeps a partial one last, so that ``__iter__`` can cut
+        ``_order()`` every ``batch_size``."""
+        nb = len(self)
+        if not self.shuffle:
+            return np.arange(nb)
+        rng = np.random.default_rng(self.seed + self._epoch)
+        if self.cache_device_batches:
+            return rng.permutation(nb)
+        full = len(self.samples) // self.batch_size
+        return np.append(rng.permutation(full), np.arange(full, nb))
+
+    def _chunks(self) -> list:
+        """This epoch's batches of a fixed-membership loader, as indices
+        into ``samples``, in the order they are drawn."""
+        bs = self.batch_size
+        base = np.arange(len(self.samples))
+        return [base[b * bs : (b + 1) * bs] for b in self._batch_order()]
 
     def _order(self) -> np.ndarray:
+        """Sample order of the current epoch: ``__iter__`` builds batch
+        ``b`` from ``_order()[b*bs:(b+1)*bs]`` (the cached path yields
+        ``_chunks()``, the same cut unless a partial batch was drawn
+        before the last place)."""
         n = len(self.samples)
         if not self.shuffle:
             return np.arange(n)
+        if self.fixed_membership:
+            # samples that drop_last never draws close the order
+            rest = np.arange(len(self) * self.batch_size, n)
+            return np.concatenate(self._chunks() + [rest])
         rng = np.random.default_rng(self.seed + self._epoch)
         return rng.permutation(n)
 
@@ -432,12 +527,7 @@ class GraphLoader:
                     self._place(self._make_batch(base[b * bs : (b + 1) * bs]))
                     for b in range(nb)
                 ]
-            if self.shuffle:
-                rng = np.random.default_rng(self.seed + self._epoch)
-                batch_order = rng.permutation(nb)
-            else:
-                batch_order = np.arange(nb)
-            for b in batch_order:
+            for b in self._batch_order():
                 yield self._cached_batches[b]
             return
         # Prefetch accounting into the shared telemetry registry
@@ -559,6 +649,33 @@ class GraphLoader:
             self._stacked = jax.device_put(stacked, self._sharding)
             self._stacked_key = key
         return self._stacked
+
+
+def _shard_indices(n: int, num_shards: int, shard_rank: int) -> np.ndarray:
+    """Indices into the full sample list that shard ``shard_rank`` sees:
+    stride-sharded, wrapping around to ``ceil(n / num_shards)`` each."""
+    if num_shards > 1 and n > 0:
+        per_shard = math.ceil(n / num_shards)
+        return (shard_rank + np.arange(per_shard) * num_shards) % n
+    return np.arange(n)
+
+
+def _largest_batch(sizes, sub: int, drawn=None) -> int:
+    """Largest sum of per-sample ``sizes`` that one (sub-)batch of ``sub``
+    samples can hold. ``drawn`` None: any ``sub`` samples can meet, so
+    the ``sub`` largest. Else the sample indices each shard draws, in
+    order: batches are consecutive chunks of them, and a chunk's
+    sub-batches consecutive windows of ``sub``."""
+    sizes = np.asarray(sizes, dtype=np.int64)
+    if drawn is None:
+        return int(np.sort(sizes)[::-1][:sub].sum())
+    worst = 0
+    for idx in drawn:
+        mine = sizes[idx]
+        if mine.size:
+            mine = np.pad(mine, (0, -mine.size % sub))
+            worst = max(worst, int(mine.reshape(-1, sub).sum(axis=1).max()))
+    return worst
 
 
 def _aligned_edge_counts(samples, k: int):

@@ -106,6 +106,42 @@ class ParallelConfig:
     def num_devices(self) -> int:
         return self.data * self.fsdp * self.edge
 
+    @property
+    def single_device(self) -> bool:
+        """A plain single-device run: no mesh, no sharded step. The one
+        answer that ``Partitioner.single_device`` gives the loop and that
+        ``api.create_dataloaders`` needs before a partitioner exists."""
+        return self.num_devices == 1
+
+    @classmethod
+    def from_config(
+        cls, nn_config: Dict[str, Any], device_stack: int = 1, multihost: bool = False
+    ) -> "ParallelConfig":
+        """Axis widths from a ``NeuralNetwork`` config section and the
+        PER-PROCESS batch device axis the loaders are built with
+        (``Partitioner.from_config`` says what each means)."""
+        par = dict(nn_config.get("Parallel") or {})
+        fsdp = int(par.get("fsdp", 1) or 1)
+        edge = int(par.get("edge", 1) or 1)
+        zero1 = bool(
+            nn_config.get("Training", {})
+            .get("Optimizer", {})
+            .get("use_zero_redundancy", False)
+        )
+        if device_stack % fsdp:
+            raise ValueError(
+                f"Parallel.fsdp={fsdp} must divide the batch device axis "
+                f"(device_stack={device_stack}); pick an fsdp width that "
+                "divides the local data-parallel width"
+            )
+        nproc = jax.process_count() if multihost else 1
+        data = (device_stack // fsdp) * nproc
+        if fsdp > 1 and zero1:
+            # fsdp shards the optimizer state (and the parameters) over
+            # its own axis — the ZeRO-1 special case is subsumed
+            zero1 = False
+        return cls(data=data, fsdp=fsdp, edge=edge, zero1=zero1)
+
 
 class Partitioner:
     """Owns the mesh and every sharding decision of a run.
@@ -157,28 +193,8 @@ class Partitioner:
         multi-host meshes this also keeps every fsdp all-gather
         intra-host. ``Training.Optimizer.use_zero_redundancy`` maps to
         the legacy ZeRO-1 layout and is subsumed when ``fsdp > 1``."""
-        par = dict(nn_config.get("Parallel") or {})
-        fsdp = int(par.get("fsdp", 1) or 1)
-        edge = int(par.get("edge", 1) or 1)
-        zero1 = bool(
-            nn_config.get("Training", {})
-            .get("Optimizer", {})
-            .get("use_zero_redundancy", False)
-        )
-        if device_stack % fsdp:
-            raise ValueError(
-                f"Parallel.fsdp={fsdp} must divide the batch device axis "
-                f"(device_stack={device_stack}); pick an fsdp width that "
-                "divides the local data-parallel width"
-            )
-        nproc = jax.process_count() if multihost else 1
-        data = (device_stack // fsdp) * nproc
-        if fsdp > 1 and zero1:
-            # fsdp shards the optimizer state (and the parameters) over
-            # its own axis — the ZeRO-1 special case is subsumed
-            zero1 = False
         return cls(
-            ParallelConfig(data=data, fsdp=fsdp, edge=edge, zero1=zero1),
+            ParallelConfig.from_config(nn_config, device_stack, multihost),
             devices=devices,
             multihost=multihost,
         )
@@ -250,8 +266,9 @@ class Partitioner:
     def single_device(self) -> bool:
         """True when this partitioner describes a plain single-device run
         — the signal scan-epoch eligibility and serve's fast path use
-        instead of sniffing meshes themselves."""
-        return self.mesh is None or self.mesh.size == 1
+        instead of sniffing meshes themselves (the mesh holds
+        ``config.num_devices`` devices, and none is built for one)."""
+        return self.config.single_device
 
     @property
     def num_devices(self) -> int:

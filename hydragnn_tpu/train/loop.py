@@ -503,14 +503,16 @@ def _stack_refusal(loader) -> Optional[str]:
     return None
 
 
-def _scan_auto_eligible(loader, partitioner=None) -> Tuple[bool, str]:
+def _scan_auto_eligible(
+    loader, config: Dict[str, Any], partitioner=None, profiler=None
+) -> Tuple[bool, str]:
     """Is the whole-epoch scan dispatch the right DEFAULT here?
     (``Training.scan_epoch`` unset — an explicit true/false always
-    wins.) Eligible = single-device mesh + a loader that can stack the
-    split device-resident + no feature that inherently needs batch
-    granularity (step-indexed fault injection). Returns (eligible,
-    human-readable reason) — the reason lands in the flight manifest's
-    ``dispatch_mode`` field either way.
+    wins.) Eligible = a loader that can stack the split device-resident
+    + what :func:`scan_dispatch_planned` asks of topology, environment
+    and configuration (``config``: the ``NeuralNetwork`` section).
+    Returns (eligible, human-readable reason) — the reason lands in the
+    flight manifest's ``dispatch_mode`` field either way.
 
     ``partitioner`` (hydragnn_tpu/parallel/partitioner.py) is the
     authoritative topology signal when given: the scan path trusts
@@ -520,27 +522,57 @@ def _scan_auto_eligible(loader, partitioner=None) -> Tuple[bool, str]:
         loader, "shuffle"
     ):
         return False, "loader cannot stack device-resident batches"
-    if partitioner is not None:
-        if not partitioner.single_device:
-            return False, "partitioner mesh is multi-device"
-    elif getattr(loader, "device_stack", 1) != 1:
+    if partitioner is None and getattr(loader, "device_stack", 1) != 1:
         return False, "multi-device stacked loader (sharded mesh)"
-    if jax.process_count() > 1:
-        return False, "multi-process run"
     try:
         if len(loader) < 1:
             return False, "empty loader"
     except TypeError:
         return False, "unsized loader"
+    return scan_dispatch_planned(
+        config,
+        single_device=partitioner is None or partitioner.single_device,
+        profiler=profiler,
+    )
+
+
+def scan_dispatch_planned(
+    config: Dict[str, Any], single_device: bool, profiler=None
+) -> Tuple[bool, str]:
+    """Will a loop-owned run of this ``NeuralNetwork`` configuration train
+    through the whole-epoch scan? The part of the dispatch-mode
+    resolution that configuration, environment and topology decide,
+    WITHOUT a loader: ``train_validate_test`` resolves its mode through
+    it, and ``api.create_dataloaders`` asks it before the train loader
+    exists, so that the loader a scan will consume is built with its
+    membership fixed (``GraphLoader(fixed_membership=True)``). A sharded
+    run brings its own step and never scans (``single_device`` false);
+    else an explicit ``Training.scan_epoch`` wins; unset, the scan is the
+    default in one process unless a feature needs batch granularity. What
+    only the loader can say (``_scan_auto_eligible``, ``_stack_refusal``)
+    stays with the loop."""
+    if not single_device:
+        return False, "partitioner mesh is multi-device"
+    training = config["Training"]
+    scan_cfg = training.get("scan_epoch")
+    if scan_cfg is not None:
+        return bool(scan_cfg), f"Training.scan_epoch={'true' if scan_cfg else 'false'}"
+    if jax.process_count() > 1:
+        return False, "multi-process run"
     inject = knobs.active_injections(include_serve=False)
     if inject:
         # deterministic fault injection is step-indexed — it needs the
         # per-step path's batch granularity to fire at the right step
         return False, f"fault injection active ({inject[0]})"
-    if knobs.get_float("HYDRAGNN_WATCHDOG_S", 0.0) > 0:
+    if (
+        knobs.get_float("HYDRAGNN_WATCHDOG_S", 0.0) > 0
+        or float(training.get("watchdog_stall_s", 0) or 0) > 0
+    ):
         # the watchdog heartbeats at batch granularity; a whole-epoch
         # dispatch would read as a stall
         return False, "hang watchdog active"
+    if profiler is not None or "Profile" in config:
+        return False, "per-step profiler configured"
     return True, "single-device mesh + device-resident stacked loader"
 
 
@@ -620,22 +652,24 @@ def train_validate_test(
             use_scan, dispatch_reason = False, "caller-supplied train step"
         elif scan_cfg is None:
             use_scan, dispatch_reason = _scan_auto_eligible(
-                train_loader, partitioner=partitioner
+                train_loader, config, partitioner=partitioner, profiler=profiler
             )
-            if use_scan and (profiler is not None or "Profile" in config):
-                use_scan, dispatch_reason = False, "per-step profiler configured"
-            if use_scan and float(training.get("watchdog_stall_s", 0) or 0) > 0:
-                use_scan, dispatch_reason = False, "hang watchdog active"
             if use_scan:
                 # the stack must actually materialize, or the run goes
                 # per-step and says why
                 refusal = _stack_refusal(train_loader)
                 if refusal is not None:
                     use_scan, dispatch_reason = False, f"stacking failed: {refusal}"
-        elif scan_cfg:
-            use_scan, dispatch_reason = True, "Training.scan_epoch=true"
         else:
-            use_scan, dispatch_reason = False, "Training.scan_epoch=false"
+            use_scan, dispatch_reason = scan_dispatch_planned(config, single_device=True)
+        if (
+            not use_scan
+            and getattr(train_loader, "shuffle", False)
+            and getattr(train_loader, "fixed_membership", False)
+        ):
+            # built for a scan (or with cache_device_batches) and iterated
+            # per step: the loader keeps its batches and shuffles their order
+            dispatch_reason += "; train batches keep their membership, only their order is shuffled"
         # Non-finite guard (hydragnn_tpu/resilience/sentry.py): folded into
         # the loop-owned step in BOTH dispatch modes — per-step via the
         # guarded jitted step, scan via the guarded scan body threading the
@@ -1127,6 +1161,15 @@ def train_validate_test(
                 "pad_nodes": getattr(ld, "pad_nodes", None),
                 "pad_edges": getattr(ld, "pad_edges", None),
                 "pad_graphs": getattr(ld, "pad_graphs", None),
+                # which plan (data/loader.py): cut to the batches that exist
+                # ("fixed_membership") or to the worst case, and the largest
+                # (sub-)batch it was cut to
+                "plan": getattr(ld, "plan", None),
+                "real_nodes_max": getattr(ld, "real_nodes_max", None),
+                "real_edges_max": getattr(ld, "real_edges_max", None),
+                # the edge layout the loader's AUTO chose under that plan
+                "dense_slots": getattr(ld, "dense_slots", None),
+                "run_align": getattr(ld, "run_align", None),
             }
 
         _dev0 = jax.devices()[0]

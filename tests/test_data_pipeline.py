@@ -424,3 +424,246 @@ def pytest_descriptors_grow_edge_dim():
     train2, val2, test2, _, _ = prepare_dataset(samples2, config2)
     with pytest.raises(ValueError, match="edge_features"):
         update_config(config2, train2, val2, test2)
+
+
+# ---------------------------------------------------------------------------
+# pad plans: cut to the batches that exist where membership is fixed, the
+# worst case (pad_plan_for) where every epoch re-draws it
+# ---------------------------------------------------------------------------
+
+
+def _ring_samples(n_samples, lo, hi, degree, seed=11):
+    """Heterogeneous graphs: ``lo..hi`` nodes, every node ``degree`` in-edges."""
+    from hydragnn_tpu.data.dataset import GraphSample
+
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n_samples):
+        n = int(rng.integers(lo, hi + 1))
+        recv = np.repeat(np.arange(n), degree)
+        send = (recv + np.tile(np.arange(1, degree + 1), n)) % n
+        out.append(
+            GraphSample(
+                x=rng.standard_normal((n, 2)).astype(np.float32),
+                edge_index=np.stack([send, recv]).astype(np.int32),
+                graph_targets={"e": rng.standard_normal(1).astype(np.float32)},
+            )
+        )
+    return out
+
+
+# how the run consumes the loader -> (constructor arguments, drawing the
+# epoch's batches); "stacked" and "reshuffle" are loaders built for scan
+_PLAN_PATHS = {
+    "iter": dict(),
+    "cache": dict(cache_device_batches=True),
+    "stacked": dict(fixed_membership=True),
+    "reshuffle": dict(fixed_membership=True, scan_reshuffle_every=1),
+}
+
+
+def _membership_is_fixed(shuffle, path):
+    return not shuffle or path in ("cache", "stacked")
+
+
+def _epoch_batches(loader, path, epoch):
+    """Every (sub-)batch of one epoch as (real nodes, real edges, graphs)."""
+    loader.set_epoch(epoch)
+    if path in ("stacked", "reshuffle"):
+        st = loader.stacked_device_batches(epoch)
+        masks = [np.asarray(m) for m in (st.node_mask, st.edge_mask, st.graph_mask)]
+    else:
+        batches = list(loader)
+        masks = [np.stack([np.asarray(getattr(b, f)) for b in batches])
+                 for f in ("node_mask", "edge_mask", "graph_mask")]
+    # [batches, (devices,) slots] -> one row a (sub-)batch
+    nm, em, gm = (m.reshape(-1, m.shape[-1]) for m in masks)
+    return nm.sum(1), em.sum(1), gm.sum(1), nm.shape[1], em.shape[1]
+
+
+def _expected_plan(samples, bs, stack, num_shards, run_align, fixed, drop_last=False):
+    """The plan from first principles: every chunk of every shard, or the
+    ``sub`` largest graphs in one batch."""
+    import math
+
+    from hydragnn_tpu.data.loader import _aligned_edge_counts, pad_plan_for
+
+    sub = bs // stack
+    nodes = [s.num_nodes for s in samples]
+    edges = [s.num_edges for s in samples]
+    aligned = _aligned_edge_counts(samples, run_align) if run_align > 1 else edges
+    if not fixed:
+        pn, pe, pg = pad_plan_for(samples, sub)
+        top = lambda v: sum(sorted(v, reverse=True)[:sub])  # noqa: E731
+        return pn, pe, pg, top(nodes), top(edges), top(aligned)
+    n = len(samples)
+    per = math.ceil(n / num_shards) if num_shards > 1 else n
+    best = [0, 0, 0]
+    for r in range(num_shards):
+        idx = [(r + k * num_shards) % n for k in range(per)] if num_shards > 1 else list(range(n))
+        nb = per // bs if drop_last else math.ceil(per / bs)
+        for b in range(nb):
+            chunk = idx[b * bs : (b + 1) * bs]
+            for d in range(stack):
+                part = chunk[d * sub : (d + 1) * sub]
+                for j, v in enumerate((nodes, edges, aligned)):
+                    best[j] = max(best[j], sum(v[i] for i in part))
+    r16 = lambda x, m: -(-x // m) * m  # noqa: E731
+    return r16(best[0] + 1, 16), r16(best[1] + 1, 8), sub + 1, best[0], best[1], best[2]
+
+
+@pytest.mark.parametrize("run_align", [0, 8])
+@pytest.mark.parametrize("num_shards", [1, 2])
+@pytest.mark.parametrize("device_stack", [1, 2])
+@pytest.mark.parametrize("path", sorted(_PLAN_PATHS))
+@pytest.mark.parametrize("shuffle", [False, True])
+def pytest_pad_plan_follows_membership(shuffle, path, device_stack, num_shards, run_align):
+    """Fixed membership -> the plan is the largest batch actually built (over
+    every shard and sub-batch); re-drawn membership -> pad_plan_for's worst
+    case. Either way every batch of three epochs fits and all shards agree."""
+    samples = _ring_samples(45, 4, 40, degree=3)
+    bs = 8
+    loaders = [
+        GraphLoader(
+            samples, bs, shuffle=shuffle, seed=2, num_shards=num_shards, shard_rank=r,
+            device_stack=device_stack, dense_slots=False, run_align=run_align,
+            prefetch=0, **_PLAN_PATHS[path],
+        )
+        for r in range(num_shards)
+    ]
+    fixed = _membership_is_fixed(shuffle, path)
+    pn, pe, pg, rn, re_, ra = _expected_plan(
+        samples, bs, device_stack, num_shards, run_align, fixed
+    )
+    if run_align > 1:
+        pe = -(-max(ra + 1, pe) // 8) * 8  # below the kernels' scale: lcm(8, K)
+    seen_nodes = seen_edges = 0
+    for ld in loaders:
+        assert ld.plan == ("fixed_membership" if fixed else "worst_case")
+        assert (ld.pad_nodes, ld.pad_edges, ld.pad_graphs) == (pn, pe, pg)
+        assert (ld.real_nodes_max, ld.real_edges_max) == (rn, re_)
+        assert ld.aligned_edges_max == (ra if run_align > 1 else None)
+        membership = set()
+        for epoch in range(3):
+            # batch_graphs raises on a batch over its plan: drawing is the check
+            nodes, edges, graphs, n_pad, e_pad = _epoch_batches(ld, path, epoch)
+            assert (n_pad, e_pad) == (pn, pe)
+            assert int(graphs.sum()) == ld.num_samples
+            assert nodes.max() < pn and edges.max() <= pe
+            seen_nodes = max(seen_nodes, int(nodes.max()))
+            seen_edges = max(seen_edges, int(edges.max()))
+            membership.add(tuple(sorted(zip(nodes.tolist(), edges.tolist()))))
+        if fixed:
+            assert len(membership) == 1  # the same batches in every epoch
+    if fixed:
+        # tight: some batch that was built reaches the plan's real maxima
+        assert (seen_nodes, seen_edges) == (rn, re_)
+
+
+@pytest.mark.parametrize("device_stack", [1, 2])
+@pytest.mark.parametrize("fixed", [False, True])
+def pytest_pad_plan_grid_rounding_at_scale(fixed, device_stack):
+    """At the kernels' scale the edge pad stays a multiple of
+    lcm(run_align*CE, _BCAST_CE) under either plan, so no pallas_call pads
+    its input and gather_presum stays eligible."""
+    import math
+
+    from hydragnn_tpu.ops.segment_pallas import _BCAST_CE, CE
+
+    samples = _ring_samples(200, 30, 90, degree=20)
+    ld = GraphLoader(
+        samples, 64, shuffle=True, device_stack=device_stack, dense_slots=False,
+        run_align=8, fixed_membership=fixed,
+    )
+    assert ld.plan == ("fixed_membership" if fixed else "worst_case")
+    assert ld.aligned_edges_max + 1 >= 8 * 8 * CE  # the case under test
+    assert ld.pad_edges % math.lcm(8 * CE, _BCAST_CE) == 0
+    assert ld.pad_edges - ld.aligned_edges_max <= math.lcm(8 * CE, _BCAST_CE)
+    worst = GraphLoader(samples, 64, shuffle=True, device_stack=device_stack,
+                        dense_slots=False, run_align=8)
+    assert ld.pad_edges <= worst.pad_edges and ld.pad_nodes <= worst.pad_nodes
+    assert fixed or (ld.pad_nodes, ld.pad_edges) == (worst.pad_nodes, worst.pad_edges)
+
+
+@pytest.mark.parametrize("drop_last", [False, True])
+@pytest.mark.parametrize("device_stack", [1, 2])
+def pytest_scan_built_loader_iterated_per_step(device_stack, drop_last):
+    """The _stack_refusal fallback: a train loader built for the scan and then
+    iterated per step draws the SAME chunks the stack holds, in an
+    epoch-seeded order, never overflows its plan, and _order() is what
+    __iter__ drew (benchmark/taps.py rebuilds membership from it)."""
+    samples = _ring_samples(45, 4, 40, degree=3)
+    bs = 8
+    ld = GraphLoader(samples, bs, shuffle=True, seed=5, device_stack=device_stack,
+                     dense_slots=False, prefetch=0, drop_last=drop_last,
+                     fixed_membership=True)
+    loose = GraphLoader(samples, bs, shuffle=True, seed=5, device_stack=device_stack,
+                        dense_slots=False, drop_last=drop_last)
+    assert ld.plan == "fixed_membership" and loose.plan == "worst_case"
+    assert ld.pad_edges < loose.pad_edges and ld.pad_nodes < loose.pad_nodes
+    nb = len(ld)
+    chunks = [frozenset(range(b * bs, min((b + 1) * bs, len(samples)))) for b in range(nb)]
+    orders = set()
+    for epoch in range(3):
+        ld.set_epoch(epoch)
+        order = ld._order()
+        assert sorted(order.tolist()) == list(range(len(samples)))
+        drawn = [frozenset(order[b * bs : (b + 1) * bs].tolist()) for b in range(nb)]
+        assert sorted(map(sorted, drawn)) == sorted(map(sorted, chunks))
+        orders.add(tuple(order.tolist()))
+        # what __iter__ builds IS _order()'s chunks (node counts identify them)
+        want = [sum(samples[i].num_nodes for i in order[b * bs : (b + 1) * bs]) for b in range(nb)]
+        got = [int(np.asarray(b.node_mask).sum()) for b in ld]
+        assert got == want
+        peek = ld.peek_batch()
+        assert int(np.asarray(peek.node_mask).sum()) == want[0]
+    assert len(orders) == 3  # the batch order is re-drawn every epoch
+    # and the stack it was built for holds the same chunks
+    st = ld.stacked_device_batches(0)
+    per_batch = np.asarray(st.node_mask).reshape(nb, -1).sum(1).tolist()
+    assert per_batch == [sum(samples[i].num_nodes for i in sorted(c)) for c in chunks]
+
+
+@pytest.mark.parametrize("drop_last", [False, True])
+def pytest_cached_loader_keeps_its_draw(drop_last):
+    """cache_device_batches draws as it always did: an epoch-seeded
+    permutation of ALL its batches, a partial one landing anywhere. Its plan
+    is cut to those batches, and peek_batch is the first of them."""
+    samples = _ring_samples(45, 4, 40, degree=3)
+    bs = 8
+    ld = GraphLoader(samples, bs, shuffle=True, seed=5, dense_slots=False,
+                     drop_last=drop_last, cache_device_batches=True)
+    assert ld.plan == "fixed_membership"
+    nb = len(ld)
+    per_chunk = [sum(s.num_nodes for s in samples[b * bs : (b + 1) * bs]) for b in range(nb)]
+    partial_moved = False
+    for epoch in range(4):
+        ld.set_epoch(epoch)
+        drawn = np.random.default_rng(5 + epoch).permutation(nb)
+        got = [int(np.asarray(b.node_mask).sum()) for b in ld]
+        assert got == [per_chunk[b] for b in drawn]
+        assert int(np.asarray(ld.peek_batch().node_mask).sum()) == got[0]
+        order = ld._order()
+        assert sorted(order.tolist()) == list(range(len(samples)))
+        in_batches = [i for b in drawn for i in range(b * bs, min((b + 1) * bs, len(samples)))]
+        assert order[: len(in_batches)].tolist() == in_batches
+        partial_moved |= not drop_last and drawn[-1] != nb - 1
+    assert partial_moved or drop_last  # the case the fixed order would change
+
+
+def pytest_worst_case_plan_is_pad_plan_for():
+    """pad_plan_for and bucket_pad_plans return what they returned, and a
+    per-step shuffled loader's plan is pad_plan_for's, bit for bit."""
+    from hydragnn_tpu.data.loader import bucket_pad_plans, pad_plan_for
+
+    samples = _ring_samples(45, 4, 40, degree=3)
+    nodes = sorted((s.num_nodes for s in samples), reverse=True)
+    edges = sorted((s.num_edges for s in samples), reverse=True)
+    want = (-(-(sum(nodes[:8]) + 1) // 16) * 16, -(-(sum(edges[:8]) + 1) // 8) * 8, 9)
+    assert pad_plan_for(samples, 8) == want
+    ld = GraphLoader(samples, 8, shuffle=True, dense_slots=False, run_align=False)
+    assert (ld.pad_nodes, ld.pad_edges, ld.pad_graphs) == want
+    cap_n, cap_e = nodes[0], edges[0]
+    (caps, plan), = bucket_pad_plans(samples, 4, num_buckets=1)
+    assert caps == (cap_n, cap_e)
+    assert plan == (-(-(4 * cap_n + 1) // 16) * 16, -(-(4 * cap_e + 1) // 8) * 8, 5)

@@ -45,29 +45,39 @@ def tiny_problem():
 
 
 def pytest_scan_auto_eligibility(tiny_problem, monkeypatch):
-    _, _, _, loader = tiny_problem
-    ok, reason = _scan_auto_eligible(loader)
+    cfg, _, _, loader = tiny_problem
+    nn = cfg["NeuralNetwork"]
+    ok, reason = _scan_auto_eligible(loader, nn)
     assert ok, reason
 
     class NoStack:
         pass
 
-    ok, reason = _scan_auto_eligible(NoStack())
+    ok, reason = _scan_auto_eligible(NoStack(), nn)
     assert not ok and "stack" in reason
 
     monkeypatch.setenv("HYDRAGNN_INJECT_SIGTERM_STEP", "5")
-    ok, reason = _scan_auto_eligible(loader)
+    ok, reason = _scan_auto_eligible(loader, nn)
     assert not ok and "fault injection" in reason
     monkeypatch.delenv("HYDRAGNN_INJECT_SIGTERM_STEP")
 
     # serve-side injection does not force per-step training dispatch
     monkeypatch.setenv("HYDRAGNN_INJECT_SERVE_RAISE", "1")
-    ok, _ = _scan_auto_eligible(loader)
+    ok, _ = _scan_auto_eligible(loader, nn)
     assert ok
     monkeypatch.delenv("HYDRAGNN_INJECT_SERVE_RAISE")
 
+    # the checks that read the configuration and the profiler argument
+    stalled = dict(nn, Training=dict(nn["Training"], watchdog_stall_s=5))
+    ok, reason = _scan_auto_eligible(loader, stalled)
+    assert not ok and "watchdog" in reason
+    ok, reason = _scan_auto_eligible(loader, dict(nn, Profile={"enable": 1}))
+    assert not ok and "profiler" in reason
+    ok, reason = _scan_auto_eligible(loader, nn, profiler=object())
+    assert not ok and "profiler" in reason
+
     monkeypatch.setenv("HYDRAGNN_WATCHDOG_S", "30")
-    ok, reason = _scan_auto_eligible(loader)
+    ok, reason = _scan_auto_eligible(loader, nn)
     assert not ok and "watchdog" in reason
 
 
@@ -78,7 +88,7 @@ def pytest_multi_device_stack_not_eligible(tiny_problem):
     if jax.local_device_count() < 2:
         pytest.skip("needs the virtual multi-device mesh")
     loader = GraphLoader(train, 8, shuffle=False, device_stack=2)
-    ok, reason = _scan_auto_eligible(loader)
+    ok, reason = _scan_auto_eligible(loader, cfg["NeuralNetwork"])
     assert not ok and "multi-device" in reason
 
 
@@ -93,6 +103,20 @@ def _read_manifest(log_dir):
     man = [e for e in events if e.get("kind") == "run_start"][0]["manifest"]
     epochs = [e for e in events if e.get("kind") == "epoch"]
     return man, epochs
+
+
+def _assert_pad_plans(man, train_plan):
+    """The manifest says which pad plan each loader got and the largest
+    batch it was cut to (docs/OBSERVABILITY.md): validation and test never
+    shuffle, the train loader's plan follows the dispatch mode."""
+    plans = man["pad_plans"]
+    assert plans["train"]["plan"] == train_plan
+    assert plans["val"]["plan"] == plans["test"]["plan"] == "fixed_membership"
+    for split in ("train", "val", "test"):
+        p = plans[split]
+        assert 0 < p["real_nodes_max"] < p["pad_nodes"]
+        assert 0 < p["real_edges_max"] <= p["pad_edges"]
+        assert p["dense_slots"] is None or p["run_align"] == 0
 
 
 def pytest_auto_scan_default_and_flight_field(tmp_path, monkeypatch):
@@ -114,6 +138,8 @@ def pytest_auto_scan_default_and_flight_field(tmp_path, monkeypatch):
     assert dm["mode"] == "scan_epoch" and dm["auto"] is True, dm
     assert "stacked loader" in dm["reason"]
     assert all(e["step_time"]["mode"] == "scan_epoch" for e in epochs)
+    # the scan permutes batch order only: every plan is cut to real batches
+    _assert_pad_plans(man, "fixed_membership")
 
 
 def pytest_explicit_false_keeps_per_step(tmp_path, monkeypatch):
@@ -130,6 +156,8 @@ def pytest_explicit_false_keeps_per_step(tmp_path, monkeypatch):
     dm = man["dispatch_mode"]
     assert dm["mode"] == "per_step" and dm["auto"] is False
     assert dm["reason"] == "Training.scan_epoch=false"
+    # per-step shuffling re-draws membership: the train plan stays worst-case
+    _assert_pad_plans(man, "worst_case")
     for e in epochs:
         st = e["step_time"]
         # the per-step span decomposition (data-wait / dispatch /
@@ -156,6 +184,97 @@ def pytest_injection_forces_per_step(tmp_path, monkeypatch):
     man, _ = _read_manifest(str(tmp_path) + "/logs")
     dm = man["dispatch_mode"]
     assert dm["mode"] == "per_step" and "fault injection" in dm["reason"]
+    _assert_pad_plans(man, "worst_case")
+
+
+def pytest_scan_built_loader_gone_per_step_says_so(tmp_path, monkeypatch):
+    """The stack is refused after the train loader was built for the scan:
+    the run goes per-step over the loader's fixed batches, and the
+    manifest's dispatch reason says that only their order is shuffled."""
+    monkeypatch.setenv("HYDRAGNN_TELEMETRY", "1")
+    from hydragnn_tpu.api import run_training
+    from hydragnn_tpu.train import loop
+    from test_train_e2e import make_config
+
+    monkeypatch.setattr(loop, "_stack_refusal", lambda loader: "no room (test)")
+    config = make_config("GIN", False, str(tmp_path), num_epoch=2)
+    config["NeuralNetwork"]["Training"]["batch_size"] = 5
+    samples = deterministic_graph_data(number_configurations=30, seed=0)
+    run_training(config, samples=samples, log_dir=str(tmp_path) + "/logs/")
+    man, epochs = _read_manifest(str(tmp_path) + "/logs")
+    dm = man["dispatch_mode"]
+    assert dm["mode"] == "per_step" and dm["auto"] is True
+    assert dm["reason"].startswith("stacking failed: no room (test)")
+    assert "only their order is shuffled" in dm["reason"]
+    _assert_pad_plans(man, "fixed_membership")
+    assert all(e["step_time"]["mode"] == "per_step" for e in epochs)
+
+
+# -- the train loader is built knowing whether its membership is fixed -------
+
+_PLANNED = {
+    # name: (Training keys, NeuralNetwork keys, environment, device_stack, train plan)
+    "default": ({}, {}, {}, 1, "fixed_membership"),
+    "scan_true": ({"scan_epoch": True}, {}, {}, 1, "fixed_membership"),
+    "scan_true_beats_injection": (
+        {"scan_epoch": True}, {}, {"HYDRAGNN_INJECT_NAN_STEP": "9"}, 1, "fixed_membership",
+    ),
+    "scan_false": ({"scan_epoch": False}, {}, {}, 1, "worst_case"),
+    "scan_false_cached": (
+        {"scan_epoch": False, "cache_device_batches": True}, {}, {}, 1, "fixed_membership",
+    ),
+    "reshuffle": ({"scan_reshuffle_every": 1}, {}, {}, 1, "worst_case"),
+    "mesh": ({}, {}, {}, 2, "worst_case"),
+    "mesh_scan_true": ({"scan_epoch": True}, {}, {}, 2, "worst_case"),
+    "edge_sharded": ({}, {"Parallel": {"edge": 2}}, {}, 1, "worst_case"),
+    "injection": ({}, {}, {"HYDRAGNN_INJECT_SIGTERM_STEP": "5"}, 1, "worst_case"),
+    "watchdog_env": ({}, {}, {"HYDRAGNN_WATCHDOG_S": "30"}, 1, "worst_case"),
+    "watchdog_cfg": ({"watchdog_stall_s": 5}, {}, {}, 1, "worst_case"),
+    "profiler": ({}, {"Profile": {"enable": 1}}, {}, 1, "worst_case"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PLANNED))
+def pytest_create_dataloaders_plans_for_the_dispatch(case, monkeypatch):
+    """api.create_dataloaders asks the loop's own question
+    (scan_dispatch_planned) before the loaders exist: the train loader's
+    membership is fixed exactly when the run will scan it, and the loop,
+    asked later with the loader in hand, resolves the same mode."""
+    from hydragnn_tpu.api import create_dataloaders
+    from hydragnn_tpu.train.loop import scan_dispatch_planned
+
+    training, nn_extra, env, device_stack, want = _PLANNED[case]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    config = base_config(multihead=False)
+    config["NeuralNetwork"]["Training"].update(batch_size=6, **training)
+    config["NeuralNetwork"].update(nn_extra)
+    samples = deterministic_graph_data(number_configurations=30, seed=3)
+    train, val, test, _, _ = prepare_dataset(samples, config)
+    train_loader, val_loader, test_loader = create_dataloaders(
+        train, val, test, config, device_stack=device_stack
+    )
+    assert train_loader.plan == want
+    assert val_loader.plan == test_loader.plan == "fixed_membership"
+    worst = GraphLoader(train, 6, shuffle=True, device_stack=device_stack)
+    plan = (train_loader.pad_nodes, train_loader.pad_edges)
+    if want == "worst_case":
+        assert plan == (worst.pad_nodes, worst.pad_edges)  # bit for bit today's
+    else:
+        assert plan[0] <= worst.pad_nodes and plan[1] <= worst.pad_edges
+    nn = config["NeuralNetwork"]
+    edge = (nn.get("Parallel") or {}).get("edge", 1)
+    single = device_stack == 1 and edge == 1
+    if single:
+        planned, reason = scan_dispatch_planned(nn, single_device=True)
+        if nn["Training"].get("scan_epoch") is None:
+            assert (planned, reason) == _scan_auto_eligible(train_loader, nn)
+        fixed = planned and not nn["Training"].get("scan_reshuffle_every")
+        fixed = fixed or bool(nn["Training"].get("cache_device_batches"))
+        assert (want == "fixed_membership") == fixed
+    for epoch in range(2):  # whatever the run does with it, every batch fits
+        train_loader.set_epoch(epoch)
+        assert sum(int(np.asarray(b.graph_mask).sum()) for b in train_loader) == len(train)
 
 
 # -- guarded scan body ------------------------------------------------------

@@ -394,9 +394,10 @@ def pytest_scan_eligibility_uses_partitioner():
     train, _, _, _, _ = prepare_dataset(samples, cfg)
     loader = GraphLoader(train, 4, shuffle=False)
 
-    ok, reason = _scan_auto_eligible(loader, partitioner=Partitioner())
+    nn = cfg["NeuralNetwork"]
+    ok, reason = _scan_auto_eligible(loader, nn, partitioner=Partitioner())
     assert ok, reason
     ok, reason = _scan_auto_eligible(
-        loader, partitioner=Partitioner(data=2, fsdp=4)
+        loader, nn, partitioner=Partitioner(data=2, fsdp=4)
     )
     assert not ok and "partitioner" in reason
