@@ -1,10 +1,13 @@
 """A cell = one configuration file under one workload file.
 
 ``workloads/<cell>.json`` names its configuration (``configs/<name>.json``),
-its chips, its traffic parameters (read by ``datagen.generate``) and the
-``Training`` / ``Architecture`` keys a user of ``run_training`` would set
-for this job (batch size, checkpoint interval, SyncBatchNorm on a mesh).
-Nothing here is specific to one cell: a new cell is a new pair of files.
+its chips, its traffic parameters (read by the family's ``generate``) and
+the ``Training`` / ``Architecture`` keys a user of ``run_training`` would
+set for this job (batch size, checkpoint interval, SyncBatchNorm on a
+mesh). The configuration names its family (``families/<family>.py``): the
+samples, their type, the reference and the exact checks are reached
+through ``cell.fam`` alone. Nothing here is specific to one cell or one
+family: a new cell is a new pair of files, a new family one file more.
 """
 
 from __future__ import annotations
@@ -13,7 +16,9 @@ import copy
 import dataclasses
 import json
 import os
-from typing import Any, Dict, List
+from typing import Any, Dict
+
+import families
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -30,6 +35,7 @@ class Cell:
     chips: int
     traffic: Dict[str, Any]
     run_config: Dict[str, Any]  # the dictionary handed to run_training
+    family: str  # module under benchmark/families/
     reference: str  # module under benchmark/reference/
     cost_model: str  # key in cost.MODELS
     warmup_epochs: int
@@ -37,6 +43,10 @@ class Cell:
     check_steps: int
     rehearse: bool
     limits: Dict[str, float] = dataclasses.field(default_factory=dict)
+
+    @property
+    def fam(self):
+        return families.load(self.family)
 
     @property
     def training(self) -> Dict[str, Any]:
@@ -65,13 +75,7 @@ def load_cell(name: str, rehearse: bool = False) -> Cell:
     nn["Architecture"].update(wl.get("architecture", {}))
     if rehearse:
         nn["Training"].update(over.get("training", {}))
-        arch_over = dict(over.get("architecture", {}))
-        if "hidden_dim" in arch_over:
-            h = int(arch_over["hidden_dim"])
-            heads = nn["Architecture"]["output_heads"]
-            heads["graph"].update(dim_sharedlayers=h, dim_headlayers=[h, max(h // 2, 1)])
-            heads["node"].update(dim_headlayers=[h, max(h // 2, 1)])
-        nn["Architecture"].update(arch_over)
+        families.load(cfg_file["family"]).rehearsal_overrides(nn, dict(over.get("architecture", {})))
     # far more epochs than any window needs: the harness ends the run
     nn["Training"]["num_epoch"] = 1_000_000
     return Cell(
@@ -80,6 +84,7 @@ def load_cell(name: str, rehearse: bool = False) -> Cell:
         chips=int(wl["chips"]),
         traffic=traffic,
         run_config=run_config,
+        family=cfg_file["family"],
         reference=cfg_file["reference"],
         cost_model=cfg_file["cost_model"],
         warmup_epochs=int(over.get("warmup_epochs", wl["warmup_epochs"])),
@@ -103,13 +108,3 @@ def place_compile_cache(rehearse: bool) -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
-
-def program_samples(raw: List[Dict[str, Any]]):
-    """The raw samples as the program's input type. The program prepares
-    (normalizes, builds edges) IN PLACE, so it gets copies."""
-    from hydragnn_tpu.data.dataset import GraphSample
-
-    return [
-        GraphSample(x=r["x"].copy(), pos=r["pos"].copy(), graph_y=r["graph_y"].copy())
-        for r in raw
-    ]

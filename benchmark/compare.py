@@ -12,10 +12,23 @@ start of the run, captured by ``taps.py`` on its way through
               leaf, from Adam's second moment (``grad_norms``).
   update_gap  the norm of each parameter leaf's change after the first
               real steps (8 in a scanned epoch, 3 on a mesh).
-  graphs_step_diff, edges_step1_diff
-              exact: the real graphs the program counted into each step,
-              and the edges it built for the first step's samples, against
-              the benchmark's own count.
+  grad_diff_median
+              (where the cell's limits name it) the norm of the DIFFERENCE
+              between the two sides' Adam first moment at the initial
+              weights, per leaf, the median leaf: a gap between two norms
+              sees elementwise rounding only in second order, the norm of
+              the difference in first order, which is what separates a
+              lower precision from the configuration's own (PR 25).
+  exact checks
+              the family's own (``cell.fam.exact_checks``), each with the
+              limit 0: for message passing ``graphs_step_diff``, the real
+              graphs the program counted into each step, and the edges it
+              built for the first step's samples, against the reference's
+              own count.
+
+The reference itself (chassis, loss, planted faults) is the family's:
+``cell.fam.reference_run``. What is common, and here, is how two sides'
+losses and Adam moments become these numbers.
 
 On a mesh the program dispatches step by step and its state is visible
 after every step: the loss is step 1's, the second moment after step 1 IS
@@ -45,12 +58,17 @@ from typing import Any, Dict, Tuple
 import jax
 import numpy as np
 
-NUMBERS = ("loss_gap", "grad_gap", "update_gap")
+NUMBERS = ("loss_gap", "grad_gap", "update_gap")  # every cell's limits name these
+OPTIONAL = ("grad_diff_median",)  # compared where a cell's limits name them
+
+
+def _leaves(tree) -> Dict[str, np.ndarray]:
+    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {jax.tree_util.keystr(p): np.asarray(x, np.float64) for p, x in leaves}
 
 
 def leaf_norms(tree) -> Dict[str, float]:
-    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
-    return {jax.tree_util.keystr(p): float(np.linalg.norm(np.asarray(x, np.float64))) for p, x in leaves}
+    return {k: float(np.linalg.norm(x)) for k, x in _leaves(tree).items()}
 
 
 def grad_norms(state) -> Dict[str, float]:
@@ -66,6 +84,15 @@ def leaf_gaps(prog: Dict[str, float], ref: Dict[str, float], skip=()) -> Dict[st
     """Per leaf |prog - ref| / max(ref_leaf, median ref leaf)."""
     med = float(np.median(list(ref.values())))
     return {k: abs(prog[k] - r) / max(r, med, 1e-30) for k, r in ref.items() if k not in skip}
+
+
+def diff_gaps(prog, ref, skip=()) -> Dict[str, float]:
+    """Per leaf ||prog - ref|| / max(||ref|| of that leaf, of the median
+    leaf): the norm of the difference, where both trees are at hand."""
+    p, r = _leaves(prog), _leaves(ref)
+    norms = {k: float(np.linalg.norm(x)) for k, x in r.items()}
+    med = float(np.median(list(norms.values())))
+    return {k: float(np.linalg.norm(p[k] - x)) / max(norms[k], med, 1e-30) for k, x in r.items() if k not in skip}
 
 
 def worst(gaps: Dict[str, float]) -> Tuple[float, str]:
@@ -84,10 +111,12 @@ def numbers(prog: Dict[str, Any], ref: Dict[str, Any], p0) -> Dict[str, Any]:
     if ref.get("probe"):
         pl, rl = prog["probe"]["losses"], ref["probe"]["losses"]
         g_prog, g_ref = grad_norms(prog["probe"]["state"]), grad_norms(ref["probe"]["state"])
+        m_prog, m_ref = prog["probe"]["state"]["mu"], ref["probe"]["state"]["mu"]
     else:
         first = min(ref["states"])
         pl, rl = prog["losses"][:1], ref["losses"][:1]
         g_prog, g_ref = grad_norms(prog["states"][first]), grad_norms(ref["states"][first])
+        m_prog, m_ref = prog["states"][first]["mu"], ref["states"][first]["mu"]
     out["loss_gaps"] = [abs(a - b) / max(abs(b), 1e-30) for a, b in zip(pl, rl)]
     out["loss_gap"] = float(max(out["loss_gaps"]))  # each step's loss has to agree: the worst counts
     gaps = leaf_gaps(g_prog, g_ref)
@@ -96,6 +125,10 @@ def numbers(prog: Dict[str, Any], ref: Dict[str, Any], p0) -> Dict[str, Any]:
     out["grad_leaf_gaps"] = gaps
     med = float(np.median(list(g_ref.values())))
     flat = [k for k, v in g_ref.items() if v < 1e-3 * med]
+    dgaps = diff_gaps(m_prog, m_ref, skip=flat)
+    out["grad_diff_gap"], out["grad_diff_leaf"] = worst(dgaps)
+    out["grad_diff_median"] = float(np.median(list(dgaps.values())))
+    out["grad_diff_leaf_gaps"] = dgaps
     last = max(ref["states"])
 
     def change(state):
@@ -114,73 +147,18 @@ def numbers(prog: Dict[str, Any], ref: Dict[str, Any], p0) -> Dict[str, Any]:
     return out
 
 
-def pads(step_groups, prepared) -> Tuple[int, int, int]:
-    """One shape for every followed step, steady from seed to seed: the
-    largest step, rounded up generously."""
-    n = max(sum(len(prepared[i]["x"]) for g in groups for i in g) for groups in step_groups)
-    e = max(sum(prepared[i]["edges"].shape[1] for g in groups for i in g) for groups in step_groups)
-    g = max(sum(len(g) for g in groups) for groups in step_groups)
-
-    def up(v, m):
-        return -(-(v + 1) // m) * m
-
-    return up(n, 2048), up(e, 32768), up(g, 8)
-
-
 def program_side(taps) -> Dict[str, Any]:
     return {"losses": taps.losses, "states": taps.states, "probe": taps.probe}
 
 
-def reference_run(cell, taps, raw, quant=None, fault=None) -> Dict[str, Any]:
-    """The reference (or a control / a planted fault) over the same
-    samples, dispatch for dispatch: the learning-rate-0 pass where the
-    program made one, then the real steps up to its last captured state."""
-    import reference
-    from reference import common
-
-    prepared = common.prepare(raw, cell.run_config)
-    deg = common.degree_stats(prepared, taps.train_ids)
-    mcfg = common.model_cfg(cell.run_config, deg)
-    head_types = dict(zip(mcfg["head_names"], mcfg["head_types"]))
-    capture_at = sorted(taps.states)
-    groups = taps.step_groups[: capture_at[-1]]
-    n_pad, e_pad, g_pad = pads(groups, prepared)
-    batches = [common.assemble(prepared, g, head_types, n_pad, e_pad, g_pad) for g in groups]
-    lr = float(cell.training["Optimizer"]["learning_rate"])
-    step = common.make_step(reference.conv_for(cell.reference), mcfg, quant, fault)
-    batches = jax.device_put(batches)  # once: both passes read the same arrays
-    probe = None
-    if taps.probe is not None:
-        pl, ps = common.follow(step, taps.initial_params, batches, 0.0, [len(batches)])
-        probe = {"losses": pl, "state": ps[len(batches)]}
-    losses, states = common.follow(step, taps.initial_params, batches, lr, capture_at)
-    share = 0.5 if fault == "half_batch" else 1.0
-    return {
-        "losses": losses, "states": states, "probe": probe,
-        "graphs": [int(sum(len(g) for g in grp) * share) for grp in groups],
-        "edges": [sum(prepared[i]["edges"].shape[1] for g in grp for i in g) for grp in groups],
-        "real": {
-            "nodes_per_epoch": sum(len(prepared[i]["x"]) for i in taps.train_ids),
-            "edges_per_epoch": sum(prepared[i]["edges"].shape[1] for i in taps.train_ids),
-            "graphs_per_epoch": len(taps.train_ids),
-        },
-    }
-
-
 def decide(cell, taps, raw):
-    ref = reference_run(cell, taps, raw)
+    ref = cell.fam.reference_run(cell, taps, raw)
     nums = numbers(program_side(taps), ref, taps.initial_params)
     checks: Dict[str, Dict[str, Any]] = {}
-    for name in NUMBERS:
+    for name in NUMBERS + OPTIONAL:
         if name in cell.limits:
             checks[name] = {"value": nums[name], "limit": cell.limits[name]}
-    seen = taps.graphs_seen[: len(ref["graphs"])]
-    checks["graphs_step_diff"] = {
-        "value": sum(abs(a - b) for a, b in zip(seen, ref["graphs"])) + abs(len(seen) - len(ref["graphs"])),
-        "limit": 0,
-    }
-    prog_edges = sum(taps.program_edges[i] for g in taps.step_groups[0] for i in g)
-    checks["edges_step1_diff"] = {"value": abs(prog_edges - ref["edges"][0]), "limit": 0}
+    checks.update(cell.fam.exact_checks(taps, ref))
     correct = all(name in checks for name in NUMBERS) and all(
         isinstance(c["value"], (int, float)) and math.isfinite(c["value"]) and c["value"] <= c["limit"]
         for c in checks.values()
@@ -189,6 +167,8 @@ def decide(cell, taps, raw):
     notes = {
         "grad_gap_leaf": nums["grad_gap_leaf"], "update_gap_leaf": nums["update_gap_leaf"],
         "grad_gap_median": nums["grad_gap_median"], "update_gap_median": nums["update_gap_median"],
+        "grad_diff_gap": nums["grad_diff_gap"], "grad_diff_leaf": nums["grad_diff_leaf"],
+        "grad_diff_median": nums["grad_diff_median"],
         "leaves_left_out": len(nums["leaves_left_out"]), "steps_followed": len(ref["losses"]),
         "loss_gaps": nums["loss_gaps"], "later_loss_gaps": nums["later_loss_gaps"],
         "program_losses": taps.losses[:3], "reference_losses": ref["losses"][:3],

@@ -54,18 +54,21 @@ def heads_flops(arch, voi, n: float, g: float) -> float:
 
 
 def model(name: str):
-    """``costmodels/<name>.py``: ``forward(arch, voi, n, e, g)`` -> operations by
-    part, ``kernel(arch, voi, n, e)`` -> {"bytes", "flops"}. A new family adds a file."""
+    """``costmodels/<name>.py``: ``forward(arch, voi, real)`` -> operations by
+    part, ``kernel(arch, voi, real)`` -> {"bytes", "flops"}, where ``real`` is the
+    dictionary of real counts an epoch that the cell's family makes
+    (``reference_run``'s ``real``); a model reads the keys it prices. A new
+    configuration adds a file."""
     import importlib
 
     return importlib.import_module(f"costmodels.{name}")
 
 
-def train_step_flops(name: str, run_config: Dict[str, Any], n: float, e: float, g: float) -> float:
+def train_step_flops(name: str, run_config: Dict[str, Any], real: Dict[str, float]) -> float:
     nn = run_config["NeuralNetwork"]
-    return 3.0 * sum(model(name).forward(nn["Architecture"], nn["Variables_of_interest"], n, e, g).values())
+    return 3.0 * sum(model(name).forward(nn["Architecture"], nn["Variables_of_interest"], real).values())
 
 
-def kernel_floor(name: str, run_config: Dict[str, Any], n: float, e: float) -> Dict[str, float]:
+def kernel_floor(name: str, run_config: Dict[str, Any], real: Dict[str, float]) -> Dict[str, float]:
     nn = run_config["NeuralNetwork"]
-    return model(name).kernel(nn["Architecture"], nn["Variables_of_interest"], n, e)
+    return model(name).kernel(nn["Architecture"], nn["Variables_of_interest"], real)

@@ -5,7 +5,8 @@ from typing import Dict
 from cost import ACT_BYTES, ID_BYTES, OUT_BYTES, heads_flops, widths
 
 
-def forward(arch, voi, n: float, e: float, g: float) -> Dict[str, float]:
+def forward(arch, voi, real: Dict[str, float]) -> Dict[str, float]:
+    n, e, g = real["nodes_per_epoch"], real["edges_per_epoch"], real["graphs_per_epoch"]
     conv = agg = 0.0
     for fin, out in widths(arch, len(voi["input_node_features"])):
         conv += n * (2 * fin) * fin * 2  # pre network, node level
@@ -17,9 +18,9 @@ def forward(arch, voi, n: float, e: float, g: float) -> Dict[str, float]:
             "heads": heads_flops(arch, voi, n, g)}
 
 
-
-def kernel(arch, voi, n: float, e: float) -> Dict[str, float]:
+def kernel(arch, voi, real: Dict[str, float]) -> Dict[str, float]:
     """Gather of the sender table + the four statistics, per layer."""
+    n, e = real["nodes_per_epoch"], real["edges_per_epoch"]
     fwd_bytes = fwd_flops = 0.0
     for fin, _ in widths(arch, len(voi["input_node_features"])):
         fwd_bytes += n * fin * ACT_BYTES + 2 * e * ID_BYTES + 4 * n * fin * OUT_BYTES

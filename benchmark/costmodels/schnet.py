@@ -5,7 +5,8 @@ from typing import Dict
 from cost import ACT_BYTES, ID_BYTES, OUT_BYTES, heads_flops, widths
 
 
-def forward(arch, voi, n: float, e: float, g: float) -> Dict[str, float]:
+def forward(arch, voi, real: Dict[str, float]) -> Dict[str, float]:
+    n, e, g = real["nodes_per_epoch"], real["edges_per_epoch"], real["graphs_per_epoch"]
     f, gauss = int(arch["num_filters"]), int(arch["num_gaussians"])
     filt = conv = msg = 0.0
     for fin, out in widths(arch, len(voi["input_node_features"])):
@@ -18,9 +19,9 @@ def forward(arch, voi, n: float, e: float, g: float) -> Dict[str, float]:
             "batchnorm": bn, "heads": heads_flops(arch, voi, n, g)}
 
 
-
-def kernel(arch, voi, n: float, e: float) -> Dict[str, float]:
+def kernel(arch, voi, real: Dict[str, float]) -> Dict[str, float]:
     """Gather of W1 x, product with the per-edge filter, sum per receiver."""
+    n, e = real["nodes_per_epoch"], real["edges_per_epoch"]
     f = int(arch["num_filters"])
     fwd_bytes = fwd_flops = 0.0
     for _ in widths(arch, len(voi["input_node_features"])):

@@ -14,8 +14,6 @@ import shutil
 
 def capture(cell, seed: int, work_dir: str, epochs: int = 1):
     """Returns (taps, raw samples) after ``epochs`` epochs of the cell."""
-    import cell as cellmod
-    import datagen
     from taps import Taps
 
     from hydragnn_tpu.api import run_training
@@ -23,8 +21,8 @@ def capture(cell, seed: int, work_dir: str, epochs: int = 1):
     cell = copy.copy(cell)
     cell.run_config = copy.deepcopy(cell.run_config)
     cell.run_config["NeuralNetwork"]["Training"]["num_epoch"] = epochs
-    raw = datagen.generate(cell.traffic, seed)
-    samples = cellmod.program_samples(raw)
+    raw = cell.fam.generate(cell.traffic, seed)
+    samples = cell.fam.program_samples(raw)
     shutil.rmtree(work_dir, ignore_errors=True)
     os.makedirs(work_dir, exist_ok=True)
     taps = Taps(cell, seed, float("inf"), samples)

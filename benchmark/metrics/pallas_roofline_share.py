@@ -16,9 +16,8 @@ def read(ctx):
     tr = ctx["trace"]
     if not tr or not tr.get("pallas_s") or not ctx["traced_epochs"]:
         return None
-    real, chips = ctx["real"], ctx["cell"].chips
-    floor = cost.kernel_floor(ctx["cell"].cost_model, ctx["cell"].run_config,
-                              real["nodes_per_epoch"], real["edges_per_epoch"])
+    chips = ctx["cell"].chips
+    floor = cost.kernel_floor(ctx["cell"].cost_model, ctx["cell"].run_config, ctx["real"])
     pk = peaks.lookup(ctx["device"]["kind"])
     least = max(floor["bytes"] / pk["hbm_bytes_s"], floor["flops"] / pk["bf16_flops"]) / chips
     return 100.0 * least * ctx["traced_epochs"] / tr["pallas_s"]

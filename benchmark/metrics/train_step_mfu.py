@@ -14,11 +14,7 @@ def read(ctx):
 
     if ctx["rehearse"] or not ctx["quiet_epochs"]:
         return None
-    real = ctx["real"]
-    per_epoch = cost.train_step_flops(
-        ctx["cell"].cost_model, ctx["cell"].run_config,
-        real["nodes_per_epoch"], real["edges_per_epoch"], real["graphs_per_epoch"],
-    )
+    per_epoch = cost.train_step_flops(ctx["cell"].cost_model, ctx["cell"].run_config, ctx["real"])
     seconds = sum(ctx["epoch_seconds"][i] for i in ctx["quiet_epochs"])
     peak = peaks.lookup(ctx["device"]["kind"])["bf16_flops"] * ctx["cell"].chips
     return 100.0 * per_epoch * len(ctx["quiet_epochs"]) / (seconds * peak)

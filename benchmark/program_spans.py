@@ -33,14 +33,14 @@ spans' boundaries and every piece goes to the DEEPEST span that covers it
 it. What lies under no child of ``epoch`` is ``unattributed``. So the
 children of ``epoch`` and ``unattributed`` add up to the window's idle
 time exactly. ``idle_in_program_s`` is the part of a span's idle time
-that lies INSIDE an ``XLA Modules`` event: no host gap. In the PR 23
-traces all of it is real work: ``trace_reduce.leaves`` drops an operation
-as a container when one of XLA's zero-length custom calls
-(``ConcatBitcast``, ``AllocateBuffer``) begins at the same nanosecond
-(in the PNA trace 370 operations and 0.185 s of 0.905 s idle; 11 of a
-train step's operations, 6.2 ms a step). The idle time here is the
-accepted reduction's, so that the two agree; PERF.md section 7 asks for
-the repair.
+that lies INSIDE an ``XLA Modules`` event: no host gap, but the device
+waiting inside a running program, and what is left of its real containers
+(a ``while``'s own bookkeeping between two bodies). Until PR 25 most of it
+was real work that ``trace_reduce.leaves`` dropped (an operation that one
+of XLA's zero-length custom calls shares its start with: 0.185 s of
+0.905 s idle in PR 23's PNA trace); since the repair it reads a few
+milliseconds an epoch. The idle time here is ``trace_reduce``'s own
+(``leaves``, ``union``), so the two agree to the nanosecond.
 
 Every function returns ``None``, and raises nothing, where the spans are
 not there: a rehearsal (no trace), an older program (no span, no
@@ -118,17 +118,6 @@ def _intersect(a: List[Interval], b: List[Interval]) -> List[Interval]:
     return out
 
 
-def _complement(busy: List[Interval], lo: int, hi: int) -> List[Interval]:
-    out, cursor = [], lo
-    for s, e in busy:
-        if s > cursor:
-            out.append((cursor, s))
-        cursor = max(cursor, e)
-    if hi > cursor:
-        out.append((cursor, hi))
-    return out
-
-
 def span_events(pd) -> List[Tuple[int, int, str, str]]:
     """(start, end, name, thread) of every host event named as a program
     span."""
@@ -198,7 +187,7 @@ def table(path: str, parents: Optional[Dict[str, Optional[str]]] = None) -> Opti
     busy_of = {chip: tr.union(tr.clip([(s, e) for s, e, _, _ in ev], lo, hi)) for chip, ev in dev.items()}
     chip = max(busy_of, key=lambda c: tr.total(busy_of[c]))
     busy = _Cover(busy_of[chip])
-    idle = _Cover(_complement(busy_of[chip], lo, hi))
+    idle = _Cover(tr.complement(busy_of[chip], lo, hi))
 
     programs: Dict[str, Dict[str, Any]] = {}
     unnamed = busy.total

@@ -116,12 +116,25 @@ def _spread(values: List[float]) -> Dict[str, float]:
     return {"min": v[0], "median": v[len(v) // 2], "max": v[-1]} if v else {}
 
 
+def _phase_means(flight, first: int, last: int) -> Dict[str, float]:
+    """Host seconds per window epoch under each child of the program's
+    ``epoch`` span, from the flight record's ``phases``."""
+    import program_spans
+
+    phases = program_spans.epoch_phases(flight)
+    sums: Dict[str, float] = {}
+    for i in range(first, last):
+        for name, row in (phases.get(i) or {}).items():
+            if row.get("parent") == program_spans.ROOT:
+                sums[name] = sums.get(name, 0.0) + float(row["s"])
+    return {k: v / (last - first) for k, v in sorted(sums.items())}
+
+
 def run_cell(args, check_device: bool = True) -> Dict[str, Any]:
     """Everything but argument parsing and printing. Tests call it with
     ``check_device=False`` (and ``args.rehearse`` true) to drive a run on
     the CPU with the timed path broken underneath."""
     import cell as cellmod
-    import datagen
 
     cell = cellmod.load_cell(args.workload, rehearse=args.rehearse)
     work = os.path.join(HERE, "_work", cell.name + ("-rehearse" if args.rehearse else ""))
@@ -164,8 +177,8 @@ def run_cell(args, check_device: bool = True) -> Dict[str, Any]:
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
     t_gen0 = time.perf_counter()
-    raw = datagen.generate(cell.traffic, args.seed)
-    samples = cellmod.program_samples(raw)
+    raw = cell.fam.generate(cell.traffic, args.seed)
+    samples = cell.fam.program_samples(raw)
     gen_s = time.perf_counter() - t_gen0
 
     trace_dir = os.path.join(work, "trace") if (args.trace and not args.rehearse) else None
@@ -210,7 +223,7 @@ def run_cell(args, check_device: bool = True) -> Dict[str, Any]:
                    "graphs": taps.graphs_per_epoch * (last - first)},
         "setup": {"seconds": (t_open - _PC0) + (_WALL0 - process_start_wall()),
                   "compile_s": sum(s for t, s in compiles if t <= t_open),
-                  "data_s": gen_s + taps.data_s},
+                  "generate_s": gen_s},
         "device": device, "trace": None, "rehearse": args.rehearse,
     }
     # epochs of the window that ran without the profiler: the host-clock
@@ -269,11 +282,12 @@ def run_cell(args, check_device: bool = True) -> Dict[str, Any]:
         "epoch_s": _spread(list(ctx["epoch_seconds"].values())),
         "train_wall_s": _spread([float(((epochs.get(i) or {}).get("hw") or {}).get("train_wall_s") or 0.0)
                                  for i in range(first, last)]),
-        # host seconds per window epoch by phase; "tail" is what is left of
-        # an epoch after the train dispatch (validate, test, diagnostics,
-        # flight record, tensorboard, every second epoch a checkpoint)
+        # host seconds per window epoch by the program's own spans (flight
+        # record); "tail" is what is left of an epoch after the train
+        # dispatch (validate, test, diagnostics, flight record, tensorboard,
+        # every second epoch a checkpoint)
         "phase_s_per_epoch": {
-            **{k: v / (last - first) for k, v in taps.phase_s.items()},
+            **_phase_means(flight, first, last),
             "tail": (window_s - sum(float(((epochs.get(i) or {}).get("hw") or {}).get("train_wall_s") or 0.0)
                                     for i in range(first, last))) / (last - first),
         },

@@ -33,7 +33,6 @@ def main() -> None:
     args = ap.parse_args()
 
     import cell as cellmod
-    import datagen
 
     cell = cellmod.load_cell(args.workload)
     if cell.chips > 1:
@@ -53,10 +52,10 @@ def main() -> None:
     from hydragnn_tpu.train import create_train_state, select_optimizer
     from hydragnn_tpu.train.state import make_scan_epoch
 
-    raw = datagen.generate(cell.traffic, args.seed)
+    raw = cell.fam.generate(cell.traffic, args.seed)
     stack = cell.chips
     train_loader, val_loader, test_loader, config = prepare_loaders_and_config(
-        cell.run_config, cellmod.program_samples(raw), device_stack=stack
+        cell.run_config, cell.fam.program_samples(raw), device_stack=stack
     )
     nn = config["NeuralNetwork"]
     example = next(iter(train_loader))

@@ -4,16 +4,18 @@
 benchmark needs a few things the entry point does not hand out, and takes
 each by wrapping a module-level name for the length of one run:
 
-  hydragnn_tpu.api.prepare_loaders_and_config  timed (``setup_data_s``)
   hydragnn_tpu.api.create_train_state          weights from ``--seed`` go in
   hydragnn_tpu.train.loop.train_epoch_scan     } the start of every epoch
   hydragnn_tpu.train.loop.train_epoch          } (own clock: window, trace,
                                                  stop) and the first steps'
                                                  losses and state come out
-  hydragnn_tpu.train.loop.{evaluate_epoch_scan, evaluate_epoch, test_epoch}
-      and hydragnn_tpu.utils.checkpoint.save_model: the host seconds of
-      each phase of an epoch's tail (own clock), and under the profiler a
-      host span in its trace
+
+Under the profiler it opens two annotations, the window marks
+``bench_trace_begin`` / ``bench_trace_end``, and no other: the phases of
+an epoch are the program's own spans (``obs/spans.py``), in the trace and
+in the flight record alike. What is specific to a family of configurations
+(which weights, what to count of the loader's samples for the exact
+checks) it asks of ``cell.fam``.
 
 A wrapper calls the wrapped function with the arguments it was given and
 returns what it returned. The step functions themselves are wrapped only
@@ -28,7 +30,6 @@ loudly; this file is then the only one to mend.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import signal
 import time
@@ -96,10 +97,8 @@ class Taps:
         self.window_last: Optional[int] = None  # epoch whose start closes it
         self.traced: Optional[tuple] = None  # (first, last+1) epochs under the profiler
         self.train_ids: Optional[List[int]] = None
-        self.program_edges: Dict[int, int] = {}  # raw sample id -> edges the program built
-        self.data_s = 0.0  # seconds inside prepare_loaders_and_config
+        self.sample_counts: Dict[str, Any] = {}  # what the family counted of the loader's samples
         self.marks: Dict[str, float] = {}  # perf_counter at points of the set-up
-        self.phase_s: Dict[str, float] = {}  # host seconds inside the window, by phase of the epoch's tail
         self.step_groups: List[List[List[int]]] = []  # [step][device] -> raw sample ids
         self.losses: List[float] = []
         self.states: Dict[int, Dict[str, Any]] = {}
@@ -127,16 +126,9 @@ class Taps:
         import hydragnn_tpu.api as api
         import hydragnn_tpu.train.loop as loop
 
-        self._patch(api, "prepare_loaders_and_config", self._wrap_prepare)
         self._patch(api, "create_train_state", self._wrap_create_state)
         self._patch(loop, "train_epoch_scan", self._wrap_train_scan)
         self._patch(loop, "train_epoch", self._wrap_train_steps)
-        import hydragnn_tpu.utils.checkpoint as ckpt
-
-        for name, label in (("evaluate_epoch_scan", "validate"), ("evaluate_epoch", "validate"),
-                            ("test_epoch", "test")):
-            self._patch(loop, name, lambda real, label=label: self._annotated(real, label))
-        self._patch(ckpt, "save_model", lambda real: self._annotated(real, "checkpoint"))
         return self
 
     def uninstall(self) -> None:
@@ -152,19 +144,6 @@ class Taps:
     def __exit__(self, *exc):
         self.uninstall()
 
-    # -- data span ---------------------------------------------------------
-
-    def _wrap_prepare(self, real):
-        def prepare_loaders_and_config(*args, **kwargs):
-            t0 = time.perf_counter()
-            try:
-                return real(*args, **kwargs)
-            finally:
-                self.data_s += time.perf_counter() - t0
-                self.marks["data_prepared"] = time.perf_counter()
-
-        return prepare_loaders_and_config
-
     # -- weights in --------------------------------------------------------
 
     def _wrap_create_state(self, real):
@@ -173,33 +152,14 @@ class Taps:
 
             self.marks["model_built"] = time.perf_counter()
 
-            params = weights.make(variables["params"], self.seed)
+            make = getattr(self.cell.fam, "weights", weights.make)
+            params = make(variables["params"], self.seed)
             self.initial_params = jax.device_get(params)
             return real({**variables, "params": params}, tx, *args, **kwargs)
 
         return create_train_state
 
     # -- epochs ------------------------------------------------------------
-
-    def _annotated(self, real, label: str):
-        """Host seconds of one phase of the epoch's tail, summed over the
-        window (own clock); under the profiler also a span in the trace."""
-
-        def wrapped(*args, **kwargs):
-            t0 = time.perf_counter()
-            try:
-                with self._span(label):
-                    return real(*args, **kwargs)
-            finally:
-                if self.window_first is not None and self.window_last is None:
-                    self.phase_s[label] = self.phase_s.get(label, 0.0) + time.perf_counter() - t0
-
-        return wrapped
-
-    def _span(self, label: str):
-        if self._tracing:
-            return jax.profiler.TraceAnnotation(f"bench_{label}")
-        return contextlib.nullcontext()
 
     def _stop_trace(self) -> None:
         with jax.profiler.TraceAnnotation("bench_trace_end"):
@@ -216,7 +176,7 @@ class Taps:
             self.window_first = i
         if self.trace_dir is not None and self.traced is None and i == self._open_at + k:
             opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0  # the host spans come from TraceAnnotation
+            opts.python_tracer_level = 0  # the host spans are the program's own TraceAnnotations
             jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
             with jax.profiler.TraceAnnotation("bench_trace_begin"):
                 pass
@@ -245,9 +205,7 @@ class Taps:
     def _note_loader(self, loader) -> None:
         if self.train_ids is None:
             self.train_ids = self._ids(loader, range(len(loader.samples)))
-            self.program_edges = {
-                i: int(s.edge_index.shape[1]) for i, s in zip(self.train_ids, loader.samples)
-            }
+            self.sample_counts = self.cell.fam.count_samples(self.train_ids, loader.samples)
             self.steps_per_epoch = len(loader)
             self.graphs_per_epoch = len(loader.samples)
 
@@ -258,8 +216,7 @@ class Taps:
             if not self.states:
                 self.mode = "scan_epoch"
                 scan_fn = self._capturing_scan(scan_fn, loader)
-            with self._span("train"):
-                return real(loader, state, scan_fn, epoch, *args, **kwargs)
+            return real(loader, state, scan_fn, epoch, *args, **kwargs)
 
         return train_epoch_scan
 
@@ -291,8 +248,7 @@ class Taps:
             if self._steps_seen < self.cell.check_steps:
                 self.mode = "per_step"
                 train_step = self._capturing_step(train_step, loader)
-            with self._span("train"):
-                return real(loader, state, train_step, *args, **kwargs)
+            return real(loader, state, train_step, *args, **kwargs)
 
         return train_epoch
 

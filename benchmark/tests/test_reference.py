@@ -26,7 +26,7 @@ WORK = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_work")
 
 
 def _numbers(cell, taps, raw, **kw):
-    return compare.reference_run(cell, taps, raw, **kw)
+    return cell.fam.reference_run(cell, taps, raw, **kw)
 
 
 @pytest.fixture(scope="module", params=CELLS)
@@ -69,3 +69,14 @@ def test_control_and_faults_read_above_the_program(captured):
         nums = compare.numbers(side, ref, p0)
         reads_above = any(nums[k] > 3 * max(prog[k], 1e-6) for k in keys)
         assert reads_above or side["graphs"] != ref["graphs"], (kind, {k: nums[k] for k in keys}, {k: prog[k] for k in keys})
+
+
+def test_the_control_reads_above_the_program_in_the_gradients_difference(captured):
+    """``grad_diff_median`` is the number that separates a lower precision
+    from bfloat16 on the chip (PERF.md section 6, PR 25): the norm of the
+    difference sees elementwise rounding in first order."""
+    cell, taps, raw, ref = captured[True]
+    p0 = taps.initial_params
+    prog = compare.numbers(compare.program_side(taps), ref, p0)["grad_diff_median"]
+    control = compare.numbers(_numbers(cell, taps, raw, quant="fp8"), ref, p0)["grad_diff_median"]
+    assert control > 3 * prog > 0, (control, prog)

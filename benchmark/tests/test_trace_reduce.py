@@ -1,17 +1,21 @@
-"""The reduction from ``.xplane.pb`` to metrics, against a small recorded
-trace: one epoch boundary of ``pna-multihead-h128.train-bcc`` on a v5e
-(PR 22, cut from a whole trace to the device's ``XLA Ops`` line and the
-benchmark's host spans: the last fifth of one scanned train dispatch,
-validation, test, the epoch's tail, and the start of the next dispatch)."""
+"""The reduction from ``.xplane.pb`` to metrics, against two small recorded
+traces of ``pna-multihead-h128.train-bcc`` on a v5e, each one epoch
+boundary cut from a whole trace: PR 22's (the device's ``XLA Ops`` line and
+the window marks; the host spans it has are the ``bench_*`` ones the
+benchmark opened until PR 25, which nothing reads any more) and PR 23's
+(``XLA Ops``, ``XLA Modules``, the program's own spans and the marks)."""
 
 import os
 import re
 
 import pytest
 
+import program_spans as ps
 import trace_reduce as tr
 
-TRACE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "pna_epoch_boundary.xplane.pb")
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+TRACE = os.path.join(DATA, "pna_epoch_boundary.xplane.pb")
+SPANS_TRACE = os.path.join(DATA, "pna_program_spans.xplane.pb")
 
 
 @pytest.fixture(scope="module")
@@ -19,22 +23,34 @@ def reduced():
     return tr.reduce(TRACE)
 
 
-def _raw():
+@pytest.fixture(scope="module")
+def reduced_spans():
+    return tr.reduce(SPANS_TRACE)
+
+
+def _raw(path=TRACE):
     """The trace's device events, tallied the plain way."""
-    pd = tr.load(TRACE)
+    pd = tr.load(path)
     (ev,) = tr.device_events(pd).values()
     spans = tr.host_spans(pd)
     lo, hi = spans[tr.MARK_BEGIN][0][1], spans[tr.MARK_END][-1][0]
     return ev, lo, hi
 
 
-def test_recorded_numbers(reduced):
+def test_recorded_numbers(reduced, reduced_spans):
+    # busy and Pallas time as read since PR 25; PR 22 to 24 read 0.520821515 and 0.250336296 from the
+    # first trace, the operations that a zero-length custom call shares its start with left out
     assert reduced["chips"] == 1
     assert reduced["window_s"] == pytest.approx(0.869152575, abs=1e-8)
-    assert reduced["busy_s"] == pytest.approx(0.520821515, abs=1e-8)
-    assert reduced["pallas_s"] == pytest.approx(0.250336296, abs=1e-8)
+    assert reduced["busy_s"] == pytest.approx(0.551688527, abs=1e-8)
+    assert reduced["pallas_s"] == pytest.approx(0.268279027, abs=1e-8)
     assert reduced["collective_s"] == 0.0 and reduced["collective_exposed_s"] == 0.0
-    assert 0 < reduced["train_busy_s"] < reduced["busy_s"] < reduced["window_s"]
+    assert reduced["train_busy_s"] == 0.0  # no program span in PR 22's trace
+    assert reduced_spans["window_s"] == pytest.approx(0.919710694, abs=1e-8)
+    assert reduced_spans["busy_s"] == pytest.approx(0.629705416, abs=1e-8)
+    assert reduced_spans["pallas_s"] == pytest.approx(0.294406842, abs=1e-8)
+    assert reduced_spans["train_busy_s"] == pytest.approx(0.53976285, abs=1e-8)
+    assert 0 < reduced_spans["train_busy_s"] < reduced_spans["busy_s"] < reduced_spans["window_s"]
 
 
 def test_control_flow_is_not_counted():
@@ -57,13 +73,57 @@ def test_pallas_time_is_the_custom_calls_and_nothing_else(reduced):
     assert tr.category(text) == "fused_elementwise"
 
 
-def test_busy_plus_named_gaps_is_the_window(reduced):
-    gaps = dict(reduced["breakdown"]["idle_gaps"])
-    assert sum(gaps.values()) == pytest.approx(reduced["window_s"] - reduced["busy_max_s"], abs=1e-9)
-    assert max(gaps, key=gaps.get) == "test"  # the host gathers the test split's outputs
-    cats = reduced["by_category_s"]
-    assert sum(cats.values()) == pytest.approx(reduced["busy_s"], rel=1e-6)  # one core: no overlap
-    assert len(reduced["breakdown"]["device_ops"]) <= 10 and len(reduced["breakdown"]["idle_gaps"]) <= 10
+@pytest.mark.parametrize("path", [TRACE, SPANS_TRACE])
+def test_an_operation_that_shares_its_start_with_a_zero_length_call_is_a_leaf(path):
+    ev, lo, hi = _raw(path)
+    zero = [e for e in ev if e[1] == e[0]]
+    assert zero and all('custom_call_target="tpu_custom_call"' not in e[2] for e in zero)  # XLA's own: ConcatBitcast, AllocateBuffer
+    starts = {e[0] for e in zero}
+    shared = [e for e in ev if e[1] > e[0] and e[0] in starts and not tr.CONTAINERS.match(e[2])]
+    assert len(shared) >= 40
+    kept = set(tr.leaves(ev))
+    assert all(e in kept for e in shared)
+    # what is dropped is control flow by name, and nothing else
+    dropped = [e for e in ev if e not in kept]
+    assert dropped and all(re.search(r"\s(while|conditional|call)\(", e[2]) for e in dropped)
+
+
+def test_a_container_needs_a_child_with_a_length():
+    op = "%fusion.{} = f32[8]{{0}} fusion(f32[8]{{0}} %x), kind=kLoop, calls=%f"
+    zero = '%custom-call.9 = f32[8]{0} custom-call(), custom_call_target="AllocateBuffer"'
+    a, b, c = (100, 150, op.format(1), None), (100, 100, zero, None), (110, 120, op.format(2), None)
+    assert tr.leaves([a, b]) == [a, b]  # the zero-length call makes no container of a
+    assert tr.leaves([a, b, c]) == [b, c]  # a real child does, the zero-length call before it or not
+    assert tr.leaves([b, a, (150, 150, zero, None)]) == [a, b, (150, 150, zero, None)]
+
+
+def test_leaves_and_program_spans_agree_on_the_idle_time(reduced_spans):
+    table = ps.table(SPANS_TRACE)
+    idle_ns = round((reduced_spans["window_s"] - reduced_spans["busy_max_s"]) * 1e9)
+    assert round(table["idle_s"] * 1e9) == idle_ns == 290005278  # to the nanosecond
+    assert round(table["busy_s"] * 1e9) == round(reduced_spans["busy_max_s"] * 1e9)
+    # inside a running program the chip is now idle for what the whiles' own
+    # bookkeeping and the gaps between two operations take: 0.6 ms of this
+    # epoch boundary (34.3 ms before PR 25, all of it dropped operations)
+    assert 0 < table["idle_in_program_s"] < 1e-3
+    assert table["spans"]["train.sync"]["idle_in_program_s"] < 1e-3
+
+
+def test_busy_plus_named_gaps_is_the_window(reduced, reduced_spans):
+    for r in (reduced, reduced_spans):
+        gaps = dict(r["breakdown"]["idle_gaps"])
+        assert sum(gaps.values()) == pytest.approx(r["window_s"] - r["busy_max_s"], abs=1e-9)
+        assert sum(r["by_category_s"].values()) == pytest.approx(r["busy_s"], rel=1e-6)  # one core: no overlap
+        assert len(r["breakdown"]["device_ops"]) <= 10 and len(r["breakdown"]["idle_gaps"]) <= 10
+    assert set(dict(reduced["breakdown"]["idle_gaps"])) == {"host_other"}  # no program span to name a gap by
+    # the gaps carry the program's span names, and each is what program_spans.py reads under that span
+    gaps = dict(reduced_spans["breakdown"]["idle_gaps"])
+    assert set(gaps) == set(tr.HOST_SPANS) | {"host_other"} and not [k for k in gaps if k.startswith("bench_")]
+    assert max(gaps, key=gaps.get) == "epoch.checkpoint"  # the save is synchronous
+    table = ps.table(SPANS_TRACE)
+    for name in tr.HOST_SPANS:
+        assert gaps[name] == pytest.approx(table["spans"][name]["idle_s"], abs=1e-9)
+    assert gaps["host_other"] == pytest.approx(table["unattributed_idle_s"], abs=1e-9)
 
 
 def test_categories_and_names():

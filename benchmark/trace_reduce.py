@@ -17,10 +17,12 @@ looked at by hand; ``describe`` prints the same for any trace):
   * ``XLA Modules`` holds one event per executed program, ``Steps`` the
     profiler's own step markers; neither is counted;
   * the host's threads are lines of the plane ``/host:CPU``; the
-    benchmark's spans (``taps.py``: ``bench_train``, ``bench_validate``,
-    ``bench_test``, ``bench_checkpoint``, and the two markers
-    ``bench_trace_begin`` / ``bench_trace_end``) are events there, on the
-    same clock as the device events.
+    program's own spans (``hydragnn_tpu/obs/spans.py``: ``epoch.train``,
+    ``epoch.validate``, ``epoch.test``, ``epoch.checkpoint`` and the other
+    children of ``epoch``) and the benchmark's two window marks
+    (``taps.py``: ``bench_trace_begin`` / ``bench_trace_end``) are events
+    there, on the same clock as the device events. The benchmark opens no
+    span of its own (it did until PR 25: ``bench_train`` and three more).
 
 Kernel names: a Pallas kernel is a ``custom-call`` instruction on the
 device line. Its instruction name is whatever scope it was traced under
@@ -41,7 +43,11 @@ OPS_LINE = "XLA Ops"
 CONTAINERS = re.compile(r"^(while|conditional|call)([.\d]*)$")
 COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
 MARK_BEGIN, MARK_END = "bench_trace_begin", "bench_trace_end"
-HOST_SPANS = ("bench_train", "bench_validate", "bench_test", "bench_checkpoint")
+TRAIN_SPAN = "epoch.train"  # the train dispatch(es) of one epoch, diagnostics sample included
+# the children of the program's ``epoch`` span (they follow one another on one thread): idle time is named by the
+# one it lies under
+HOST_SPANS = (TRAIN_SPAN, "epoch.validate", "epoch.test", "epoch.head_quality", "epoch.diag_snapshot",
+              "epoch.record", "epoch.checkpoint")
 
 
 def load(path: str):
@@ -100,13 +106,23 @@ def short_name(name: str) -> str:
 
 
 def leaves(events: List[Tuple[int, int, str, Optional[str]]]) -> List[Tuple[int, int, str, Optional[str]]]:
-    """Drop every event that contains another one (control flow)."""
+    """Drop every event that contains another one (control flow). An event
+    is a container only if what it contains has a length: XLA's zero-length
+    custom calls (``ConcatBitcast``, ``AllocateBuffer``) begin at the same
+    nanosecond as the operation that follows them and make no container of
+    it (until PR 25 they did: 11 operations of every PNA train step were
+    dropped, 6 ms a step)."""
     ev = sorted(events, key=lambda e: (e[0], -(e[1])))
+    # for each event, the next one in this order that has a length
+    nxt: List[Optional[Tuple[int, int, str, Optional[str]]]] = [None] * len(ev)
+    following = None
+    for i in range(len(ev) - 1, -1, -1):
+        nxt[i] = following
+        if ev[i][1] > ev[i][0]:
+            following = ev[i]
     out = []
-    for i, e in enumerate(ev):
-        has_child = i + 1 < len(ev) and ev[i + 1][0] < e[1] and ev[i + 1][1] <= e[1] and (
-            ev[i + 1][0] > e[0] or ev[i + 1][1] < e[1] or CONTAINERS.match(e[2]) is not None
-        )
+    for e, c in zip(ev, nxt):
+        has_child = c is not None and c[0] < e[1] and c[1] <= e[1] and (c[0] > e[0] or c[1] < e[1])
         if has_child or CONTAINERS.match(e[2]):
             continue
         out.append(e)
@@ -122,6 +138,18 @@ def union(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
         else:
             merged.append((s, e))
     return merged
+
+
+def complement(merged: List[Tuple[int, int]], lo: int, hi: int) -> List[Tuple[int, int]]:
+    """What of [lo, hi) a sorted disjoint list inside it leaves uncovered."""
+    out, cursor = [], lo
+    for s, e in merged:
+        if s > cursor:
+            out.append((cursor, s))
+        cursor = max(cursor, e)
+    if hi > cursor:
+        out.append((cursor, hi))
+    return out
 
 
 def clip(intervals, lo: int, hi: int):
@@ -199,7 +227,7 @@ def reduce(path: str) -> Dict[str, Any]:
     all_ends = [e[1] for v in dev.values() for e in v]
     lo = spans[MARK_BEGIN][0][1] if spans[MARK_BEGIN] else min(all_starts)
     hi = spans[MARK_END][-1][0] if spans[MARK_END] else max(all_ends)
-    train = union(clip(spans["bench_train"], lo, hi))
+    train = union(clip(spans[TRAIN_SPAN], lo, hi))
 
     busy, train_busy, pallas, coll, exposed = [], [], [], [], []
     cats: Dict[str, float] = {}
@@ -224,21 +252,17 @@ def reduce(path: str) -> Dict[str, Any]:
     chips = len(dev)
     ns = 1e-9
     fullest = max(per_chip_busy, key=lambda k: total(per_chip_busy[k]))
-    # idle gaps of the fullest chip, named by what the host was doing
+    # idle time of the fullest chip, cut at the program's spans: each piece
+    # goes to the child of ``epoch`` that covers it, the rest to host_other
+    idle = complement(per_chip_busy[fullest], lo, hi)
     gaps: Dict[str, float] = {}
-    cursor = lo
-    for s, e in per_chip_busy[fullest] + [(hi, hi)]:
-        if s > cursor:
-            gap = [(cursor, s)]
-            label, best = "host_other", 0
-            for name in HOST_SPANS:
-                ov = overlap(gap, union(spans[name]))
-                if ov > best:
-                    label, best = name[len("bench_"):], ov
-            if label == "train":
-                label = "inside_train_dispatch"
-            gaps[label] = gaps.get(label, 0.0) + (s - cursor)
-        cursor = max(cursor, e)
+    for name in HOST_SPANS:
+        under = overlap(idle, union(clip(spans[name], lo, hi)))
+        if under:
+            gaps[name] = float(under)
+    rest = total(idle) - sum(gaps.values())
+    if rest > 0:
+        gaps["host_other"] = float(rest)
     top_ops = sorted(names.items(), key=lambda kv: -kv[1])[:6]
     by_cat = sorted(cats.items(), key=lambda kv: -kv[1])
     breakdown_ops = [[f"category:{k}", v * ns / chips] for k, v in by_cat][:7] + [[k, v * ns / chips] for k, v in top_ops][:3]
