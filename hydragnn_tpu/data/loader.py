@@ -153,7 +153,10 @@ class GraphLoader:
         keep it on device; epochs then permute batch ORDER only. Removes
         per-epoch host batching + H2D transfer from the hot loop — the win
         is large when the host->device link is slow — at the cost of
-        coarser shuffling (batch membership is fixed after epoch 0).
+        coarser shuffling (batch membership is fixed after epoch 0). A
+        loader that does not shuffle loses nothing by it, and
+        ``keep_on_device`` turns it on for one after construction: a
+        scanned run does that to its test loader (``train/loop.py``).
       fixed_membership: the run will consume this shuffling loader
         through ``stacked_device_batches`` (scan-epoch dispatch), which
         permutes batch ORDER only. The loader then keeps membership fixed
@@ -517,16 +520,39 @@ class GraphLoader:
             return jax.device_put(batch, self._sharding)
         return batch
 
+    def _build_cache(self) -> List[GraphBatch]:
+        """Every ``samples[b*bs:(b+1)*bs]`` batch, built once and placed
+        (on one device ``batch_graphs`` already ends in device arrays)."""
+        bs = self.batch_size
+        base = np.arange(len(self.samples))
+        return [
+            self._place(self._make_batch(base[b * bs : (b + 1) * bs]))
+            for b in range(len(self))
+        ]
+
+    def keep_on_device(self) -> None:
+        """``cache_device_batches`` for a loader built without it, and the
+        cache built now rather than at the first ``__iter__``. Only for a
+        loader that does not shuffle: its batches and their order never
+        change, so iterating yields the same batches as before, without
+        the host batching and the transfer. Raises what building raises
+        (RESOURCE_EXHAUSTED for a split too large to be resident) and
+        then leaves the loader as it was."""
+        if self.shuffle and not self.cache_device_batches:
+            raise ValueError(
+                "a shuffling loader fixes its membership at construction: "
+                "build it with cache_device_batches=True"
+            )
+        if self._cached_batches is None:
+            self._cached_batches = self._build_cache()
+        self.cache_device_batches = True
+
     def __iter__(self) -> Iterator[GraphBatch]:
         bs = self.batch_size
         nb = len(self)
         if self.cache_device_batches:
             if self._cached_batches is None:
-                base = np.arange(len(self.samples))
-                self._cached_batches = [
-                    self._place(self._make_batch(base[b * bs : (b + 1) * bs]))
-                    for b in range(nb)
-                ]
+                self._cached_batches = self._build_cache()
             for b in self._batch_order():
                 yield self._cached_batches[b]
             return

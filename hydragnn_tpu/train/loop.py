@@ -487,20 +487,48 @@ def _allgather_varlen(arr: np.ndarray) -> np.ndarray:
     return np.concatenate([gathered[p, : counts[p]] for p in range(len(counts))])
 
 
-def _stack_refusal(loader) -> Optional[str]:
+def _stack_refusal(loader, keep: bool = False) -> Optional[str]:
     """Materialize ``loader``'s device-resident stack (the loader caches
-    it, so epoch 0 does not pay twice). None when it did; else why not,
+    it, so epoch 0 does not pay twice), or with ``keep`` the batches it
+    iterates (``keep_on_device``). None when it did; else why not,
     for the two anticipated causes — batches of unlike shape cannot
     stack (ValueError), a split too large for device memory cannot be
     resident. Anything else is a fault and raises."""
     try:
         with span("setup.stack_splits"):
-            loader.stacked_device_batches(0)
+            if keep:
+                loader.keep_on_device()
+            else:
+                loader.stacked_device_batches(0)
     except (ValueError, jax.errors.JaxRuntimeError) as exc:
         if not (isinstance(exc, ValueError) or "RESOURCE_EXHAUSTED" in str(exc)):
             raise
         return f"{type(exc).__name__}: {str(exc)[:160]}"
     return None
+
+
+def _keep_test_split_on_device(
+    test_loader, use_scan: bool, caller_step: bool
+) -> Tuple[bool, str]:
+    """Where the train split scans, the test loader's batches are built
+    once, at set-up, and stay on the device (``GraphLoader.keep_on_device``):
+    ``test_epoch`` then iterates them with no host batching and no
+    transfer in, every epoch. Same pass, same values; only where the
+    batches come from changes, and the choice is made from what the loop
+    knows: a per-step run streams its splits and keeps doing so, a
+    caller-supplied (sharded) step keeps its loader's placement as it is,
+    ``run_prediction`` makes one pass and never comes here. Returns
+    (kept, reason) for the manifest's ``dispatch_mode.test_split``."""
+    if not use_scan:
+        return False, "the train split is dispatched per step"
+    if caller_step:
+        return False, "caller-supplied eval_step_out"
+    if not hasattr(test_loader, "keep_on_device"):
+        return False, "the test loader cannot keep its batches"
+    refusal = _stack_refusal(test_loader, keep=True)  # a loader that shuffles refuses
+    if refusal is not None:
+        return False, f"keeping failed: {refusal}"
+    return True, "scan dispatch: test batches built once, kept on the device"
 
 
 def _scan_auto_eligible(
@@ -703,6 +731,9 @@ def train_validate_test(
             guard_nonfinite=guard_nonfinite,
         )
         eval_step = eval_step or make_eval_step(model)
+        test_kept, test_reason = _keep_test_split_on_device(
+            test_loader, use_scan, caller_step=eval_step_out is not None
+        )
         eval_step_out = eval_step_out or make_eval_step(model, with_outputs=True)
         if stats_step is None and training.get("bn_recalibration", True):
             stats_step = make_stats_step(model)
@@ -1292,6 +1323,12 @@ def train_validate_test(
                     "mode": "scan_epoch" if scan_fn is not None else "per_step",
                     "auto": scan_auto,
                     "reason": dispatch_reason,
+                    # the test pass iterates batches kept on the device
+                    # from set-up, or builds them every epoch, and why
+                    "test_split": {
+                        "path": "on_device" if test_kept else "rebuilt",
+                        "reason": test_reason,
+                    },
                 },
                 "compile_monitor_available": bool(cmon and cmon.available),
                 "nonfinite_guard": sentry is not None,

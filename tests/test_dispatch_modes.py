@@ -411,3 +411,190 @@ def pytest_metric_accum_scalar_counts_still_work():
     acc.add(jnp.asarray(5.0), jnp.asarray([5.0]), jnp.asarray(6.0))
     avg_loss, avg_tasks = acc.finalize()
     assert avg_loss == pytest.approx((3.0 * 2 + 5.0 * 6) / 8)
+
+
+# -- the test split lives on the device (ISSUE 28) ---------------------------
+
+
+@pytest.fixture(scope="module")
+def multihead_split():
+    """A split of four batches, the last one partial (24 graphs by 7),
+    with a graph head and three node heads."""
+    cfg = base_config(multihead=True)
+    cfg["NeuralNetwork"]["Architecture"]["model_type"] = "GIN"
+    samples = deterministic_graph_data(number_configurations=30, seed=11)
+    train, val, test, _, _ = prepare_dataset(samples, cfg)
+    cfg = update_config(cfg, train, val, test)
+    loader = GraphLoader(train, 7, shuffle=False)
+    assert len(loader) > 2 and len(train) % 7
+    model, variables = create_model_config(cfg["NeuralNetwork"], next(iter(loader)))
+    tx = select_optimizer(cfg["NeuralNetwork"]["Training"]["Optimizer"])
+    return model, create_train_state(variables, tx), loader
+
+
+@pytest.mark.parametrize("case", ["samples", "no_samples", "later_epoch"])
+def pytest_kept_test_epoch_equals_rebuilt(multihead_split, case, monkeypatch):
+    """``test_epoch`` over a loader that keeps its batches on the device
+    returns what it returns over one that builds them every epoch: the
+    losses, and per head the true and predicted values of the real
+    entries, in the same order and of the same lengths. ``later_epoch``:
+    a second pass, with other weights, builds no batch and yields the
+    very arrays the first pass read."""
+    from hydragnn_tpu.train import make_eval_step
+    from hydragnn_tpu.train.loop import test_epoch as run_test_epoch
+
+    model, state, rebuilt = multihead_split
+    cfg = model.cfg
+    step = make_eval_step(model, with_outputs=True)
+    kept = GraphLoader(rebuilt.all_samples, rebuilt.batch_size, shuffle=False)
+    kept.keep_on_device()
+    assert kept.cache_device_batches and (kept.pad_nodes, kept.pad_edges) == (
+        rebuilt.pad_nodes, rebuilt.pad_edges)
+    want_samples = case != "no_samples"
+    if case == "later_epoch":
+        first = list(kept)
+        run_test_epoch(kept, state, step, cfg)
+        state = state.replace(
+            params=jax.tree_util.tree_map(lambda p: p * 1.25 + 0.01, state.params)
+        )
+        monkeypatch.setattr(
+            GraphLoader, "_make_batch", lambda self, idx: pytest.fail("a kept batch was rebuilt")
+        )
+    loss_k, tasks_k, true_k, pred_k = run_test_epoch(
+        kept, state, step, cfg, return_samples=want_samples
+    )
+    if case == "later_epoch":
+        assert all(a is b for a, b in zip(first, kept))
+        monkeypatch.undo()
+    loss_r, tasks_r, true_r, pred_r = run_test_epoch(
+        rebuilt, state, step, cfg, return_samples=want_samples
+    )
+    np.testing.assert_allclose(loss_k, loss_r, rtol=1e-6)
+    np.testing.assert_allclose(tasks_k, tasks_r, rtol=1e-6)
+    assert len(true_k) == len(pred_k) == (cfg.num_heads if want_samples else 0)
+    assert len(true_r) == len(true_k)
+    rows = {"graph": rebuilt.num_graphs_total(), "node": sum(s.num_nodes for s in rebuilt.samples)}
+    for ihead in range(len(true_k)):
+        assert true_k[ihead].shape == true_r[ihead].shape == pred_k[ihead].shape == pred_r[ihead].shape
+        assert true_k[ihead].shape[0] == rows[cfg.output_type[ihead]]
+        np.testing.assert_array_equal(true_k[ihead], true_r[ihead])
+        np.testing.assert_allclose(pred_k[ihead], pred_r[ihead], rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["shuffles", "too_large"])
+def pytest_keep_on_device_refused_leaves_the_loader_as_it_was(multihead_split, case, monkeypatch):
+    """A loader that shuffles fixes its membership when it is built, so it
+    cannot be asked later; a split too large for the device raises what the
+    placement raised. Either way the loader goes on building its batches."""
+    _, _, rebuilt = multihead_split
+    loader = GraphLoader(rebuilt.all_samples, rebuilt.batch_size, shuffle=case == "shuffles")
+    if case == "too_large":
+        def no_room(self, batch):
+            raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory (test)")
+
+        monkeypatch.setattr(GraphLoader, "_place", no_room)
+    with pytest.raises(ValueError if case == "shuffles" else jax.errors.JaxRuntimeError):
+        loader.keep_on_device()
+    monkeypatch.undo()
+    assert not loader.cache_device_batches and loader._cached_batches is None
+    assert [int(b.graph_mask.sum()) for b in loader] == [7, 7, 7, len(loader.samples) - 21]
+
+
+def _refuse_keeping(monkeypatch):
+    def keep_on_device(self):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory (test)")
+
+    monkeypatch.setattr(GraphLoader, "keep_on_device", keep_on_device)
+
+
+def _caller_supplies_eval_step_out(monkeypatch):
+    from hydragnn_tpu import api
+    from hydragnn_tpu.train import make_eval_step
+
+    real = api.train_validate_test
+
+    def with_own_step(model, *args, **kwargs):
+        kwargs["eval_step_out"] = make_eval_step(model, with_outputs=True)
+        return real(model, *args, **kwargs)
+
+    monkeypatch.setattr(api, "train_validate_test", with_own_step)
+
+
+_TEST_SPLIT_PATHS = {
+    # name: (Training keys, what the case changes, train mode, path, reason starts with)
+    "default": ({}, None, "scan_epoch", "on_device", "scan dispatch: test batches built once"),
+    "scan_true": ({"scan_epoch": True}, None, "scan_epoch", "on_device", "scan dispatch"),
+    "scan_false": ({"scan_epoch": False}, None, "per_step", "rebuilt", "the train split"),
+    "keeping_refused": (
+        {}, _refuse_keeping, "scan_epoch", "rebuilt",
+        "keeping failed: JaxRuntimeError: RESOURCE_EXHAUSTED: out of memory (test)",
+    ),
+    "caller_step": (
+        {}, _caller_supplies_eval_step_out, "scan_epoch", "rebuilt",
+        "caller-supplied eval_step_out",
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TEST_SPLIT_PATHS))
+def pytest_test_split_path_in_manifest_and_loader(case, tmp_path, monkeypatch):
+    """The choice is made once, at set-up, and shows twice: the flight
+    manifest's ``dispatch_mode.test_split`` says whether the test pass
+    iterates batches kept on the device or rebuilds them every epoch, and
+    why; and the test loader builds its batches once (under
+    ``setup.stack_splits``) or once an epoch. Either way ``epoch.test``
+    has the same children, the head quality is there, and no program
+    compiles after epoch 0."""
+    monkeypatch.setenv("HYDRAGNN_TELEMETRY", "1")
+    monkeypatch.setenv("HYDRAGNN_DIAGNOSTICS", "1")  # the test pass gathers samples
+    from hydragnn_tpu.api import run_training
+    from hydragnn_tpu.obs import epoch_phases
+    from test_train_e2e import make_config
+
+    training, change, mode, path, reason = _TEST_SPLIT_PATHS[case]
+    if change is not None:
+        change(monkeypatch)
+    from hydragnn_tpu.train import loop
+
+    built, built_by_pass = [], []  # batches built on the host: all, and by each test pass
+    real_make, real_test_epoch = GraphLoader._make_batch, loop.test_epoch
+
+    def counting_make(self, idx):
+        built.append(len(idx))
+        return real_make(self, idx)
+
+    def counting_test_epoch(*args, **kwargs):
+        before = len(built)
+        out = real_test_epoch(*args, **kwargs)
+        built_by_pass.append(len(built) - before)
+        return out
+
+    monkeypatch.setattr(GraphLoader, "_make_batch", counting_make)
+    monkeypatch.setattr(loop, "test_epoch", counting_test_epoch)
+    config = make_config("GIN", True, str(tmp_path), num_epoch=3)
+    config["NeuralNetwork"]["Training"].update(training, batch_size=5)
+    samples = deterministic_graph_data(number_configurations=60, seed=0)
+    run_training(config, samples=samples, log_dir=str(tmp_path) + "/logs/")
+
+    from hydragnn_tpu.obs.flight import read_flight_record
+
+    events = read_flight_record(glob.glob(str(tmp_path) + "/logs/*/flight.jsonl")[0])
+    (man,) = [e["manifest"] for e in events if e.get("kind") == "run_start"]
+    epochs = [e for e in events if e.get("kind") == "epoch"]
+    dm = man["dispatch_mode"]
+    assert dm["mode"] == mode
+    assert dm["test_split"]["path"] == path, dm
+    assert dm["test_split"]["reason"].startswith(reason), dm
+    nb = man["pad_plans"]["test"]["num_batches"]
+    assert nb > 1
+    by_epoch = epoch_phases(events)
+    for ev in epochs:
+        under_test = {
+            k: p for k, p in by_epoch[ev["epoch"]].items() if p["parent"] == "epoch.test"
+        }
+        assert {"test.loader_wait", "test.dispatch", "test.gather", "test.sync"} <= set(under_test)
+        assert under_test["test.dispatch"]["n"] == nb
+        assert ev["heads"]["available"] and set(ev["heads"]["mae"]) == set(man["head_names"])
+        if ev["epoch"] > 0:
+            assert ev["compiles"]["count"] == 0 and not ev["compiles"]["unexpected"]
+    assert built_by_pass == [0 if path == "on_device" else nb] * len(epochs)
