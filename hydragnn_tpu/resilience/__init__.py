@@ -31,6 +31,10 @@ machinery when a run is dying:
     heartbeats/preemption coordination (``PodSignaler``), and elastic
     restore that re-shards a committed generation across a different
     host count.
+  - :mod:`~hydragnn_tpu.resilience.pod` — ``PodPlane``: everything one
+    training run does because it is one host of several (podview's
+    shards and skew, podckpt's heartbeats, cuts and generations), behind
+    the one object ``train/loop.py`` calls at its epoch boundaries.
   - :mod:`~hydragnn_tpu.resilience.inject` — env-gated deterministic
     fault injection (NaN batch, SIGTERM, SIGKILL mid-checkpoint,
     stalled producer) so every path above is testable, not decorative.

@@ -21,12 +21,15 @@ same single-name "continue" UX:
 
 from __future__ import annotations
 
+import json
 import os
-from typing import Any, Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 from flax import serialization
+
+from hydragnn_tpu.utils.print_utils import print_distributed
 
 #: On-disk checkpoint format generation, stamped into the meta sidecar
 #: and every pod-shard manifest/COMMIT (resilience/podckpt.py). History:
@@ -286,8 +289,6 @@ def save_train_meta(meta: dict, log_name: str, path: str = "./logs/") -> None:
     state"); this closes that gap."""
     if jax.process_index() != 0:
         return
-    import json
-
     meta = dict(meta)
     meta.setdefault("format_version", CHECKPOINT_FORMAT_VERSION)
     out_dir = os.path.join(path, log_name)
@@ -341,13 +342,137 @@ def reconcile_pod_meta(log_name: str, path: str, info: dict) -> None:
 
 
 def load_train_meta(log_name: str, path: str = "./logs/") -> Optional[dict]:
-    import json
-
     p = os.path.join(path, log_name, f"{log_name}.meta.json")
     if not os.path.exists(p):
         return None
     with open(p) as f:
         return json.load(f)
+
+
+class LoopState:
+    """The train loop's host-side state — epoch index, plateau scheduler,
+    early-stop counters, per-epoch history — and the one place that knows
+    the ``<log_name>.meta.json`` sidecar's keys: :meth:`snapshot` writes
+    what :meth:`restore` reads (``epoch``, ``step``, ``early_stopped``,
+    ``scheduler{best, num_bad_epochs}``, ``stopper{count, min_loss}``,
+    ``history``). ``scheduler`` / ``stopper`` are the loop's
+    ``ReduceLROnPlateau`` / ``EarlyStopping`` (or None)."""
+
+    HISTORY_KEYS = (
+        "train_loss", "val_loss", "test_loss", "train_tasks", "val_tasks", "test_tasks", "lr",
+    )
+
+    def __init__(self, scheduler, stopper, num_epoch: int):
+        self.scheduler = scheduler
+        self.stopper = stopper
+        self.num_epoch = num_epoch
+        self.history: Dict[str, List] = {k: [] for k in self.HISTORY_KEYS}
+        self.start_epoch = 0
+        self.epochs_done = 0
+        self.resumed_from: Optional[int] = None  # set when a continue-run loaded meta
+
+    @property
+    def early_stopped(self) -> bool:
+        return bool(self.stopper and self.stopper.count >= self.stopper.patience)
+
+    def restore(self, training: dict, state: Any, steps_per_epoch: int,
+                log_name: str, log_dir: str, verbosity: int = 0) -> None:
+        """Exact resume under ``Training.continue`` (beyond the reference's
+        restore-model-and-start-over: epoch index, plateau scheduler and
+        early-stop counters survive the restart). The TrainState itself is
+        restored by the caller via Training.continue/startfrom."""
+        if training.get("continue") != 1:
+            return
+        if "startfrom" not in training:
+            raise ValueError("Training.continue=1 requires Training.startfrom")
+        meta = load_train_meta(training["startfrom"], log_dir)
+        if meta is None:
+            return
+        # The model file and the meta sidecar are written sequentially
+        # (each atomic, the pair not): a crash between them leaves meta
+        # one interval older than the weights. The meta carries the
+        # optimizer step it described; on mismatch, re-derive the epoch
+        # from the restored weights instead of replaying epochs.
+        meta_step = meta.get("step")
+        state_step = int(jax.device_get(state.step))
+        if meta_step is not None and int(meta_step) != state_step:
+            derived = min(self.num_epoch, state_step // max(steps_per_epoch, 1))
+            print_distributed(
+                verbosity,
+                f"WARNING: checkpoint meta (step {meta_step}) does not "
+                f"match restored weights (step {state_step}) — the run "
+                "likely crashed between the weight and meta writes; "
+                f"resuming from epoch {derived} derived from the "
+                f"weights, not meta epoch {meta['epoch']}",
+            )
+            # Repair the whole sidecar, not just the epoch: the stale
+            # history would misalign epoch indices for everything
+            # appended after it, and the stale scheduler/stopper
+            # counters describe an older state than the weights (the
+            # weights' own opt_state already carries the live LR).
+            hist = meta.get("history", {})
+            for k, v in hist.items():
+                v = v[:derived]
+                while v and len(v) < derived:
+                    v.append(v[-1])  # unknown epochs: carry the last
+                hist[k] = v
+            meta = {
+                "epoch": derived,
+                "step": state_step,
+                "early_stopped": False,
+                "scheduler": {"best": float("inf"), "num_bad_epochs": 0},
+                "stopper": {"count": 0, "min_loss": float("inf")},
+                "history": hist,
+            }
+            # rewrite once so future resumes see a consistent pair —
+            # under the name resume READS from (training["startfrom"]),
+            # which may differ from this run's log_name; also under
+            # log_name so this run's own sidecar starts consistent
+            save_train_meta(meta, training["startfrom"], log_dir)
+            if log_name != training["startfrom"]:
+                save_train_meta(meta, log_name, log_dir)
+        # an early-stopped run resumes to a no-op (the stop decision
+        # is honored, not replayed into extra epochs); a completed or
+        # interrupted run continues from its recorded epoch — which
+        # also supports the reference's extend-training workflow
+        # (continue with a larger num_epoch)
+        self.start_epoch = self.num_epoch if meta.get("early_stopped") else int(meta["epoch"])
+        self.epochs_done = self.resumed_from = self.start_epoch
+        self.scheduler.best = float(meta["scheduler"]["best"])
+        self.scheduler.num_bad_epochs = int(meta["scheduler"]["num_bad_epochs"])
+        if self.stopper is not None and "stopper" in meta:
+            self.stopper.count = int(meta["stopper"]["count"])
+            self.stopper.min_loss = float(meta["stopper"]["min_loss"])
+        self.history = meta["history"]
+
+    def snapshot(self, step: int, epoch_next: int, early_stopped: bool) -> dict:
+        stopper = self.stopper
+        return {
+            "epoch": epoch_next,
+            # the optimizer step ties this sidecar to the weight file
+            # it was written with (resume verifies the pair matches)
+            "step": step,
+            "early_stopped": early_stopped,
+            "scheduler": {
+                "best": self.scheduler.best,
+                "num_bad_epochs": self.scheduler.num_bad_epochs,
+            },
+            "stopper": {
+                "count": stopper.count if stopper else 0,
+                "min_loss": stopper.min_loss if stopper else float("inf"),
+            },
+            "history": self.history,
+        }
+
+    def append(self, **epoch_values) -> None:
+        """One epoch's entry in every history list."""
+        for k in self.HISTORY_KEYS:
+            self.history[k].append(epoch_values[k])
+
+    def epoch_done(self, epoch: int, val_loss: float) -> bool:
+        """Count the epoch; True when early stopping says stop."""
+        self.epochs_done = epoch + 1
+        return self.stopper is not None and self.stopper(val_loss)
 
 
 def load_existing_model_config(

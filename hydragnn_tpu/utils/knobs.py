@@ -69,7 +69,7 @@ KNOBS: Dict[str, Knob] = {
         _K("HYDRAGNN_DEVICE_KIND", "str", "default", "ops/segment_pallas.py",
            "Row selector into TUNE_TILES.json for block/chunk defaults "
            "(never read from jax.devices(): import must not init a backend)."),
-        _K("HYDRAGNN_DIAGNOSTICS", "bool", "1", "train/loop.py",
+        _K("HYDRAGNN_DIAGNOSTICS", "bool", "1", "train/run.py",
            "Force-disable model introspection (per-head grad norms, MFU "
            "ledger) regardless of config; the tier-1 suite sets 0."),
         _K("HYDRAGNN_DRIFT_REF", "path", None, "serve/server.py",
@@ -102,7 +102,7 @@ KNOBS: Dict[str, Knob] = {
            "tenants without an explicit quota; 0 = unlimited."),
         _K("HYDRAGNN_FULL_MATRIX", "flag", None, "tests/test_train_matrix.py",
            "Opt into the full 7-model acceptance matrix (~15 min)."),
-        _K("HYDRAGNN_GRAFTCHECK", "bool", "1", "train/loop.py",
+        _K("HYDRAGNN_GRAFTCHECK", "bool", "1", "train/run.py",
            "Stamp the compiled-IR contract block (lint/ir.py CC001-CC006) "
            "into every run_start flight manifest; 0 skips the lowering."),
         _K("HYDRAGNN_GRAFTCHECK_LAYOUTS", "str", "dp,fsdp2",
@@ -282,7 +282,7 @@ KNOBS: Dict[str, Knob] = {
            "step_skew trigger threshold on podview.skew_frac; 0/unset = "
            "derive from the committed scaling model's skew_tolerance "
            "block (fallback 0.25)."),
-        _K("HYDRAGNN_PODVIEW_STALL_S", "float", "120", "train/loop.py",
+        _K("HYDRAGNN_PODVIEW_STALL_S", "float", "120", "resilience/pod.py",
            "host_stall trigger threshold: seconds since the least-recent "
            "host's last flight event before the stall incident fires."),
         _K("HYDRAGNN_POD_BARRIER_TIMEOUT_S", "float", "60",
@@ -290,7 +290,7 @@ KNOBS: Dict[str, Knob] = {
            "Bounded-wait limit for pod_barrier rendezvous; on expiry "
            "the host PROCEEDS and records the missing peers (a pod "
            "must degrade to evidence, never to a hang)."),
-        _K("HYDRAGNN_POD_CKPT", "bool", "1", "train/loop.py",
+        _K("HYDRAGNN_POD_CKPT", "bool", "1", "resilience/pod.py",
            "Pod-sharded generation checkpointing (resilience/podckpt.py) "
            "whenever the run spans more than one podview host; 0 keeps "
            "only the single-host msgpack path."),

@@ -4,6 +4,7 @@ preemption, non-finite sentry + rollback, hang watchdog, checkpoint
 retention/integrity fallback, and the bounded restart supervisor.
 All CPU; process-killing faults run in subprocesses."""
 
+import contextlib
 import glob
 import json
 import os
@@ -424,6 +425,82 @@ def pytest_rollback_budget_exhausts_to_typed_failure(tmp_path, monkeypatch):
     events = _flight_events(tmp_path)
     assert sum(e.get("kind") == "rollback" for e in events) == 1
     assert events[-1]["kind"] == "run_end" and events[-1]["status"] == "failed"
+
+
+# ---------------------------------------------------------------------------
+# the three endings of a run: one routine, the same steps
+
+_ENDINGS = {
+    # status: (environment, Training keys, what run_training raises)
+    "completed": ({}, {}, None),
+    "failed": (
+        {"HYDRAGNN_INJECT_NAN_STEP": "0:100"},
+        {"nonfinite_patience": 2, "nonfinite_max_rollbacks": 0},
+        NonFiniteRollbackExhausted,
+    ),
+    "preempted": ({"HYDRAGNN_INJECT_SIGTERM_EPOCH": "1"}, {}, TrainingPreempted),
+}
+
+
+@pytest.mark.parametrize("status", sorted(_ENDINGS))
+def pytest_every_ending_closes_the_run(status, tmp_path, monkeypatch):
+    """However a run ends — it completes, something raises inside an
+    epoch, a SIGTERM preempts it — ``run_end`` with that status is the
+    record's last event, and nothing of the run outlives it: compile
+    monitor detached, tensorboard writer closed, preemption handler
+    and watchdog torn down, the process-global timer stopped."""
+    import signal
+    import threading
+
+    from hydragnn_tpu.api import run_training
+    from hydragnn_tpu.obs import compile_monitor
+    from hydragnn_tpu.train import run as run_module
+    from hydragnn_tpu.utils.time_utils import Timer
+
+    class Writer:
+        opened = []
+
+        def __init__(self):
+            self.scalars, self.closed = 0, False
+            Writer.opened.append(self)
+
+        def add_scalar(self, *a, **kw):
+            assert not self.closed
+            self.scalars += 1
+
+        def flush(self):
+            pass
+
+        def close(self):
+            self.closed = True
+
+    env, training, raises = _ENDINGS[status]
+    monkeypatch.setenv("HYDRAGNN_TELEMETRY", "1")
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(run_module, "get_summary_writer", lambda *a, **kw: Writer())
+    handlers = {s: signal.getsignal(s) for s in (signal.SIGTERM, signal.SIGINT)}
+    monitors = list(compile_monitor._active)
+    # the watchdog on (it forces per-step dispatch), its limit out of reach;
+    # a grace window no failing assertion can outlast
+    cfg = _tiny_config(
+        num_epoch=3, checkpoint_every=1, watchdog_stall_s=600, preempt_grace_s=600, **training
+    )
+    with pytest.raises(raises) if raises else contextlib.nullcontext():
+        run_training(cfg, samples=_tiny_samples(), log_dir=str(tmp_path / "logs/"))
+
+    events = _flight_events(tmp_path)
+    assert events[-1]["kind"] == "run_end" and events[-1]["status"] == status
+    assert [e["kind"] for e in events].count("run_end") == 1
+    if status != "completed":
+        assert events[-2]["kind"] == {"failed": "error", "preempted": "preempt"}[status]
+    assert not validate_flight_record(events)
+    assert compile_monitor._active == monitors
+    (writer,) = Writer.opened
+    assert writer.closed and (writer.scalars > 0 or status == "failed")
+    assert {s: signal.getsignal(s) for s in handlers} == handlers
+    assert not [t for t in threading.enumerate() if t.name == "hydragnn-watchdog"]
+    assert Timer("train_validate_test")._start is None
 
 
 # ---------------------------------------------------------------------------

@@ -605,6 +605,58 @@ def pytest_resume_noop_is_pure(tmp_path):
         assert open(p, "rb").read() == content, f"no-op resume rewrote {p}"
 
 
+def pytest_resume_reads_the_parents_sidecar(tmp_path):
+    """A checkpoint and loop-state sidecar written by the code before
+    ``LoopState`` owned the format (commit 17bd3b9: ``make_config("GIN",
+    False, ..., num_epoch=2)`` with ``checkpoint_every=1, EarlyStopping=True,
+    patience=7`` over ``deterministic_graph_data(80, seed=0)``; kept under
+    tests/data/loop_sidecar_parent/) resumes under today's: same keys read,
+    same keys written."""
+    import json
+    import os
+    import shutil
+
+    from hydragnn_tpu.api import run_training
+    from hydragnn_tpu.obs.flight import read_flight_record
+    from hydragnn_tpu.utils.config import get_log_name_config
+    from test_train_e2e import make_config
+
+    src = os.path.join(os.path.dirname(__file__), "data", "loop_sidecar_parent")
+    logs = os.path.join(str(tmp_path), "logs")
+    shutil.copytree(src, os.path.join(logs, "parent_run"))
+    old = json.load(open(os.path.join(logs, "parent_run", "parent_run.meta.json")))
+    history_keys = {"train_loss", "val_loss", "test_loss", "train_tasks", "val_tasks",
+                    "test_tasks", "lr"}
+    assert set(old) == {"epoch", "step", "early_stopped", "scheduler", "stopper", "history",
+                        "format_version"}
+    assert set(old["scheduler"]) == {"best", "num_bad_epochs"}
+    assert set(old["stopper"]) == {"count", "min_loss"}
+    assert set(old["history"]) == history_keys and old["epoch"] == 2
+
+    cfg = make_config("GIN", False, str(tmp_path), num_epoch=4)
+    cfg["NeuralNetwork"]["Training"].update(
+        checkpoint_every=1, EarlyStopping=True, patience=7, startfrom="parent_run",
+        **{"continue": 1},
+    )
+    _, _, hist, full = run_training(
+        cfg, samples=deterministic_graph_data(number_configurations=80, seed=0),
+        log_dir=logs + "/",
+    )
+    for k in history_keys:
+        assert len(hist[k]) == 4 and hist[k][:2] == old["history"][k]
+    name = get_log_name_config(full)
+    events = read_flight_record(os.path.join(logs, name, "flight.jsonl"))
+    assert [e["epoch"] for e in events if e["kind"] == "resumed"] == [2]
+    assert [e["epoch"] for e in events if e["kind"] == "epoch"] == [2, 3]
+    new = json.load(open(os.path.join(logs, name, f"{name}.meta.json")))
+    assert set(new) == set(old) and new["format_version"] == old["format_version"]
+    for block in ("scheduler", "stopper", "history"):
+        assert set(new[block]) == set(old[block])
+    assert new["epoch"] == 4 and new["step"] == 2 * old["step"] and not new["early_stopped"]
+    # the plateau scheduler went on from the restored best, not from infinity
+    assert new["scheduler"]["best"] <= old["scheduler"]["best"]
+
+
 def pytest_meta_step_mismatch_rederives_epoch(tmp_path):
     """A meta sidecar older than the weights (crash between the two
     writes) must not replay epochs on the newer weights: resume derives
