@@ -93,6 +93,7 @@ class HostSyncInHotPath(Rule):
         "make_stats_step",
         "make_eval_step",
         "make_diagnostics_step",
+        "make_diagnosed_first_step",
         "make_sharded_train_step",
         "make_sharded_stats_step",
         "make_sharded_eval_step",

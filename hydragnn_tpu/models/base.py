@@ -499,3 +499,42 @@ def model_loss(
         tasks_loss.append(head_loss)
         total = total + weights[ihead] * head_loss
     return total, tasks_loss
+
+
+def cast_floats(tree, dtype):
+    """Cast float32 leaves to ``dtype`` (ints/bools untouched)."""
+    return jax.tree_util.tree_map(
+        lambda x: x.astype(dtype)
+        if hasattr(x, "dtype") and x.dtype == jnp.float32
+        else x,
+        tree,
+    )
+
+
+def train_loss_closure(model: HydraModel, compute_dtype, batch_stats, batch: GraphBatch, dropout_rng):
+    """``params -> (loss, (tasks[H], mutated))``: the train-mode forward
+    (mixed-precision casts, dropout rng, BatchNorm statistics as mutated
+    output) and the weighted multi-task loss. The ONE forward/loss
+    closure: every step body of ``train/state.py`` differentiates it, and
+    the diagnosed step and the diagnostics observer linearise it
+    (``obs/introspect.py:linearize_heads``)."""
+
+    def loss_fn(params):
+        if compute_dtype is not None:
+            apply_params = cast_floats(params, compute_dtype)
+            apply_batch = cast_floats(batch, compute_dtype)
+        else:
+            apply_params, apply_batch = params, batch
+        outputs, mutated = model.apply(
+            {"params": apply_params, "batch_stats": batch_stats},
+            apply_batch,
+            train=True,
+            mutable=["batch_stats"],
+            rngs={"dropout": dropout_rng},
+        )
+        # loss in f32 against the ORIGINAL (uncast) targets
+        outputs = [o.astype(jnp.float32) for o in outputs]
+        total, tasks = model_loss(model.cfg, outputs, batch)
+        return total, (jnp.stack(tasks), mutated)
+
+    return loss_fn

@@ -8,18 +8,21 @@ This module makes two more questions answerable from the run's own
 artifact (docs/OBSERVABILITY.md "Model-level diagnostics"):
 
 **Is the multi-task optimization healthy?**
-  :func:`make_diagnostics_step` builds ONE jitted function computing, per
-  sampled step: per-head gradient norms (one forward + one ``jax.vjp``
-  linearization shared by H one-hot cotangent pulls — not H separate
-  backward passes over a re-traced forward), the pairwise inter-task
-  gradient cosine matrix (the conflict matrix: persistently negative
-  entries mean two heads fight over the shared encoder), and the global
-  update-to-param norm ratio (the effective step size the optimizer is
-  actually taking). :class:`HeadDiagnostics` samples it every
-  ``Training.diag_every`` steps (default: once per epoch) so the hot
-  path gains no per-step host syncs, and the diagnostics executable is a
-  SEPARATE jitted fn compiled once — the train step itself is untouched
-  (pinned by the zero-unexpected-recompile test).
+  Per sampled step: per-head gradient norms (one forward + one ``jax.vjp``
+  linearization shared by H one-hot cotangent pulls, :func:`linearize_heads`
+  — not H separate backward passes over a re-traced forward), the pairwise
+  inter-task gradient cosine matrix (the conflict matrix: persistently
+  negative entries mean two heads fight over the shared encoder), and the
+  global update-to-param norm ratio (the effective step size the optimizer
+  is actually taking): :func:`head_diagnostics`. :class:`HeadDiagnostics`
+  samples every ``Training.diag_every`` steps (default: once per epoch)
+  so the hot path gains no per-step host syncs. Where the loop scans its
+  epochs, the sampled step is the epoch's first TRAIN step, run once by
+  the program that diagnoses it (``train/state.py:
+  make_diagnosed_first_step``: the update is built from the same
+  linearisation). Elsewhere :func:`make_diagnostics_step` is a separate
+  observer program dispatched before the sampled step; the train step
+  itself is untouched (pinned by the zero-unexpected-recompile test).
 
 **How efficiently did the hardware run?**
   :class:`HardwareLedger` records the compiled train step's analytic
@@ -247,18 +250,45 @@ def device_memory_stats(device=None) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def make_diagnostics_step(
-    model,
-    tx,
-    compute_dtype=None,
-    remat: bool = False,
-) -> Callable[..., Dict[str, Any]]:
-    """Jitted ``(state, batch) -> diagnostics dict`` over the SAME loss
-    the train step optimizes (same dropout-rng split, same mixed-precision
-    casts), without touching the state: no donation, no mutation — a pure
-    observer the loop dispatches on sampled steps only.
+def linearize_heads(loss_fn, params, weights, remat: bool = False):
+    """ONE forward and ONE ``jax.vjp`` linearisation of the stacked task
+    losses, then H + 1 pulls through it: a one-hot cotangent per head
+    (each head's UNWEIGHTED loss gradient w.r.t. the full parameter tree;
+    not H backward passes over a re-traced forward) and the task weights
+    as cotangent, which is the gradient of the train loss: the operation
+    the train step's backward is, so an update built from it is the train
+    step's update to rounding.
 
-    Returned (device) dict:
+    ``loss_fn``: ``models/base.py:train_loss_closure``'s ``params ->
+    (loss, (tasks[H], mutated))``. Returns ``(loss, tasks, mutated, head_grads,
+    total_grad)``."""
+    import jax
+    import jax.numpy as jnp
+
+    def tasks_fn(p):
+        loss, (tasks, mutated) = loss_fn(p)
+        return tasks, (loss, mutated)
+
+    fn = jax.checkpoint(tasks_fn) if remat else tasks_fn
+    tasks, vjp_fn, (loss, mutated) = jax.vjp(fn, params, has_aux=True)
+    num_heads = tasks.shape[0]
+    head_grads = []
+    for ihead in range(num_heads):
+        cot = jnp.zeros((num_heads,), tasks.dtype).at[ihead].set(1.0)
+        (g,) = vjp_fn(cot)
+        head_grads.append(g)
+    (total_grad,) = vjp_fn(jnp.asarray(weights, tasks.dtype))
+    return loss, tasks, mutated, head_grads, total_grad
+
+
+def head_diagnostics(tasks, head_grads, total_grad, params, updates) -> Dict[str, Any]:
+    """The diagnostics arithmetic: a pure function of one step's
+    linearisation (:func:`linearize_heads`) and update, which the observer
+    (:func:`make_diagnostics_step`) and the diagnosed train step
+    (``train/state.py:make_diagnosed_first_step``) both call. Returned
+    (device) dict:
+
+      - ``tasks_loss`` [H]: the forward's per-head losses;
       - ``grad_norms`` [H]: global norm of each head's UNWEIGHTED loss
         gradient w.r.t. the full parameter tree;
       - ``cosine`` [H, H]: pairwise cosine similarity between per-head
@@ -268,104 +298,92 @@ def make_diagnostics_step(
         (what the optimizer actually consumes);
       - ``param_norm`` / ``update_norm`` / ``update_ratio``: global
         parameter norm, optax update norm, and their ratio — the
-        effective relative step size.
-
-    Cost: one forward + (H+1) cotangent pulls through one shared
-    ``jax.vjp`` linearization (the "per-head loss vjp" trick), plus one
-    ``tx.update`` whose new opt_state is discarded.
-    """
+        effective relative step size."""
     import jax
     import jax.numpy as jnp
     import optax
 
-    from hydragnn_tpu.models.base import model_loss
-    from hydragnn_tpu.train.state import _cast_floats
-
-    cfg = model.cfg
-    num_heads = cfg.num_heads
-    weights = jnp.asarray(cfg.normalized_weights, jnp.float32)
-
     def _tree_dot(a, b) -> jnp.ndarray:
         leaves = jax.tree_util.tree_map(
-            lambda x, y: jnp.vdot(
-                x.astype(jnp.float32), y.astype(jnp.float32)
-            ),
-            a,
-            b,
+            lambda x, y: jnp.vdot(x.astype(jnp.float32), y.astype(jnp.float32)), a, b
         )
         return sum(jax.tree_util.tree_leaves(leaves), jnp.zeros((), jnp.float32))
+
+    dots = jnp.stack([jnp.stack([_tree_dot(gi, gj) for gj in head_grads]) for gi in head_grads])
+    norms = jnp.sqrt(jnp.clip(jnp.diagonal(dots), 0.0, None))
+    denom = jnp.maximum(norms[:, None] * norms[None, :], 1e-30)
+    param_norm = optax.global_norm(params)
+    update_norm = optax.global_norm(updates)
+    return {
+        "tasks_loss": tasks,
+        "grad_norms": norms,
+        "cosine": dots / denom,
+        "grad_norm_total": optax.global_norm(total_grad),
+        "param_norm": param_norm,
+        "update_norm": update_norm,
+        "update_ratio": update_norm / jnp.maximum(param_norm, 1e-30),
+    }
+
+
+def make_diagnostics_step(
+    model,
+    tx,
+    compute_dtype=None,
+    remat: bool = False,
+) -> Callable[..., Dict[str, Any]]:
+    """The diagnostics OBSERVER: jitted ``(state, batch) -> diagnostics
+    dict`` (:func:`head_diagnostics`) over the SAME loss the train step
+    optimizes (``models/base.py:train_loss_closure``: the same dropout-rng
+    split, the same mixed-precision casts), without touching the state:
+    no donation, no mutation. The path where the loop does not own a
+    scanned step: per-step dispatch samples it BEFORE the train step,
+    which then computes the forward, the gradient and the update a second
+    time. A loop that scans its epochs runs ``train/state.py:
+    make_diagnosed_first_step`` in its place: the same two functions of
+    this module around the same closure, and the update lands.
+
+    Cost: one forward + (H+1) cotangent pulls through one shared
+    ``jax.vjp`` linearization (:func:`linearize_heads`), plus one
+    ``tx.update`` whose new opt_state is discarded.
+    """
+    import jax
+
+    from hydragnn_tpu.models.base import train_loss_closure
+
+    weights = model.cfg.normalized_weights
 
     def diagnostics_step(state, batch) -> Dict[str, Any]:
         # identical split to the train step body: the diagnosed gradient
         # is the gradient THIS step's update is built from
         _, dropout_rng = jax.random.split(state.rng)
-
-        def tasks_fn(params):
-            if compute_dtype is not None:
-                apply_params = _cast_floats(params, compute_dtype)
-                apply_batch = _cast_floats(batch, compute_dtype)
-            else:
-                apply_params, apply_batch = params, batch
-            outputs, _ = model.apply(
-                {"params": apply_params, "batch_stats": state.batch_stats},
-                apply_batch,
-                train=True,
-                mutable=["batch_stats"],
-                rngs={"dropout": dropout_rng},
-            )
-            outputs = [o.astype(jnp.float32) for o in outputs]
-            _, tasks = model_loss(cfg, outputs, batch)
-            return jnp.stack(tasks)
-
-        fn = jax.checkpoint(tasks_fn) if remat else tasks_fn
-        tasks, vjp_fn = jax.vjp(fn, state.params)
-        head_grads = []
-        for ihead in range(num_heads):
-            cot = jnp.zeros((num_heads,), tasks.dtype).at[ihead].set(1.0)
-            (g,) = vjp_fn(cot)
-            head_grads.append(g)
-        # the weighted-total gradient from the same linearization: one
-        # more pull with the weight vector as cotangent
-        (total_grad,) = vjp_fn(weights.astype(tasks.dtype))
-
-        dots = jnp.stack(
-            [
-                jnp.stack([_tree_dot(head_grads[i], head_grads[j]) for j in range(num_heads)])
-                for i in range(num_heads)
-            ]
-        )
-        norms = jnp.sqrt(jnp.clip(jnp.diagonal(dots), 0.0, None))
-        denom = jnp.maximum(norms[:, None] * norms[None, :], 1e-30)
-        cosine = dots / denom
-
-        param_norm = optax.global_norm(state.params)
-        updates, _ = tx.update(total_grad, state.opt_state, state.params)
-        update_norm = optax.global_norm(updates)
-        return {
-            "tasks_loss": tasks,
-            "grad_norms": norms,
-            "cosine": cosine,
-            "grad_norm_total": optax.global_norm(total_grad),
-            "param_norm": param_norm,
-            "update_norm": update_norm,
-            "update_ratio": update_norm / jnp.maximum(param_norm, 1e-30),
-        }
+        loss_fn = train_loss_closure(model, compute_dtype, state.batch_stats, batch, dropout_rng)
+        _, tasks, _, head_grads, grads = linearize_heads(loss_fn, state.params, weights, remat=remat)
+        updates, _ = tx.update(grads, state.opt_state, state.params)
+        return head_diagnostics(tasks, head_grads, grads, state.params, updates)
 
     return jax.jit(diagnostics_step)
 
 
 class HeadDiagnostics:
-    """Sampling controller around the jitted diagnostics step.
+    """Which steps are diagnosed, and their diagnostics until the epoch's
+    record is written. Two callers:
 
-    ``maybe_sample(state, batch)`` is called once per training step
-    BEFORE the (buffer-donating) train step consumes the state; on
-    non-sampled steps it is a counter increment and nothing else. On
-    sampled steps (every ``every`` steps, starting with the very first
-    — so the one diagnostics compile lands in epoch 0 alongside the
-    train step's) it dispatches the jitted fn and keeps the DEVICE
-    results; no host sync happens until :meth:`epoch_snapshot`
-    materializes them at the epoch boundary, where the epoch metrics
-    sync anyway."""
+    - a per-step loop calls ``maybe_sample(state, batch)`` once per
+      training step BEFORE the (buffer-donating) train step consumes the
+      state; on sampled steps (every ``every`` steps, starting with the
+      very first — so the one diagnostics compile lands in epoch 0
+      alongside the train step's) it dispatches the observer ``diag_fn``;
+      on the others it is a counter increment and nothing else;
+    - a loop that scans its epochs counts in epochs (``every`` is then an
+      epoch stride, ``train/loop.py:DispatchPlan.diag_stride``) and
+      dispatches nothing here: where :attr:`due`, its first train step is
+      the diagnosed one (``train/state.py:make_diagnosed_first_step``) and
+      ``count_step`` is handed that program's dictionary; ``diag_fn`` is
+      None.
+
+    Either way the DEVICE results are kept; no host sync happens until
+    :meth:`epoch_snapshot` materializes them at the epoch boundary, where
+    the epoch metrics sync anyway."""
 
     def __init__(self, diag_fn, head_names: Sequence[str], every: int):
         self.fn = diag_fn
@@ -375,11 +393,21 @@ class HeadDiagnostics:
         self._pending = None
         self._pending_step = None
 
-    def maybe_sample(self, state, batch) -> None:
-        if self._n % self.every == 0:
-            self._pending = self.fn(state, batch)
+    @property
+    def due(self) -> bool:
+        """Is the step (scan: the epoch) about to be counted a sampled one?"""
+        return self._n % self.every == 0
+
+    def count_step(self, diagnostics=None) -> None:
+        """One step (scan: one epoch) ran; ``diagnostics``: its device
+        dictionary where it was a diagnosed one."""
+        if diagnostics is not None:
+            self._pending = diagnostics
             self._pending_step = self._n
         self._n += 1
+
+    def maybe_sample(self, state, batch) -> None:
+        self.count_step(self.fn(state, batch) if self.due else None)
 
     def epoch_snapshot(self) -> Optional[Dict[str, Any]]:
         """Materialize the epoch's sampled diagnostics (one D2H sync),

@@ -94,7 +94,8 @@ def span_iter(iterable: Iterable, name: str) -> Iterator:
 
 
 def count(name: str, n: float) -> None:
-    """Add ``n`` to the counter ``name`` (``graphs``, ``steps``)."""
+    """Add ``n`` to the counter ``name`` (``graphs``, ``steps``,
+    ``diagnosed_steps``)."""
     if telemetry_enabled():
         with _LOCK:
             _COUNTS[name] = _COUNTS.get(name, 0) + n

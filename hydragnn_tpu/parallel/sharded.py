@@ -189,7 +189,7 @@ def make_sharded_train_step(
     params + optimizer leaves over the ``fsdp`` axis — XLA turns the
     replicated-in / sharded-out constraint pair into the all-gather /
     reduce-scatter FSDP pattern)."""
-    from hydragnn_tpu.train.state import _cast_floats
+    from hydragnn_tpu.models.base import cast_floats
 
     axes = _axes_arg(batch_axes)
     lead = _lead_spec(batch_axes)
@@ -204,8 +204,8 @@ def make_sharded_train_step(
 
         def loss_fn(p):
             if compute_dtype is not None:
-                ap = _cast_floats(p, compute_dtype)
-                ab = _cast_floats(batch, compute_dtype)
+                ap = cast_floats(p, compute_dtype)
+                ab = cast_floats(batch, compute_dtype)
             else:
                 ap, ab = p, batch
             outputs, mutated = model.apply(

@@ -32,6 +32,7 @@ import numpy as np
 from jax.experimental import multihost_utils
 
 from hydragnn_tpu.models.base import HydraModel, ModelConfig
+from hydragnn_tpu.obs.introspect import HeadDiagnostics, make_diagnostics_step
 from hydragnn_tpu.obs.spans import StepSpans, count, span, span_iter
 from hydragnn_tpu.parallel.mesh import local_view
 from hydragnn_tpu.resilience import NonFiniteRollbackExhausted, TrainingPreempted
@@ -39,6 +40,7 @@ from hydragnn_tpu.train.optimizer import current_learning_rate, set_learning_rat
 from hydragnn_tpu.train.run import config_profiler, prepare_run
 from hydragnn_tpu.train.state import (
     TrainState,
+    make_diagnosed_first_step,
     make_eval_step,
     make_scan_epoch,
     make_scan_eval,
@@ -331,7 +333,7 @@ def _landing_checked(cached, fresh, ecache, key, expected_delta, label):
 def train_epoch_scan(
     loader, state: TrainState, scan_fn, epoch: int, diag=None, sentry=None
 ) -> Tuple[TrainState, float, np.ndarray]:
-    """One training epoch as a single device dispatch (``Training.
+    """One training epoch as device-resident dispatches (``Training.
     scan_epoch``): lax.scan over the loader's device-resident stacked
     batches, shuffled device-side by an epoch-seeded permutation of the
     batch axis (sample-to-batch membership reshuffles only when the
@@ -339,13 +341,18 @@ def train_epoch_scan(
     ``GraphLoader.stacked_device_batches``). Same weighted-metric
     semantics as ``train_epoch``.
 
-    ``diag`` (obs/introspect.py:HeadDiagnostics): sampled ONCE per epoch
-    on the first scheduled batch, BEFORE the donating scan consumes the
-    state — scan mode has no step granularity, so per-epoch is the
-    sampling floor. ``sentry``: when the scan_fn is the GUARDED variant
+    ``scan_fn`` is ONE callable that runs the whole epoch: ``(state,
+    stacked, order[, consec]) -> (state, losses[B], tasks[B, H],
+    counts[B][, bads[B], consec])``, one entry per step in ``order``'s
+    order (``benchmark/taps.py`` wraps it and reads positions 0, 1, 3).
+    ``diag`` (obs/introspect.py:HeadDiagnostics) is given where that
+    callable is :meth:`DispatchPlan.first_step_epoch`, whose first step
+    is the diagnosed one: the device dictionary it returns behind the
+    rest is handed to ``diag``, and nothing is dispatched for the
+    diagnostics here. ``sentry``: when the scan_fn is the GUARDED variant
     (make_scan_epoch(guard_nonfinite=True)), the per-step bad flags and
     the carry's consecutive counter are handed to it, device-resident."""
-    # the four places the chip is known to wait inside this call
+    # the places the chip is known to wait inside this call
     with span("epoch.train"):
         with span("train.stack"):
             stacked = loader.stacked_device_batches(epoch)
@@ -355,27 +362,23 @@ def train_epoch_scan(
             else:
                 order = np.arange(nb)
             order_dev = jnp.asarray(order, dtype=jnp.int32)
-        if diag is not None:
-            with span("train.diag_sample"):
-                # DEVICE-scalar index: a Python-int index would bake the batch
-                # position into the gather executable and recompile every epoch
-                # (the shuffle moves order[0]), tripping the zero-unexpected-
-                # recompile contract the compile monitor enforces
-                i0 = jnp.asarray(order[0], dtype=jnp.int32)
-                first = jax.tree_util.tree_map(lambda x: x[i0], stacked)
-                diag.maybe_sample(state, first)
         with span("train.dispatch"):
+            consec = () if sentry is None else (sentry.consec,)
+            out = scan_fn(state, stacked, order_dev, *consec)
+            if diag is not None:
+                *out, diagnostics = out
+                diag.count_step(diagnostics)
             if sentry is not None:
-                state, losses, tasks, counts, bads, consec = scan_fn(
-                    state, stacked, order_dev, sentry.consec
-                )
+                state, losses, tasks, counts, bads, consec = out
                 sentry.observe_scan(bads, consec)
             else:
-                state, losses, tasks, counts = scan_fn(state, stacked, order_dev)
+                state, losses, tasks, counts = out
         with span("train.sync"):
             avg_loss, avg_tasks, graphs = _finalize_scan(losses, tasks, counts)
     count("graphs", graphs)
     count("steps", nb)
+    # steps whose update came from the program that diagnosed them
+    count("diagnosed_steps", int(diag is not None))
     return state, avg_loss, avg_tasks
 
 
@@ -598,7 +601,8 @@ def scan_dispatch_planned(
 class DispatchPlan:
     """Which programs run an epoch, decided once at set-up: the choice
     (whole-epoch ``lax.scan`` or per step, and why; the non-finite guard;
-    where the test split lives, and why), the step functions it built or
+    where the test split lives, and why; which program diagnoses the
+    sampled step, :meth:`open_diagnostics`), the step functions it built or
     was handed, the executable-cache twin of the train program
     (:meth:`wire_exec_cache`) and the manifest's ``dispatch_mode`` block.
     The epoch loop calls :meth:`train`, :meth:`validate` and :meth:`test`
@@ -654,14 +658,22 @@ class DispatchPlan:
         # consecutive-bad counter through the carry. Sharded callers pass
         # their own step and keep their own policy.
         self.guard_nonfinite = bool(training.get("nonfinite_guard", True)) and loop_owned
-        build = dict(
+        self._build = build = dict(
             compute_dtype=self.compute_dtype,
             remat=bool(training.get("remat", False)),
             guard_nonfinite=self.guard_nonfinite,
         )
         self.scan_fn = self.scan_eval_fn = None
+        # set by open_diagnostics, which set-up calls once telemetry is known
+        self.first_step = self.diag_every = None
+        # the first epoch without a sample on the first-step path: the plain
+        # scan is traced there, not in epoch 0 (Run.record_epoch's ``compiles``)
+        self.first_plain_epoch = None
+        self.diag_path, self.diag_reason = "off", "introspection not opened"
         if use_scan:
-            self.scan_fn = make_scan_epoch(model, tx, **build)
+            # the scan after a diagnosed first step is the same jitted function
+            # called with ``first``, until wire_exec_cache replaces either
+            self.scan_fn = self.scan_rest = make_scan_epoch(model, tx, **build)
             if eval_step is None:  # a caller-supplied eval_step keeps priority
                 # auto mode must not die on an unstackable VAL split —
                 # eval falls back to per-step, training stays scanned
@@ -697,8 +709,51 @@ class DispatchPlan:
                     "path": "on_device" if self.test_kept else "rebuilt",
                     "reason": self.test_reason,
                 },
+                # the sampled step's diagnostics come from the epoch's first
+                # train step, from an observer program before it, or not at all
+                "diagnostics": {"path": self.diag_path, "reason": self.diag_reason},
             },
         }
+
+    def open_diagnostics(self, model, tx, on: bool, head_names, diag_every: int):
+        """Which program diagnoses the sampled step; returns the run's
+        ``HeadDiagnostics`` or None. Where the loop scans its epochs (one
+        device, its own step), the sampled step is the epoch's first train
+        step, run once by the program that diagnoses it (``first_step``:
+        train/state.py:make_diagnosed_first_step, then the scan over the
+        other steps; :meth:`first_step_epoch`). A per-step loop is handed
+        batches one at a time and dispatches the observer before the
+        sampled step (``observer``: obs/introspect.py:make_diagnostics_step;
+        the forward and the gradient run twice there). A caller-supplied
+        step is not the loop's to diagnose (``off``)."""
+        self.diag_path = "off"
+        if not on:
+            self.diag_reason = "Training.diagnostics, HYDRAGNN_DIAGNOSTICS or telemetry is off"
+            return None
+        if not self.loop_owned:
+            self.diag_reason = "caller-supplied train step"
+            return None
+        observer = None
+        self.diag_every = self.diag_stride(diag_every)
+        if self.scan_fn is not None:
+            self.first_step = make_diagnosed_first_step(model, tx, **self._build)
+            self.diag_path = "first_step"
+            self.diag_reason = "scan dispatch: the sampled step is the epoch's first train step"
+        else:
+            observer = make_diagnostics_step(
+                model, tx, compute_dtype=self.compute_dtype, remat=self._build["remat"]
+            )
+            self.diag_path = "observer"
+            self.diag_reason = "per-step dispatch: an observer program before the sampled step"
+        return HeadDiagnostics(observer, head_names, every=self.diag_every)
+
+    def first_step_epoch(self, state, stacked, order, *consec):
+        """A scanned epoch whose first step is the diagnosed one: two
+        dispatches, both queued before the host blocks. The signature and
+        the leading outputs are the plain scan's (``train_epoch_scan``'s
+        ``scan_fn``); the diagnostics dictionary comes behind them."""
+        state, first, *consec, diagnostics = self.first_step(state, stacked, order, *consec)
+        return (*self.scan_rest(state, stacked, order, *consec, first), diagnostics)
 
     def step_args(self, state, batch) -> tuple:
         """What the per-step train program is lowered with."""
@@ -706,17 +761,17 @@ class DispatchPlan:
 
     def diag_stride(self, diag_every: int) -> int:
         """Per-step mode samples every ``diag_every`` steps (default once
-        per epoch). Scan mode calls the sampler once per EPOCH
-        (train_epoch_scan), so diag_every converts to an epoch stride
-        there — the sampling floor one dispatch per epoch allows."""
+        per epoch). Scan mode diagnoses an epoch's FIRST step or none
+        (:meth:`train`), so diag_every converts to an epoch stride
+        there — the sampling floor whole-epoch dispatch allows."""
         nb = max(len(self.train_loader), 1)
         return max(1, diag_every // nb) if self.scan_fn is not None else diag_every or nb
 
     def step_time(self, spans) -> Tuple[Optional[dict], dict]:
         """(the per-step decomposition or None, the epoch event's
-        ``step_time``). Scan mode is ONE device dispatch per epoch: its
-        host side is in ``phases`` (train.stack, train.diag_sample,
-        train.dispatch, train.sync), its steps exist only on the device."""
+        ``step_time``). Scan mode is one or two device dispatches per
+        epoch: its host side is in ``phases`` (train.stack, train.dispatch,
+        train.sync), its steps exist only on the device."""
         if self.scan_fn is not None:
             return None, {"mode": "scan_epoch"}
         snap = spans.epoch_snapshot()
@@ -765,12 +820,21 @@ class DispatchPlan:
                 tr_parent["Training"] = tr_key
             arch = fingerprint(cfg_key, abstract_fingerprint(state))
             is_scan = self.scan_fn is not None
+            # where every epoch starts with the diagnosed step, the scanned
+            # program that runs is the one after it, a step shorter
+            after_first = self.first_step is not None and self.diag_every == 1
             if is_scan:
-                order0 = jnp.arange(len(self.train_loader), dtype=jnp.int32)
-                cargs = (state, self.train_loader.stacked_device_batches(0), order0)
+                nb = len(self.train_loader)
+                cargs = (state, self.train_loader.stacked_device_batches(0), jnp.arange(nb, dtype=jnp.int32))
                 if self.guard_nonfinite:
                     cargs += (jnp.zeros((), jnp.int32),)
-                label, delta, raw = "scan_epoch", int(order0.shape[0]), self.scan_fn
+                label, delta, raw = "scan_epoch", nb, self.scan_fn
+                if after_first:
+                    # the first step's loss, tasks[H], count[, bad]
+                    scalar = jnp.zeros((), jnp.float32)
+                    first = (scalar, jnp.zeros((self.cfg.num_heads,), jnp.float32), scalar)
+                    cargs += (first + ((scalar,) if self.guard_nonfinite else ()),)
+                    label, delta = "scan_epoch_after_first", nb - 1
             else:
                 cargs = self.step_args(state, next(iter(self.train_loader)))
                 label, delta, raw = "train_step", 1, self.train_step
@@ -796,7 +860,9 @@ class DispatchPlan:
                 exe = _landing_checked(
                     exe, cache_fn, ecache, ckey, expected_delta=delta, label=label
                 )
-            if is_scan:
+            if after_first:
+                self.scan_rest = exe
+            elif is_scan:
                 self.scan_fn = exe
             else:
                 self.train_step = exe
@@ -823,11 +889,19 @@ class DispatchPlan:
         (state, avg_loss, avg_tasks_loss[H])."""
         if self.scan_fn is not None:
             if run.incidents is not None:
-                # scan mode is one dispatch per epoch: a single tick
+                # scan mode dispatches an epoch at once: a single tick
                 # here spans the whole epoch's capture window
                 run.incidents.tick()
+            epoch_fn, diag = self.scan_fn, None
+            if self.first_step is not None:
+                if run.diag.due:
+                    epoch_fn, diag = self.first_step_epoch, run.diag
+                else:
+                    run.diag.count_step()  # an epoch without a sample: the plain scan
+                    if self.first_plain_epoch is None:
+                        self.first_plain_epoch = epoch
             return train_epoch_scan(
-                self.train_loader, state, self.scan_fn, epoch, diag=run.diag, sentry=run.sentry
+                self.train_loader, state, epoch_fn, epoch, diag=diag, sentry=run.sentry
             )
         return train_epoch(
             self.train_loader, state, self.train_step, self.verbosity, profiler=run.profiler,
@@ -1147,8 +1221,9 @@ def train_validate_test(
 # when the call that opened it returns; with this frame 100+ slots smaller, the deep
 # recursion that traces and lowers the diagnostics step in epoch 0 oscillates across
 # a chunk boundary and takes 25-60% more host time (PERF.md, PR 29: 7 s of a cell's
-# 80 s of set-up on the chip's host). The frame keeps its old size until the first
-# trace of that step leaves epoch 0 (ROADMAP S4, S6).
+# 80 s of set-up on the chip's host; PR 30: the diagnosed first step, traced under
+# train.dispatch, lowers in 3.1 s with it and 3.8 s without on the CPU reproducer).
+# The frame keeps its old size until that first trace leaves epoch 0 (ROADMAP S4).
 train_validate_test.__code__ = train_validate_test.__code__.replace(
     co_stacksize=train_validate_test.__code__.co_stacksize + 160
 )

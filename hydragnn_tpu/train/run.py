@@ -22,8 +22,7 @@ from hydragnn_tpu.obs import CompileMonitor, StepSpans, get_registry, telemetry_
 from hydragnn_tpu.obs.drift import build_reference
 from hydragnn_tpu.obs.export import registry_to_prometheus
 from hydragnn_tpu.obs.introspect import (
-    HardwareLedger, HeadDiagnostics, conv_traffic_model, make_diagnostics_step,
-    pad_waste_from_batch, per_head_error_metrics,
+    HardwareLedger, conv_traffic_model, pad_waste_from_batch, per_head_error_metrics,
 )
 from hydragnn_tpu.obs.spans import drain, drain_counts, span
 from hydragnn_tpu.obs.trace import Tracer
@@ -217,8 +216,10 @@ class Run:
             n_compiles = cmon.count_since("epoch_start")
             compiles["count"] = n_compiles
             compiles["seconds"] = round(cmon.seconds_since("epoch_start"), 6)
+            # the epochs that trace a train program for the first time
+            first_trace = (self.loop_state.start_epoch, self.plan.first_plain_epoch)
             compiles["unexpected"] = bool(
-                cmon.available and epoch > self.loop_state.start_epoch and n_compiles > 0
+                cmon.available and epoch not in first_trace and n_compiles > 0
             )
         extra: Dict[str, Any] = {}
         if nonfinite:
@@ -247,9 +248,11 @@ class Run:
             test_tasks=_named_tasks(names, test_tasks),
             step_time=step_time,
             compiles=compiles,
-            # real graphs through an optimizer step, and steps
+            # real graphs through an optimizer step, steps, and those of them
+            # whose update came from the program that diagnosed them
             graphs=int(counts.get("graphs", 0)),
             steps=int(counts.get("steps", 0)),
+            diagnosed_steps=int(counts.get("diagnosed_steps", 0)),
             # the program's spans closed so far this epoch
             # (obs/spans.py:span; docs/OBSERVABILITY.md "Program
             # spans"); the ones still open follow as phases_late
@@ -434,7 +437,8 @@ def _open_introspection(run: Run, model, tx, state, train_loader) -> None:
     """Model-level introspection (hydragnn_tpu/obs/introspect.py,
     docs/OBSERVABILITY.md "Model-level diagnostics"): per-head
     gradient diagnostics sampled every Training.diag_every steps
-    (default: once per epoch), per-head eval MAE/RMSE off the
+    (default: once per epoch; DispatchPlan.open_diagnostics says from
+    which program), per-head eval MAE/RMSE off the
     test_epoch gather path, and the hardware-efficiency ledger
     (compiled-step FLOPs from the LOWERED module — no second compile
     — turned into per-epoch achieved TFLOP/s + MFU + memory
@@ -453,18 +457,13 @@ def _open_introspection(run: Run, model, tx, state, train_loader) -> None:
         and bool(training.get("diagnostics", True))
         and knobs.get_bool("HYDRAGNN_DIAGNOSTICS", True)
     )
-    run.diag = run.ledger = None
+    run.ledger = None
+    # which program diagnoses the sampled step is the plan's to say
+    run.diag = plan.open_diagnostics(
+        model, tx, run.introspect_on, run.head_names, int(training.get("diag_every", 0))
+    )
     if not run.introspect_on:
         return
-    if plan.loop_owned:
-        run.diag = HeadDiagnostics(
-            make_diagnostics_step(
-                model, tx, compute_dtype=plan.compute_dtype,
-                remat=bool(training.get("remat", False)),
-            ),
-            head_names=run.head_names,
-            every=plan.diag_stride(int(training.get("diag_every", 0))),
-        )
     try:
         example = next(iter(train_loader))
         # the scan path runs the SAME step body nb times per

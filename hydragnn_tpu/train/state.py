@@ -10,8 +10,8 @@ are already a dict-of-heads on the batch.
 
 The name of each function handed to ``jax.jit`` is its compiled program's
 name (``jit_train_scan_epoch``, ``jit_train_step``, ``jit_eval_scan``,
-``jit_eval_step``, ``jit_eval_step_outputs``, ``jit_bn_stats_step``;
-``parallel/`` uses the same ones): a profiler trace's ``XLA Modules`` line
+``jit_eval_step``, ``jit_eval_step_outputs``, ``jit_bn_stats_step``,
+``jit_diagnostics_step``; ``parallel/`` uses the same ones): a profiler trace's ``XLA Modules`` line
 tells the programs apart by it, and ``benchmark/program_spans.py`` reads it.
 """
 
@@ -26,7 +26,8 @@ import optax
 from flax import struct
 
 from hydragnn_tpu.graph.batch import GraphBatch
-from hydragnn_tpu.models.base import HydraModel, model_loss
+from hydragnn_tpu.models.base import HydraModel, model_loss, train_loss_closure
+from hydragnn_tpu.obs.introspect import head_diagnostics, linearize_heads, make_diagnostics_step
 
 
 class TrainState(struct.PyTreeNode):
@@ -78,52 +79,21 @@ def create_eval_state(
     )
 
 
-def _cast_floats(tree: Any, dtype) -> Any:
-    """Cast float32 leaves to ``dtype`` (ints/bools untouched)."""
-    return jax.tree_util.tree_map(
-        lambda x: x.astype(dtype)
-        if hasattr(x, "dtype") and x.dtype == jnp.float32
-        else x,
-        tree,
-    )
-
-
-def _train_step_body(
-    model: HydraModel,
-    tx: optax.GradientTransformation,
-    compute_dtype=None,
-    remat: bool = False,
-) -> Callable[[TrainState, GraphBatch], Tuple[TrainState, jnp.ndarray, jnp.ndarray]]:
-    """The un-jitted per-batch training body shared by the jitted
-    single-step path and the scan-over-epoch path."""
-
-    def train_step(state: TrainState, batch: GraphBatch):
-        rng, dropout_rng = jax.random.split(state.rng)
-
-        def loss_fn(params):
-            if compute_dtype is not None:
-                apply_params = _cast_floats(params, compute_dtype)
-                apply_batch = _cast_floats(batch, compute_dtype)
-            else:
-                apply_params, apply_batch = params, batch
-            outputs, mutated = model.apply(
-                {"params": apply_params, "batch_stats": state.batch_stats},
-                apply_batch,
-                train=True,
-                mutable=["batch_stats"],
-                rngs={"dropout": dropout_rng},
-            )
-            # loss in f32 against the ORIGINAL (uncast) targets
-            outputs = [o.astype(jnp.float32) for o in outputs]
-            total, tasks = model_loss(model.cfg, outputs, batch)
-            return total, (jnp.stack(tasks), mutated)
-
-        lf = jax.checkpoint(loss_fn) if remat else loss_fn
-        (loss, (tasks, mutated)), grads = jax.value_and_grad(lf, has_aux=True)(
-            state.params
-        )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+def _land(state: TrainState, rng, loss, tasks, mutated, grads, updates, opt_state, consec=None):
+    """The step's new state from its update. Without ``consec``:
+    ``(state, loss, tasks)``. With it (the non-finite guard, the device
+    half of ``hydragnn_tpu/resilience/sentry.py``): a cheap on-device
+    ``isfinite(loss) & isfinite(global_norm(grads))`` check decides
+    whether the update LANDS. A bad batch leaves params, optimizer state,
+    BatchNorm statistics and the step counter at their previous values
+    (one fused ``where`` over the state, no host sync), and the return is
+    ``(state, loss, tasks, consec, bad)``: ``consec`` the consecutive-bad
+    counter (int32 device scalar, threaded by the caller across steps),
+    ``bad`` this step's flag as float32 (0.0/1.0); reported loss and task
+    losses are zeroed on bad steps so the epoch's weighted metrics (which
+    also zero the batch's count) stay clean."""
+    params = optax.apply_updates(state.params, updates)
+    if consec is None:
         new_state = state.replace(
             step=state.step + 1,
             params=params,
@@ -132,82 +102,60 @@ def _train_step_body(
             rng=rng,
         )
         return new_state, loss, tasks
+    bad = jnp.logical_not(jnp.isfinite(loss) & jnp.isfinite(optax.global_norm(grads)))
 
-    return train_step
+    def keep(new, old):
+        return jax.tree_util.tree_map(lambda a, b: jnp.where(bad, b, a), new, old)
+
+    new_state = state.replace(
+        step=state.step + jnp.where(bad, 0, 1).astype(state.step.dtype),
+        params=keep(params, state.params),
+        batch_stats=keep(mutated["batch_stats"], state.batch_stats),
+        opt_state=keep(opt_state, state.opt_state),
+        rng=rng,
+    )
+    return (
+        new_state,
+        jnp.where(bad, 0.0, loss),
+        jnp.where(bad, jnp.zeros_like(tasks), tasks),
+        jnp.where(bad, consec + 1, 0).astype(jnp.int32),
+        bad.astype(jnp.float32),
+    )
 
 
-def _guarded_step_body(
+def _train_step_body(
     model: HydraModel,
     tx: optax.GradientTransformation,
     compute_dtype=None,
     remat: bool = False,
-):
-    """Non-finite-guarded training body (the device half of
-    ``hydragnn_tpu/resilience/sentry.py``): runs the normal step, then
-    a cheap on-device ``isfinite(loss) & isfinite(global_norm(grads))``
-    check decides whether the update LANDS. A bad batch leaves params,
-    optimizer state, BatchNorm statistics and the step counter at their
-    previous values — one fused ``where`` over the state, no host sync.
+    guarded: bool = False,
+) -> Callable[..., Tuple]:
+    """The un-jitted per-batch training body shared by the jitted
+    single-step path and the scan-over-epoch path: ``(state, batch) ->
+    (state, loss, tasks)``, or ``guarded`` (:func:`_land`'s non-finite
+    guard) ``(state, batch, consec) -> (state, loss, tasks, consec, bad)``;
+    with all-finite inputs the two compute the same."""
 
-    Signature: ``(state, batch, consec) -> (state, loss, tasks, consec,
-    bad)`` where ``consec`` is the consecutive-bad-step counter
-    (int32 device scalar, threaded by the caller across steps) and
-    ``bad`` is this step's flag as float32 (0.0/1.0) — reported loss
-    and task losses are zeroed on bad steps so the epoch's weighted
-    metrics (which also zero the batch's count) stay clean.
-    """
-
-    def train_step(state: TrainState, batch: GraphBatch, consec: jnp.ndarray):
+    def step(state: TrainState, batch: GraphBatch, consec: Optional[jnp.ndarray]):
         rng, dropout_rng = jax.random.split(state.rng)
-
-        def loss_fn(params):
-            if compute_dtype is not None:
-                apply_params = _cast_floats(params, compute_dtype)
-                apply_batch = _cast_floats(batch, compute_dtype)
-            else:
-                apply_params, apply_batch = params, batch
-            outputs, mutated = model.apply(
-                {"params": apply_params, "batch_stats": state.batch_stats},
-                apply_batch,
-                train=True,
-                mutable=["batch_stats"],
-                rngs={"dropout": dropout_rng},
-            )
-            outputs = [o.astype(jnp.float32) for o in outputs]
-            total, tasks = model_loss(model.cfg, outputs, batch)
-            return total, (jnp.stack(tasks), mutated)
-
+        loss_fn = train_loss_closure(model, compute_dtype, state.batch_stats, batch, dropout_rng)
         lf = jax.checkpoint(loss_fn) if remat else loss_fn
         (loss, (tasks, mutated)), grads = jax.value_and_grad(lf, has_aux=True)(
             state.params
         )
-        bad = jnp.logical_not(
-            jnp.isfinite(loss) & jnp.isfinite(optax.global_norm(grads))
-        )
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        return _land(state, rng, loss, tasks, mutated, grads, updates, opt_state, consec)
 
-        def keep(new, old):
-            return jax.tree_util.tree_map(
-                lambda a, b: jnp.where(bad, b, a), new, old
-            )
+    # two defs of one name: the name is the compiled program's (module docstring)
+    if guarded:
 
-        new_state = state.replace(
-            step=state.step + jnp.where(bad, 0, 1).astype(state.step.dtype),
-            params=keep(params, state.params),
-            batch_stats=keep(mutated["batch_stats"], state.batch_stats),
-            opt_state=keep(opt_state, state.opt_state),
-            rng=rng,
-        )
-        badf = bad.astype(jnp.float32)
-        new_consec = jnp.where(bad, consec + 1, 0).astype(jnp.int32)
-        return (
-            new_state,
-            jnp.where(bad, 0.0, loss),
-            jnp.where(bad, jnp.zeros_like(tasks), tasks),
-            new_consec,
-            badf,
-        )
+        def train_step(state: TrainState, batch: GraphBatch, consec: jnp.ndarray):
+            return step(state, batch, consec)
+
+    else:
+
+        def train_step(state: TrainState, batch: GraphBatch):
+            return step(state, batch, None)
 
     return train_step
 
@@ -237,30 +185,97 @@ def make_train_step(
     returns the GUARDED step instead — signature ``(state, batch,
     consec) -> (state, loss, tasks_loss, consec, bad)`` — which skips
     any batch producing a non-finite loss or gradient norm (see
-    :func:`_guarded_step_body`; the host policy lives in
+    :func:`_land`; the host policy lives in
     ``hydragnn_tpu/resilience/sentry.py``). With all-finite inputs it
     computes exactly what the unguarded step computes.
 
-    ``diagnostics=True`` (config ``Training.diagnostics``) additionally
-    returns the jitted per-head diagnostics step — ``(train_step,
-    diag_step)`` — a SEPARATE executable over the same loss (per-head
-    gradient norms, inter-task cosine conflict matrix, update-to-param
-    ratio; see ``hydragnn_tpu/obs/introspect.py``) that the train loop
-    dispatches only on sampled steps, so the hot path's executable and
-    sync discipline are untouched."""
-    body = (
-        _guarded_step_body(model, tx, compute_dtype=compute_dtype, remat=remat)
-        if guard_nonfinite
-        else _train_step_body(model, tx, compute_dtype=compute_dtype, remat=remat)
+    ``diagnostics=True`` additionally returns the jitted per-head
+    diagnostics OBSERVER — ``(train_step, diag_step)`` — a separate
+    executable over the same loss (per-head gradient norms, inter-task
+    cosine conflict matrix, update-to-param ratio; see
+    ``hydragnn_tpu/obs/introspect.py``) that a per-step loop dispatches on
+    sampled steps before the train step, which then repeats the forward
+    and the gradient. A loop that scans its epochs does not pair the two:
+    it runs :func:`make_diagnosed_first_step` as the epoch's first train
+    step."""
+    body = _train_step_body(
+        model, tx, compute_dtype=compute_dtype, remat=remat, guarded=guard_nonfinite
     )
     step = jax.jit(body, donate_argnums=(0,))
     if diagnostics:
-        from hydragnn_tpu.obs.introspect import make_diagnostics_step
-
         return step, make_diagnostics_step(
             model, tx, compute_dtype=compute_dtype, remat=remat
         )
     return step
+
+
+def make_diagnosed_first_step(
+    model: HydraModel,
+    tx: optax.GradientTransformation,
+    compute_dtype=None,
+    remat: bool = False,
+    guard_nonfinite: bool = False,
+) -> Callable[..., Tuple]:
+    """The epoch's first train step, run ONCE by the program that
+    diagnoses it (``Training.diagnostics`` under the whole-epoch scan).
+
+    One linearisation of the stacked task losses serves the diagnostics
+    (H head pulls) and the update (the pull with the task weights as
+    cotangent, which is the train step's backward), so the sampled step is
+    not observed and then repeated. Its batch is cut from the stack inside
+    the program, ``stacked[order[0]]``, as the scan body cuts its own:
+    ``order`` is a device value, so the shuffle moving ``order[0]``
+    compiles nothing. Training's numbers are the plain step's to rounding.
+
+    Returns jitted ``(state, stacked, order) -> (state, (loss, tasks[H],
+    count), diagnostics)``, and with ``guard_nonfinite`` ``(state,
+    stacked, order, consec) -> (state, (loss, tasks[H], count, bad),
+    consec, diagnostics)`` with :func:`_land`'s guard. The state is
+    donated; the tuple is what ``make_scan_epoch``'s programs take as
+    ``first``; ``diagnostics`` is ``obs/introspect.py:head_diagnostics``'
+    dictionary. The composition is this module's (the closure, ``tx.update``
+    and :func:`_land` of every other step body); ``obs/`` lends the two
+    pure functions ``linearize_heads`` and ``head_diagnostics``, which its
+    observer calls around the same closure.
+    The compiled program keeps the observer's name,
+    ``jit_diagnostics_step``: it is the program that carries the
+    diagnostics (``benchmark/program_spans.py`` finds them by it)."""
+
+    def step(state: TrainState, stacked: GraphBatch, order: jnp.ndarray, consec):
+        i = order[0]
+        batch = jax.tree_util.tree_map(lambda x: x[i], stacked)
+        rng, dropout_rng = jax.random.split(state.rng)
+        loss_fn = train_loss_closure(model, compute_dtype, state.batch_stats, batch, dropout_rng)
+        loss, tasks, mutated, head_grads, grads = linearize_heads(
+            loss_fn, state.params, model.cfg.normalized_weights, remat=remat
+        )
+        updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        diagnostics = head_diagnostics(tasks, head_grads, grads, state.params, updates)
+        count = batch.graph_mask.sum().astype(jnp.float32)
+        landed = _land(state, rng, loss, tasks, mutated, grads, updates, opt_state, consec)
+        if consec is None:
+            state, loss, tasks = landed
+            return state, (loss, tasks, count), diagnostics
+        state, loss, tasks, consec, bad = landed
+        return state, (loss, tasks, count * (1.0 - bad), bad), consec, diagnostics
+
+    if guard_nonfinite:
+
+        def diagnostics_step(state, stacked, order, consec):
+            return step(state, stacked, order, consec)
+
+    else:
+
+        def diagnostics_step(state, stacked, order):
+            return step(state, stacked, order, None)
+
+    return jax.jit(diagnostics_step, donate_argnums=(0,))
+
+
+def _after_first(first: Tuple, rest: Tuple) -> Tuple:
+    """The epoch's per-step outputs: the first step's in front of the
+    scanned steps'."""
+    return tuple(jnp.concatenate([f[None], r]) for f, r in zip(first, rest))
 
 
 def make_scan_epoch(
@@ -289,38 +304,45 @@ def make_scan_epoch(
 
     ``guard_nonfinite=True`` scans the GUARDED step body instead — the
     same on-device non-finite skip the per-step path gets
-    (:func:`_guarded_step_body`), with the consecutive-bad counter
+    (:func:`_land`), with the consecutive-bad counter
     threaded through the scan carry. Signature then becomes
     ``(state, stacked, order, consec0) -> (state, losses, tasks, counts,
     bads[B], consec_end)`` where bad steps contribute zero loss/count
     (the ``NonFiniteSentry.observe_scan`` contract).
+
+    Both take one more argument, ``first``: the per-step outputs of
+    :func:`make_diagnosed_first_step`, which has run ``order[0]`` on the
+    state handed in. The scan then runs ``order[1:]`` and returns the same
+    ``B``-long arrays with ``first`` in front (a second compiled program
+    of the same name, one step shorter).
     """
+    body = _train_step_body(
+        model, tx, compute_dtype=compute_dtype, remat=remat, guarded=guard_nonfinite
+    )
     if guard_nonfinite:
-        gbody = _guarded_step_body(
-            model, tx, compute_dtype=compute_dtype, remat=remat
-        )
 
         def train_scan_epoch_guarded(
             state: TrainState, stacked: GraphBatch, order: jnp.ndarray,
-            consec: jnp.ndarray,
+            consec: jnp.ndarray, first: Optional[Tuple] = None,
         ):
             def scan_body(carry, i: jnp.ndarray):
                 state, consec = carry
                 batch = jax.tree_util.tree_map(lambda x: x[i], stacked)
-                state, loss, tasks, consec, bad = gbody(state, batch, consec)
+                state, loss, tasks, consec, bad = body(state, batch, consec)
                 cnt = batch.graph_mask.sum().astype(jnp.float32) * (1.0 - bad)
                 return (state, consec), (loss, tasks, cnt, bad)
 
-            (state, consec), (losses, tasks, counts, bads) = jax.lax.scan(
-                scan_body, (state, consec), order
+            (state, consec), outs = jax.lax.scan(
+                scan_body, (state, consec), order if first is None else order[1:]
             )
+            losses, tasks, counts, bads = outs if first is None else _after_first(first, outs)
             return state, losses, tasks, counts, bads, consec
 
         return jax.jit(train_scan_epoch_guarded, donate_argnums=(0,))
 
-    body = _train_step_body(model, tx, compute_dtype=compute_dtype, remat=remat)
-
-    def train_scan_epoch(state: TrainState, stacked: GraphBatch, order: jnp.ndarray):
+    def train_scan_epoch(
+        state: TrainState, stacked: GraphBatch, order: jnp.ndarray, first: Optional[Tuple] = None
+    ):
         # Scan over the PERMUTATION, dynamic-indexing one batch out of the
         # closed-over stack per iteration: a full permuted copy of the
         # train split as scan xs would double the feature's HBM footprint.
@@ -329,7 +351,8 @@ def make_scan_epoch(
             new_state, loss, tasks = body(state, batch)
             return new_state, (loss, tasks, batch.graph_mask.sum().astype(jnp.float32))
 
-        state, (losses, tasks, counts) = jax.lax.scan(scan_body, state, order)
+        state, outs = jax.lax.scan(scan_body, state, order if first is None else order[1:])
+        losses, tasks, counts = outs if first is None else _after_first(first, outs)
         return state, losses, tasks, counts
 
     return jax.jit(train_scan_epoch, donate_argnums=(0,))

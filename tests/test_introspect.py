@@ -206,16 +206,58 @@ def test_diagnostics_zero_unexpected_recompiles_and_no_per_step_syncs(monkeypatc
     # next sampled step
     assert diag.epoch_snapshot() is None
 
+    # A loop that scans its epochs: the diagnosed step IS the epoch's first
+    # train step, its batch cut from the stack inside the program. Two
+    # executables in epoch 0, and none when the shuffle moves order[0];
+    # HeadDiagnostics dispatches nothing and syncs nothing.
+    from hydragnn_tpu.train.state import make_diagnosed_first_step, make_scan_epoch
 
-def test_telemetry_disabled_training_is_bit_identical(tmp_path, monkeypatch):
+    stacked = jax.tree_util.tree_map(lambda x: jnp.stack([x] * 3), batch)
+    first, scan = make_diagnosed_first_step(model, tx), make_scan_epoch(model, tx)
+    diag = HeadDiagnostics(None, cfg.output_names, every=1)
+    state = create_train_state(variables, tx)
+
+    def epoch(state, order):
+        assert diag.due
+        state, head, diagnostics = first(state, stacked, jnp.asarray(order, jnp.int32))
+        diag.count_step(diagnostics)
+        return scan(state, stacked, jnp.asarray(order, jnp.int32), head)
+
+    with CompileMonitor() as mon:
+        state, losses, _, counts = epoch(state, [0, 1, 2])
+        jax.block_until_ready(losses)
+        mon.mark("warm")
+        monkeypatch.setattr(jax, "block_until_ready", _boom)
+        monkeypatch.setattr(jax, "device_get", _boom)
+        for order in ([2, 0, 1], [1, 2, 0]):
+            state, losses, _, counts = epoch(state, order)
+        monkeypatch.undo()
+        jax.block_until_ready(losses)
+        assert mon.count_since("warm") == 0, "a shuffled order recompiled the diagnosed first step"
+    assert int(state.step) == 9 and losses.shape == counts.shape == (3,)
+    assert diag.epoch_snapshot()["sampled_step"] == 2
+
+
+@pytest.mark.parametrize("dispatch", ["per_step", "scan_diagnostics_off", "scan"])
+def test_telemetry_disabled_training_is_bit_identical(tmp_path, monkeypatch, dispatch):
     """HYDRAGNN_TELEMETRY=0 must leave the training computation
-    untouched: same config + data + seeds with telemetry (and its
-    default-on diagnostics) fully enabled vs fully disabled produce
-    bit-identical final parameters."""
+    untouched: same config + data + seeds with telemetry fully enabled vs
+    fully disabled produce bit-identical final parameters, wherever
+    telemetry adds no program to the ones that train: with its default-on
+    diagnostics as an observer (per-step dispatch), and under the scan
+    with the diagnostics off. Under the scan with the diagnostics on, the
+    epoch's first train step is run by the program that diagnoses it
+    (ISSUE 30: "training's numbers are today's to rounding"): the same
+    operations from another program, held to rounding element by element
+    by the rule of ``assert_adam_states_agree_to_rounding``, over the
+    whole state."""
+    import glob
+
     from hydragnn_tpu.api import run_training
     from hydragnn_tpu.data.synthetic import deterministic_graph_data
     from hydragnn_tpu.flagship import flagship_config
-    from hydragnn_tpu.obs import reset_registry
+    from hydragnn_tpu.obs import read_flight_record, reset_registry
+    from test_diagnosed_step import assert_adam_states_agree_to_rounding
 
     def _run(log_dir, telemetry: bool):
         if not telemetry:
@@ -224,12 +266,14 @@ def test_telemetry_disabled_training_is_bit_identical(tmp_path, monkeypatch):
             monkeypatch.delenv("HYDRAGNN_TELEMETRY", raising=False)
             # the on-run must exercise the full introspection path the
             # suite's conftest otherwise disables
-            monkeypatch.setenv("HYDRAGNN_DIAGNOSTICS", "1")
+            monkeypatch.setenv("HYDRAGNN_DIAGNOSTICS", "0" if dispatch == "scan_diagnostics_off" else "1")
         reset_registry()
         try:
             cfg = flagship_config(
                 hidden_dim=8, num_conv_layers=2, batch_size=5, num_epoch=1
             )
+            if dispatch == "per_step":
+                cfg["NeuralNetwork"]["Training"]["scan_epoch"] = False
             samples = deterministic_graph_data(
                 number_configurations=20,
                 unit_cell_x_range=(2, 3),
@@ -238,16 +282,24 @@ def test_telemetry_disabled_training_is_bit_identical(tmp_path, monkeypatch):
                 seed=0,
             )
             _, state, _, _ = run_training(cfg, samples=samples, log_dir=str(log_dir))
-            return jax.device_get(state.params)
+            return jax.device_get(state)
         finally:
             monkeypatch.delenv("HYDRAGNN_TELEMETRY", raising=False)
             reset_registry()
 
-    p_on = _run(tmp_path / "on", telemetry=True)
-    p_off = _run(tmp_path / "off", telemetry=False)
-    flat_on, flat_off = _flatten_tree(p_on), _flatten_tree(p_off)
+    on = _run(tmp_path / "on", telemetry=True)
+    off = _run(tmp_path / "off", telemetry=False)
+    events = read_flight_record(glob.glob(str(tmp_path / "on") + "/*/flight.jsonl")[0])
+    manifest = [e for e in events if e.get("kind") == "run_start"][0]["manifest"]
+    want = {"per_step": "observer", "scan_diagnostics_off": "off", "scan": "first_step"}[dispatch]
+    assert manifest["dispatch_mode"]["diagnostics"]["path"] == want
+    flat_on, flat_off = _flatten_tree(on.params), _flatten_tree(off.params)
     assert flat_on.shape == flat_off.shape
-    np.testing.assert_array_equal(flat_on, flat_off)
+    if dispatch == "scan":
+        assert int(on.step) == int(off.step) == 4
+        assert_adam_states_agree_to_rounding(on, off, lr=1e-3, steps=4)
+    else:
+        np.testing.assert_array_equal(flat_on, flat_off)
 
 
 # ---------------------------------------------------------------------------
@@ -517,3 +569,17 @@ def test_head_diagnostics_sampling_cadence():
     assert snap["sampled_step"] == 8
     assert snap["grad_norm"] == {"a": 1.0, "b": 2.0}
     assert snap["update_ratio"] == pytest.approx(0.025)
+
+    # a loop that scans counts epochs, asks ``due`` and hands the diagnosed
+    # first step's dictionary over: no observer, the same cadence
+    diag = HeadDiagnostics(None, ["a", "b"], every=2)
+    due = []
+    for epoch in range(5):
+        due.append(diag.due)
+        diag.count_step(fake_fn(epoch, None) if diag.due else None)
+        snap = diag.epoch_snapshot()
+        assert (snap is not None) == due[-1]
+        if snap is not None:
+            assert snap["sampled_step"] == epoch
+    assert due == [True, False, True, False, True]
+    assert calls[3:] == [0, 2, 4]

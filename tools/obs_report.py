@@ -133,12 +133,13 @@ def render_phases(events: List[dict]) -> List[str]:
     )
     lines.append("== epoch phases (ms) ==")
     lines.append("  " + " ".join([f"{'ep':>4}", f"{'epoch':>9}"] + [f"{c.split('.', 1)[1][:13]:>13}" for c in cols]
-                                 + [f"{'graphs':>8}", f"{'steps':>6}"]))
+                                 + [f"{'graphs':>8}", f"{'steps':>6}", f"{'diagnosed':>9}"]))
     for ep in sorted(by_epoch):
         ph = by_epoch[ep]
         cells = [f"{ep:>4}", f"{_ms(ph.get('epoch')):>9}"] + [f"{_ms(ph.get(c)):>13}" for c in cols]
         ev = counts.get(ep, {})
-        lines.append("  " + " ".join(cells + [f"{ev.get('graphs', '-'):>8}", f"{ev.get('steps', '-'):>6}"]))
+        lines.append("  " + " ".join(cells + [f"{ev.get('graphs', '-'):>8}", f"{ev.get('steps', '-'):>6}",
+                                              f"{ev.get('diagnosed_steps', '-'):>9}"]))
     # whole epochs only: one cut short by a graceful stop has no ``epoch`` span
     whole = [ph for _, ph in sorted(by_epoch.items()) if "epoch" in ph]
     steady = whole[1:] or whole or list(by_epoch.values())
