@@ -167,6 +167,9 @@ def _prepare_samples(
     voi = nn_cfg["Variables_of_interest"]
     nf, gf = ds_cfg["node_features"], ds_cfg["graph_features"]
 
+    if ds_cfg.get("format") == "token_documents":
+        return _check_token_samples(samples, voi)
+
     scale_features_by_num_nodes(samples, gf["name"], nf["name"], gf["dim"], nf["dim"])
     mm_g, mm_n = normalize_dataset(samples, gf["dim"], nf["dim"])
 
@@ -191,6 +194,25 @@ def _prepare_samples(
     )
     select_input_features(samples, voi["input_node_features"], nf["dim"])
     return mm_g, mm_n
+
+
+def _check_token_samples(samples: List[GraphSample], voi: Dict) -> Tuple[np.ndarray, np.ndarray]:
+    """``Dataset.format: "token_documents"`` (``data/tokens.py``): the node
+    features are integers (token id, index in the document, which copy) and
+    the targets are in place, so no step of the module docstring applies:
+    nothing is scaled or normalised, no edge is built (a sample without an
+    ``edge_index`` gets an empty one), no column is selected. Returns empty
+    min-max tables."""
+    names = list(voi["output_names"])
+    for s in samples:
+        if s.x.dtype != np.int32 or s.x.ndim != 2:
+            raise ValueError(f"a token document's node features are int32 [n, F], got {s.x.dtype} {s.x.shape}")
+        missing = [n for n in names if n not in s.node_targets or n + "_weight" not in s.node_targets]
+        if missing:
+            raise ValueError(f"a token document needs node_targets[name] and node_targets[name + '_weight'] for {missing}")
+        if s.edge_index is None:
+            s.edge_index = np.zeros((2, 0), np.int32)
+    return np.zeros((2, 0)), np.zeros((2, 0))
 
 
 def prepare_dataset(

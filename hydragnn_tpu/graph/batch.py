@@ -93,7 +93,7 @@ class GraphBatch:
     """A fixed-shape batch of graphs.
 
     Attributes:
-      nodes: [N, F] node features.
+      nodes: [N, F] node features (float32; int32 for token documents).
       senders / receivers: [E] int32 edge endpoints (message flows
         sender -> receiver, matching PyG's edge_index[0] -> edge_index[1]).
       edge_attr: [E, De] edge features, or None.
@@ -366,8 +366,8 @@ def batch_graphs(
             f"for real totals (nodes {tot_nodes}, edges {tot_edges})"
         )
 
-    feat_dim = _as_2d(graphs[0]["x"]).shape[1]
-    nodes = np.zeros((n_node_pad, feat_dim), dtype=np.float32)
+    x0 = _as_2d(graphs[0]["x"])
+    nodes = np.zeros((n_node_pad, x0.shape[1]), dtype=x0.dtype)
     senders = np.full((n_edge_pad,), tot_nodes, dtype=np.int32)
     receivers = np.full((n_edge_pad,), tot_nodes, dtype=np.int32)
     node_graph = np.full((n_node_pad,), n_graphs, dtype=np.int32)
@@ -390,8 +390,8 @@ def batch_graphs(
     g_targets: Dict[str, list] = {}
     n_targets: Dict[str, Any] = {}
     for name in nt_names:
-        d = _as_2d(graphs[0]["node_targets"][name]).shape[1]
-        n_targets[name] = np.zeros((n_node_pad, d), dtype=np.float32)
+        t0 = _as_2d(graphs[0]["node_targets"][name])
+        n_targets[name] = np.zeros((n_node_pad, t0.shape[1]), dtype=t0.dtype)
 
     node_off, edge_off = 0, 0
     for gi, g in enumerate(graphs):
@@ -744,7 +744,14 @@ def pad_batch(batch: GraphBatch, n_node: int, n_edge: int, n_graph: int) -> Grap
 
 
 def _as_2d(a) -> np.ndarray:
-    a = np.asarray(a, dtype=np.float32)
+    """[n] or [n, d] as [n, d] float32. An int32 array stays int32: token
+    ids and indices (``data/tokens.py``), an embedding's input and a
+    cross-entropy's target, which no cast to a float may touch; any other
+    dtype (numpy's default int64 among them) is a feature and becomes
+    float32 as before."""
+    a = np.asarray(a)
+    if a.dtype != np.int32:
+        a = a.astype(np.float32, copy=False)
     return a[:, None] if a.ndim == 1 else a
 
 

@@ -26,18 +26,22 @@ Differences by design:
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
 
+from hydragnn_tpu.data.tokens import COPY
 from hydragnn_tpu.graph import segment as S
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models import convs as C
 from hydragnn_tpu.models.layers import MLP, MaskedBatchNorm
 
-KNOWN_MODELS = ("GIN", "PNA", "GAT", "MFC", "CGCNN", "SAGE", "SchNet")
+# The token stack (models/token_stack.py) is imported where a configuration
+# names it, with its kernels: the conv models load none of it.
+TOKEN_MODELS = ("BlockDiffusionMoE",)
+KNOWN_MODELS = ("GIN", "PNA", "GAT", "MFC", "CGCNN", "SAGE", "SchNet") + TOKEN_MODELS
 
 
 @dataclasses.dataclass(frozen=True, eq=True)
@@ -110,10 +114,73 @@ class ModelConfig:
     # hydragnn/utils/distributed.py:227-228). None = per-device stats,
     # matching DDP's default non-synced BatchNorm.
     bn_axis_name: Optional[str] = None
+    # The token stack (models/token_stack.py; hidden_dim and
+    # num_conv_layers are its width and depth): grouped-query attention
+    # under the block-diffusion mask, a mixture of experts of which
+    # ``experts_held`` from ``expert_offset`` live here, the vocabulary held.
+    num_attention_heads: Optional[int] = None
+    num_key_value_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    num_experts: Optional[int] = None
+    num_experts_per_tok: Optional[int] = None
+    moe_intermediate_size: Optional[int] = None
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    vocab_size: Optional[int] = None
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-6
+    block_length: int = 4
+
+    @property
+    def is_token_stack(self) -> bool:
+        return self.model_type in TOKEN_MODELS
+
+    @property
+    def has_batch_norm(self) -> bool:
+        """Whether the stack holds BatchNorm statistics, which the end of
+        training recalibrates (train/loop.py)."""
+        return not self.is_token_stack
+
+    def manifest_block(self) -> Dict[str, Any]:
+        """What the flight record's manifest says of the model beyond its
+        ``config``: nothing for the conv models."""
+        if not self.is_token_stack:
+            return {}
+        from hydragnn_tpu.models.token_stack import manifest_block
+
+        return manifest_block(self)
+
+    def epoch_counters(self, train_samples) -> Optional[Callable[[Any], Dict[str, Any]]]:
+        """``batch_stats -> {name: value}`` for the flight record's ``epoch``
+        event, where the stack counts something of its own; else None."""
+        if not self.is_token_stack:
+            return None
+        from hydragnn_tpu.models.token_stack import epoch_counters
+
+        return epoch_counters(train_samples)
 
     def __post_init__(self):
         if self.model_type not in KNOWN_MODELS:
             raise ValueError(f"Unknown model_type: {self.model_type}")
+        if self.is_token_stack:
+            need = ("num_attention_heads", "num_key_value_heads", "head_dim", "num_experts",
+                    "num_experts_per_tok", "moe_intermediate_size", "experts_held", "vocab_size")
+            missing = [k for k in need if getattr(self, k) is None]
+            if missing:
+                raise ValueError(f"{self.model_type} requires Architecture keys {missing}")
+            if self.output_type != ("node",) or self.loss_function_type != "cross_entropy":
+                raise ValueError(
+                    f"{self.model_type} has ONE node head over the vocabulary with "
+                    'Training.loss_function_type "cross_entropy"; got heads '
+                    f"{self.output_type} and {self.loss_function_type!r}"
+                )
+            if self.num_attention_heads % self.num_key_value_heads:
+                raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+            if not 0 <= self.expert_offset <= self.num_experts - self.experts_held:
+                raise ValueError(
+                    f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
+                    f"are not among the router's {self.num_experts}"
+                )
         if len(self.output_dim) != len(self.output_type) or len(self.output_dim) != len(
             self.output_names
         ):
@@ -342,6 +409,10 @@ class HydraModel(nn.Module):
         BatchNorm recalibration can run batch-stats forward passes with
         dropout off (hydragnn_tpu/train/state.py:make_stats_step)."""
         cfg = self.cfg
+        if cfg.is_token_stack:
+            from hydragnn_tpu.models.token_stack import TokenStack
+
+            return [TokenStack(cfg, name="tokens")(batch)]
         bn = train if bn_train is None else bn_train
         ctx = self._conv_args(batch)
         x = batch.nodes
@@ -479,6 +550,17 @@ def masked_loss(
     raise ValueError(f"Unknown loss function type: {kind}")
 
 
+def token_cross_entropy(logp: jnp.ndarray, weight: jnp.ndarray, mask: jnp.ndarray, counted: jnp.ndarray) -> jnp.ndarray:
+    """``-sum(weight * log p(target))`` over the real rows, divided by the
+    number of real rows that are ``counted`` (the tokens of the step: one
+    copy's rows). ``logp`` [N] is the vocabulary head's first column; a row
+    of weight 0 (unmasked, clean copy, padding) adds nothing, and its
+    ``logp`` is not looked at (so a NaN there stays out)."""
+    w = jnp.where(mask, weight, 0.0)
+    tokens = jnp.maximum((mask & counted).sum().astype(jnp.float32), 1.0)
+    return -jnp.where(w > 0, w * logp, 0.0).sum() / tokens
+
+
 def model_loss(
     cfg: ModelConfig, outputs: List[jnp.ndarray], batch: GraphBatch
 ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
@@ -495,7 +577,21 @@ def model_loss(
         else:
             target = batch.node_targets[name]
             mask = batch.node_mask
-        head_loss = masked_loss(cfg.loss_function_type, outputs[ihead], target, mask)
+        if cfg.loss_function_type == "cross_entropy":
+            # integer targets and a weight a node (data/tokens.py); the head
+            # has already picked the target's log-probability
+            if cfg.output_type[ihead] != "node" or name + "_weight" not in batch.node_targets:
+                raise ValueError(
+                    f'head {name!r}: "cross_entropy" is for a node head over a vocabulary '
+                    f"whose batch carries node_targets[{name + '_weight'!r}]"
+                )
+            head_loss = token_cross_entropy(
+                outputs[ihead][:, 0], batch.node_targets[name + "_weight"][:, 0], mask, batch.nodes[:, COPY] == 1
+            )
+        elif cfg.loss_function_type in ("mse", "mae", "rmse"):
+            head_loss = masked_loss(cfg.loss_function_type, outputs[ihead], target, mask)
+        else:
+            raise ValueError(f"head {name!r}: unknown loss function type {cfg.loss_function_type!r}")
         tasks_loss.append(head_loss)
         total = total + weights[ihead] * head_loss
     return total, tasks_loss

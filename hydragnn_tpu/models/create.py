@@ -11,6 +11,7 @@ initialized from a fixed PRNG seed, the analog of the reference's
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -90,6 +91,15 @@ def model_config_from_dict(
         freeze_conv=bool(arch.get("freeze_conv_layers", False)),
         initial_bias=arch.get("initial_bias"),
         bn_axis_name=bn_axis_name if arch.get("SyncBatchNorm") else None,
+        **{
+            key: arch[key]
+            for key in (
+                "num_attention_heads", "num_key_value_heads", "head_dim", "num_experts",
+                "num_experts_per_tok", "moe_intermediate_size", "experts_held", "expert_offset",
+                "vocab_size", "rope_theta", "rms_norm_eps", "block_length",
+            )
+            if arch.get(key) is not None
+        },
     )
 
 
@@ -126,7 +136,13 @@ def create_model(
             )
     model = HydraModel(cfg)
     rngs = {"params": jax.random.PRNGKey(seed), "dropout": jax.random.PRNGKey(seed + 1)}
-    variables = model.init(rngs, example_batch, train=False)
+    if cfg.is_token_stack:
+        # Under jit nothing of the forward pass survives (no initial value
+        # hangs on it), so a stack of real size is initialised without being
+        # run: its dense CPU attention path would not fit any host.
+        variables = jax.jit(functools.partial(model.init, train=False))(rngs, example_batch)
+    else:
+        variables = model.init(rngs, example_batch, train=False)
     if cfg.initial_bias is not None:
         variables = _set_initial_bias(variables, cfg)
     return model, variables

@@ -277,6 +277,10 @@ def linearize_heads(loss_fn, params, weights, remat: bool = False):
         cot = jnp.zeros((num_heads,), tasks.dtype).at[ihead].set(1.0)
         (g,) = vjp_fn(cot)
         head_grads.append(g)
+    if num_heads == 1 and float(weights[0]) == 1.0:
+        # one head of weight 1: its gradient IS the train loss's, and a
+        # second pull would hold a second tree of gradients for nothing
+        return loss, tasks, mutated, head_grads, head_grads[0]
     (total_grad,) = vjp_fn(jnp.asarray(weights, tasks.dtype))
     return loss, tasks, mutated, head_grads, total_grad
 
@@ -453,6 +457,15 @@ def per_head_error_metrics(
     every execution mode (per-step, scan, sharded)."""
     out: Dict[str, Dict[str, float]] = {}
     for name, tv, pv in zip(names, trues, preds):
+        if np.issubdtype(np.asarray(tv).dtype, np.integer):
+            # a vocabulary head (models/token_stack.py): the targets are
+            # ids and a prediction is (log p(target), arg-max); an error in
+            # ids means nothing, the share of rows it gets right does
+            ids, pv = np.asarray(tv).reshape(-1), np.asarray(pv, np.float64).reshape(-1, 2)
+            n = min(ids.size, pv.shape[0])
+            out[name] = {"mae": None, "rmse": None, "count": int(n),
+                         "accuracy": float((pv[:n, 1] == ids[:n]).mean()) if n else None}
+            continue
         tv = np.asarray(tv, np.float64).reshape(-1)
         pv = np.asarray(pv, np.float64).reshape(-1)
         n = min(tv.size, pv.size)

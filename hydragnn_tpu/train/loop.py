@@ -686,7 +686,9 @@ class DispatchPlan:
         )
         self.eval_step_out = eval_step_out or make_eval_step(model, with_outputs=True)
         self.stats_step = None
-        if training.get("bn_recalibration", True):
+        # a stack without BatchNorm has nothing to recalibrate, and the two
+        # float32 passes over the train split hand back a copy of the state
+        if training.get("bn_recalibration", True) and model.cfg.has_batch_norm:
             self.stats_step = stats_step or make_stats_step(model)
 
     @property

@@ -82,6 +82,7 @@ class Run:
         # when the epoch has fewer steps than its schedule expects
         self.profiling = profiler if profiler is not None else contextlib.nullcontext()
         self.head_names = list(plan.cfg.output_names)
+        self.model_counters = None  # batch_stats -> the model's own counters for the epoch event, or None
         self.timer = Timer("train_validate_test")
         # Spans that close after their epoch's event is written (the end
         # of epoch.record, epoch.checkpoint, epoch itself) wait here under
@@ -224,6 +225,8 @@ class Run:
         extra: Dict[str, Any] = {}
         if nonfinite:
             extra["nonfinite"] = nonfinite
+        if self.model_counters is not None:
+            extra.update(self.model_counters(state.batch_stats))
         if self.introspect_on:
             # heads: the model-level half of the epoch record — sampled
             # gradient diagnostics and eval MAE/RMSE when introspection
@@ -235,6 +238,8 @@ class Run:
                 heads["available"] = True
                 heads["mae"] = {n: m["mae"] for n, m in head_quality.items()}
                 heads["rmse"] = {n: m["rmse"] for n, m in head_quality.items()}
+                if any("accuracy" in m for m in head_quality.values()):
+                    heads["accuracy"] = {n: m.get("accuracy") for n, m in head_quality.items()}
             extra["heads"] = heads
             extra["hw"] = hw if hw is not None else {"available": False}
         flight.epoch(
@@ -582,6 +587,7 @@ def _start_record(run: Run, loaders, config, run_config, parallel_block, graftch
     handling so start_epoch reflects what will actually execute."""
     train_loader, val_loader, test_loader = loaders
     ls, flight, diag, ledger = run.loop_state, run.flight, run.diag, run.ledger
+    run.model_counters = run.plan.cfg.epoch_counters(train_loader.samples)
     dev0 = jax.devices()[0]
     # lineage left behind by a pod-checkpoint restore earlier in this
     # process (utils/checkpoint.load_existing_model → podckpt); consumed
@@ -615,6 +621,7 @@ def _start_record(run: Run, loaders, config, run_config, parallel_block, graftch
         "preempt_handler": bool(preempt and preempt.available),
         "watchdog_stall_s": run.stall_s or None,
         "head_names": run.head_names,
+        **run.plan.cfg.manifest_block(),
         "diagnostics": {
             "enabled": diag is not None,
             "diag_every": diag.every if diag is not None else None,

@@ -165,3 +165,72 @@ def test_resident_stack_kernel_compiles_at_its_vmem_budget(one_chip):
         ((layers, H, H), f32), ((layers, 1, H), f32),
     )
     assert _kernels(fn, one_chip, *shapes) == 1
+
+
+# -- the token stack's kernels at the published widths of its cell ---------------
+# (benchmark/configs/sdar-30b-a3b-chat.json: 32 query and 4 key-value heads of
+# 128, 16 held experts 2,048 -> 768 -> 2,048; 16,896 rows = 33 tiles of 512 for the attention)
+
+ROWS, HQ, HKV, D = 16_896, 32, 4, 128
+
+
+def _attention_args(sharding):
+    ba = importlib.import_module("hydragnn_tpu.ops.block_attention")
+    nt = ROWS // ba.TILE
+    pair = [((nt * nt,), i32)] * 4 + [((1,), i32)]
+    shapes = [((HQ, ROWS, D), jnp.bfloat16), ((HKV, ROWS, D), jnp.bfloat16), ((HKV, ROWS, D), jnp.bfloat16),
+              ((ROWS, ba.META), i32)] + pair + pair
+    return ba, [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+
+
+def test_block_attention_forward_kernel_compiles(one_chip):
+    ba, args = _attention_args(one_chip)
+
+    def fn(q, k, v, meta, *pairs):
+        return ba._attend(q, k, v, meta, (pairs[:5], pairs[5:]), D**-0.5, ba.TILE, False)
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "block_attention_fwd" in text
+
+
+def test_block_attention_backward_kernels_compile(one_chip):
+    ba, args = _attention_args(one_chip)
+
+    def fn(q, k, v, meta, *pairs):
+        out, pull = jax.vjp(lambda q, k, v: ba._attend(q, k, v, meta, (pairs[:5], pairs[5:]), D**-0.5, ba.TILE, False),
+                            q, k, v)
+        return pull(out)
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "block_attention_dq" in text and "block_attention_dkv" in text
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+def test_held_experts_compile_to_grouped_products(one_chip, backward):
+    """The held experts' rounds at the cell's widths: the TPU compiler makes
+    ``jax.lax.ragged_dot`` its own grouped kernel (not a product a group
+    over every row), forward and backward."""
+    ts = importlib.import_module("hydragnn_tpu.models.token_stack")
+    rows, hidden, width, held, per_tok, per_round = 16_400, 2048, 768, 16, 8, 16_896
+    bf16 = jnp.bfloat16
+    shapes = [((rows, hidden), bf16), ((rows * per_tok,), jnp.float32), ((held, hidden, width), bf16),
+              ((held, hidden, width), bf16), ((held, width, hidden), bf16),
+              ((rows * per_tok + per_round,), i32), ((held,), i32), ((held,), i32), ((), i32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+
+    def fn(m, weights, gate, up, down, *plan):
+        def layer(m, weights, gate, up, down):
+            return ts.held_experts(m, weights, gate, up, down, plan, per_round, per_tok)
+
+        if not backward:
+            return layer(m, weights, gate, up, down)
+        out, pull = jax.vjp(layer, m, weights, gate, up, down)
+        return pull(out)
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    products = 3 if not backward else 12  # a round's three; the backward recomputes them and pulls each back twice
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= products
+    dense = 2 * per_round * hidden * width  # one product of a round's rows with ONE expert's matrix
+    assert compiled.cost_analysis()["flops"] < products * dense * 1.5
