@@ -547,6 +547,21 @@ class GraphLoader:
             self._cached_batches = self._build_cache()
         self.cache_device_batches = True
 
+    def built_senders(self) -> Optional[np.ndarray]:
+        """The senders of the batches this loader has built and keeps (the
+        device-resident stack, else the kept batches) on the host,
+        [..., E]; None where it keeps none or they are not all
+        on this process."""
+        if self._stacked is not None:
+            kept = [self._stacked.senders]
+        elif self._cached_batches:
+            kept = [b.senders for b in self._cached_batches]
+        else:
+            return None
+        if not all(getattr(s, "is_fully_addressable", True) for s in kept):
+            return None
+        return np.stack([np.asarray(s) for s in kept])
+
     def __iter__(self) -> Iterator[GraphBatch]:
         bs = self.batch_size
         nb = len(self)

@@ -127,9 +127,9 @@ BN = knobs.get_int("HYDRAGNN_BN", _TILE_DEFAULTS["BN"])  # output rows (nodes) p
 CE = knobs.get_int("HYDRAGNN_CE", _TILE_DEFAULTS["CE"])  # edges DMA'd per inner chunk
 # Gather-kernel chunk: the bcast kernel has no cross-chunk accumulator,
 # so it tolerates bigger chunks than the family/sum kernels' CE —
-# measured on v5e (r05 flagship trace): 512 -> 77.8 ms/step, 1024 ->
-# 75.9, 2048 -> 79.7 (wider chunks span more BW-windows and the stray
-# re-reads win back the overhead). Default 1024.
+# measured on v5e (r05 flagship trace, with the table window then tied
+# to CE at 528 rows): 512 -> 77.8 ms/step, 1024 -> 75.9, 2048 -> 79.7.
+# Default 1024. Neither chunk size moves the window (BW, below).
 _BCAST_CE = knobs.get_int("HYDRAGNN_BCAST_CE", _TILE_DEFAULTS["BCAST_CE"])
 if BN % 16 or CE % 16 or BN <= 0 or CE <= 0 or _BCAST_CE % 16 or _BCAST_CE <= 0:
     raise ValueError(
@@ -696,10 +696,10 @@ def segment_sum_local_fast(
 # 471 ms step (r03 trace, docs/PERF.md). For SORTED ids the gather is a
 # CSR broadcast with perfect locality: an edge chunk of C ids
 # (C = _BCAST_CE for the gather kernel, CE for the fused backward)
-# reads only the <= C distinct table rows it references, so a one-hot
-# MXU matmul (out_chunk = onehot[C, W] @ window[W, H]) streams the
-# output at bandwidth instead of looping rows; chunks spanning more
-# than one BW-row window loop over as many windows as needed. Exactness: each output row is
+# reads only the table rows its ids span, so a one-hot MXU matmul
+# (out_chunk = onehot[C, BW] @ window[BW, H]) streams the output at
+# bandwidth instead of looping rows; a chunk whose ids span more than
+# one BW-row window loops over as many windows as it needs. Exactness: each output row is
 # 1.0 * table_row summed once — exact for bf16 inputs with f32
 # accumulation; f32 inputs use HIGHEST (the f32-as-3xbf16 split times
 # exact 1.0 reconstructs exactly) — for |x| >= ~1e-30. Below that the
@@ -715,20 +715,23 @@ def segment_sum_local_fast(
 ALIGN = 16  # window starts/sizes are 16-row aligned: Mosaic must prove
 # HBM slice starts divisible by the tiling — 8 rows for f32, 16 for
 # packed bf16 (8-sublane tile x 2-row packing)
-BW = CE + ALIGN  # table-window rows per DMA: CE sorted edges span
-# <= CE distinct rows; +ALIGN covers the aligned window start. Chunks
-# wider than BW (the gather kernel's _BCAST_CE=1024 default) loop over
-# ceil(span / BW) windows inside _window_gather_acc.
+BW = 128  # table-window rows per DMA, one MXU pass wide. The one-hot
+# costs BW columns a window, selecting or not, so the window follows the
+# rows a chunk's ids span, not how many ids it holds: batched graphs'
+# senders span 84 rows (p50) and at most 139 a 1,024-id chunk, sorted
+# receivers at most 72 (the cells' loader batches, ISSUE 32), about one
+# window a chunk; a wider span loops over ceil(span / BW) windows.
+# Measured against 256 and 528 on v5e: PERF.md section 6, PR 32.
 
 
 def _window_gather_acc(scal_ref, table_hbm, recv_ref, win_vmem, acc_ref, sems):
     """Shared windowed-gather loop: accumulate ``table[recv]`` for the
     current grid step's edge chunk into ``acc_ref`` (f32).
 
-    A chunk's CE sorted ids hold <= CE distinct VALUES but may SPAN an
-    arbitrary row range (ids can skip nodes), so the chunk loops over
-    as many BW-wide windows as its span needs — ``scal_ref[1, k]``
-    (prefetched) holds the count, 1 in the dense-receiver common case.
+    A chunk's ids may SPAN any row range (ids can skip nodes), so the
+    chunk loops over as many BW-wide windows as its span needs —
+    ``scal_ref[1, k]`` (prefetched) holds the count, 1 in the batched-
+    graph common case.
     Window DMA starts are clamped to stay in bounds; a logical range
     check keeps overlapping clamped windows from double-selecting.
     Exactness: each output row accumulates exactly one 1.0 x value
@@ -830,11 +833,33 @@ def _window_plan_local(recv, n_pad_t, n_chunks, ce=None):
     ).astype(jnp.int32)
 
 
+def window_counts(ids, n_rows, ce=None):
+    """Host mirror of :func:`_window_plan_local`'s count: the BW-row
+    windows each ``ce``-id chunk (default ``_BCAST_CE``) of ``ids``
+    needs over a table of ``n_rows`` rows. ``ids`` is [..., E]; each
+    row of E ids is padded and chunked as :func:`_bcast_kernel_call`
+    does. Set-up arithmetic for the manifest's ``gather_windows``
+    (train/run.py), never on a step's path."""
+    import numpy as np
+
+    ce = _BCAST_CE if ce is None else ce
+    ids = np.asarray(ids, np.int64)
+    ids = ids.reshape(-1, ids.shape[-1])
+    n_pad_t = max(((n_rows + ALIGN - 1) // ALIGN) * ALIGN, BW)
+    e_pad = -(-ids.shape[1] // ce) * ce
+    ids = np.pad(ids, ((0, 0), (0, e_pad - ids.shape[1])), constant_values=n_pad_t)
+    chunks = ids.reshape(ids.shape[0], -1, ce)
+    lo = chunks.min(axis=-1)
+    hi = np.minimum(chunks.max(axis=-1), n_pad_t - 1)
+    astart = lo & ~(ALIGN - 1)
+    return np.maximum(1, (hi + 1 - astart + BW - 1) // BW).reshape(-1)
+
+
 def _bcast_kernel(scal_ref, table_hbm, recv_ref, out_ref,
                   win_vmem, acc_ref, sems):
     """Grid step k: out rows [k*C, (k+1)*C) = table[recv rows], C =
-    the call's chunk size (_BCAST_CE; chunks wider than BW loop over
-    multiple table windows — the dense common case at the 1024 default).
+    the call's chunk size (_BCAST_CE; a chunk whose ids span more than
+    BW rows loops over several table windows).
     recv chunk and out chunk are Pallas-pipelined BlockSpec windows; the
     data-dependent table windows are manual DMAs (BlockSpec index maps
     cannot express data-dependent starts) — see

@@ -63,7 +63,22 @@ def _loader_plan(ld) -> Dict[str, Any]:
     for key in ("num_samples", "batch_size", "pad_nodes", "pad_edges", "pad_graphs", "plan",
                 "real_nodes_max", "real_edges_max", "dense_slots", "run_align"):
         plan[key] = getattr(ld, key, None)
+    plan["gather_windows"] = _gather_windows(ld)
     return plan
+
+
+def _gather_windows(ld) -> Optional[Dict[str, Any]]:
+    """How many table windows a sender chunk of the windowed gathers
+    needs (``ops/segment_pallas.py:window_counts``), over the batches the
+    loader has built and keeps; None where it keeps none or they hold no
+    edges. 1 is every chunk's ids inside one window."""
+    from hydragnn_tpu.ops.segment_pallas import _BCAST_CE, BW, window_counts
+
+    senders = ld.built_senders() if hasattr(ld, "built_senders") else None
+    if senders is None or senders.shape[-1] == 0:
+        return None
+    counts = window_counts(senders, ld.pad_nodes)
+    return {"width": BW, "chunk": _BCAST_CE, "mean": float(counts.mean()), "max": int(counts.max())}
 
 
 class Run:

@@ -53,7 +53,8 @@ KNOBS: Dict[str, Knob] = {
            "checkpoint instead of starting over."),
         _K("HYDRAGNN_BCAST_CE", "int", "1024", "ops/segment_pallas.py",
            "Edges per DMA chunk for the CSR-broadcast gather kernel "
-           "(multiple of 16; overrides the TUNE_TILES.json table)."),
+           "(multiple of 16; overrides the TUNE_TILES.json table). The "
+           "gathers' table window (BW, 128 rows) does not follow it."),
         _K("HYDRAGNN_BENCH_GATE_TOL", "float", "0.15", "tools/bench_gate.py",
            "Fractional regression tolerance for the CI perf gate's "
            "graphs/sec, MFU, and traffic arms."),
@@ -62,7 +63,8 @@ KNOBS: Dict[str, Knob] = {
            "(multiple of 16; overrides the TUNE_TILES.json table)."),
         _K("HYDRAGNN_CE", "int", "512", "ops/segment_pallas.py",
            "Edges DMA'd per inner chunk in the segment-sum kernels "
-           "(multiple of 16; overrides the TUNE_TILES.json table)."),
+           "(multiple of 16; overrides the TUNE_TILES.json table). Since "
+           "PR 32 it does not move the gathers' table window (BW, 128 rows)."),
         _K("HYDRAGNN_DEBUG_BATCH", "bool", "0", "data/loader.py",
            "Validate layout contracts (sorted receivers, masked-edge "
            "targeting, window coverage) on every host batch."),
