@@ -1,4 +1,5 @@
-"""Token documents as graphs without edges, and the block-diffusion noising.
+"""Token documents as graphs without edges: the block-diffusion noising
+and the next-token layout.
 
 A document of ``n`` tokens becomes ONE graph of ``2n`` nodes and no edge:
 the noised copy first, then the clean copy (BD3-LM's training layout,
@@ -25,6 +26,12 @@ loss weight is ``1/t``). The rates of a document's blocks are a stratified
 draw over ``[t_min, t_max]`` (one rate from each of as many equal strata as
 the document has blocks, in a random order), clipped from below so that the
 weight is bounded by ``1/t_min``.
+
+Next-token training (``models/token_stack.py:LatentStack``) takes ONE copy:
+a document of ``n`` tokens is a graph of ``n`` nodes (token, index, copy 0),
+and head ``d`` of ``head_names`` has the target ``t[i + 1 + d]`` with the
+weight 1, or 0 (and the id 0) where the document has no such token
+(:func:`next_token_samples`).
 """
 
 from __future__ import annotations
@@ -95,3 +102,31 @@ def block_diffusion_samples(
         noise_document(doc, np.random.default_rng([int(seed), i]), block_length, mask_id, t_min, t_max, head_name)
         for i, doc in enumerate(documents)
     ]
+
+
+def next_token_document(tokens: np.ndarray, head_names: Sequence[str] = ("token", "token_mtp")) -> GraphSample:
+    """One document (int ids, ``[n]``) as a ``GraphSample`` of ``n`` nodes,
+    one head a name: head ``d`` predicts the token ``1 + d`` further on."""
+    tokens = np.asarray(tokens).astype(np.int32).reshape(-1)
+    n = int(tokens.shape[0])
+    if n < 2:
+        raise ValueError(f"a document needs at least two tokens for a next token, got {n}")
+    x = np.zeros((n, 3), np.int32)
+    x[:, TOKEN] = tokens
+    x[:, INDEX] = np.arange(n, dtype=np.int32)
+    targets = {}
+    for d, name in enumerate(head_names):
+        ahead = 1 + d
+        target = np.zeros((n, 1), np.int32)
+        weight = np.zeros((n, 1), np.float32)
+        target[: n - ahead, 0] = tokens[ahead:]
+        weight[: n - ahead, 0] = 1.0
+        targets[name], targets[name + "_weight"] = target, weight
+    return GraphSample(x=x, edge_index=np.zeros((2, 0), np.int32), node_targets=targets)
+
+
+def next_token_samples(documents: Sequence[np.ndarray], head_names: Sequence[str] = ("token", "token_mtp")) -> List[GraphSample]:
+    """The transform a user calls on token documents before
+    ``run_training(config, samples=...)`` for next-token training with
+    ``len(head_names) - 1`` more prediction depths."""
+    return [next_token_document(doc, head_names) for doc in documents]

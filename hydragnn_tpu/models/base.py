@@ -40,7 +40,7 @@ from hydragnn_tpu.models.layers import MLP, MaskedBatchNorm
 
 # The token stack (models/token_stack.py) is imported where a configuration
 # names it, with its kernels: the conv models load none of it.
-TOKEN_MODELS = ("BlockDiffusionMoE",)
+TOKEN_MODELS = ("BlockDiffusionMoE", "LatentAttentionMoE")
 KNOWN_MODELS = ("GIN", "PNA", "GAT", "MFC", "CGCNN", "SAGE", "SchNet") + TOKEN_MODELS
 
 
@@ -130,10 +130,47 @@ class ModelConfig:
     rope_theta: float = 1e6
     rms_norm_eps: float = 1e-6
     block_length: int = 4
+    # LatentAttentionMoE (models/token_stack.py:LatentStack), next-token
+    # training: latent attention (queries through a ``q_lora_rank`` latent,
+    # keys and values through a ``kv_lora_rank`` latent; heads score at
+    # ``qk_nope_head_dim + qk_rope_head_dim`` and carry values of
+    # ``v_head_dim``; rotary embedding over the rope dimensions only, its
+    # pairs interleaved), ``first_k_dense_replace`` leading dense layers of
+    # width ``intermediate_size``, then expert layers with
+    # ``n_shared_experts`` shared experts beside the routed ones; and
+    # ``num_nextn_predict_layers`` more prediction depths (one vocabulary
+    # head each, behind the main one). The expert layer's router (both
+    # stacks): ``scoring_func`` "softmax" or "sigmoid" over all experts, the
+    # chosen experts' scores renormalised to sum 1 and multiplied by
+    # ``routed_scaling_factor``; with ``bias_update_speed`` > 0 a balancing
+    # bias (``batch_stats``) joins the scores for the choice only and moves
+    # by that much against each expert's load after every train step.
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    first_k_dense_replace: int = 0
+    intermediate_size: Optional[int] = None
+    n_shared_experts: int = 0
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    bias_update_speed: float = 0.0
+    num_nextn_predict_layers: int = 0
 
     @property
     def is_token_stack(self) -> bool:
         return self.model_type in TOKEN_MODELS
+
+    @property
+    def token_objective(self) -> Optional[str]:
+        """``"block_diffusion"`` (a noised and a clean copy of every
+        document, the loss over the noised copy's tokens) or
+        ``"next_token"`` (one copy, each head's loss a mean over the rows
+        that have its target); None for the conv models."""
+        if not self.is_token_stack:
+            return None
+        return "block_diffusion" if self.model_type == "BlockDiffusionMoE" else "next_token"
 
     @property
     def has_batch_norm(self) -> bool:
@@ -157,30 +194,44 @@ class ModelConfig:
             return None
         from hydragnn_tpu.models.token_stack import epoch_counters
 
-        return epoch_counters(train_samples)
+        return epoch_counters(self, train_samples)
+
+    def _check_token_stack(self) -> None:
+        need = ["num_attention_heads", "num_experts", "num_experts_per_tok", "moe_intermediate_size",
+                "experts_held", "vocab_size"]
+        if self.model_type == "BlockDiffusionMoE":
+            need += ["num_key_value_heads", "head_dim"]
+            heads = 1
+        else:
+            need += ["q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"]
+            need += ["intermediate_size"] if self.first_k_dense_replace else []
+            heads = 1 + self.num_nextn_predict_layers
+        missing = [k for k in need if getattr(self, k) is None]
+        if missing:
+            raise ValueError(f"{self.model_type} requires Architecture keys {missing}")
+        if self.output_type != ("node",) * heads or self.loss_function_type != "cross_entropy":
+            raise ValueError(
+                f"{self.model_type} has {heads} node head(s) over the vocabulary with "
+                'Training.loss_function_type "cross_entropy"; got heads '
+                f"{self.output_type} and {self.loss_function_type!r}"
+            )
+        if self.model_type == "BlockDiffusionMoE" and self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        if not 0 <= self.expert_offset <= self.num_experts - self.experts_held:
+            raise ValueError(
+                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
+                f"are not among the router's {self.num_experts}"
+            )
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f'scoring_func is "softmax" or "sigmoid", got {self.scoring_func!r}')
+        if not 0 <= self.first_k_dense_replace < self.num_conv_layers:
+            raise ValueError("first_k_dense_replace must leave at least one expert layer")
 
     def __post_init__(self):
         if self.model_type not in KNOWN_MODELS:
             raise ValueError(f"Unknown model_type: {self.model_type}")
         if self.is_token_stack:
-            need = ("num_attention_heads", "num_key_value_heads", "head_dim", "num_experts",
-                    "num_experts_per_tok", "moe_intermediate_size", "experts_held", "vocab_size")
-            missing = [k for k in need if getattr(self, k) is None]
-            if missing:
-                raise ValueError(f"{self.model_type} requires Architecture keys {missing}")
-            if self.output_type != ("node",) or self.loss_function_type != "cross_entropy":
-                raise ValueError(
-                    f"{self.model_type} has ONE node head over the vocabulary with "
-                    'Training.loss_function_type "cross_entropy"; got heads '
-                    f"{self.output_type} and {self.loss_function_type!r}"
-                )
-            if self.num_attention_heads % self.num_key_value_heads:
-                raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
-            if not 0 <= self.expert_offset <= self.num_experts - self.experts_held:
-                raise ValueError(
-                    f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
-                    f"are not among the router's {self.num_experts}"
-                )
+            self._check_token_stack()
         if len(self.output_dim) != len(self.output_type) or len(self.output_dim) != len(
             self.output_names
         ):
@@ -410,8 +461,10 @@ class HydraModel(nn.Module):
         dropout off (hydragnn_tpu/train/state.py:make_stats_step)."""
         cfg = self.cfg
         if cfg.is_token_stack:
-            from hydragnn_tpu.models.token_stack import TokenStack
+            from hydragnn_tpu.models.token_stack import LatentStack, TokenStack
 
+            if cfg.model_type == "LatentAttentionMoE":
+                return LatentStack(cfg, name="tokens")(batch)
             return [TokenStack(cfg, name="tokens")(batch)]
         bn = train if bn_train is None else bn_train
         ctx = self._conv_args(batch)
@@ -552,10 +605,12 @@ def masked_loss(
 
 def token_cross_entropy(logp: jnp.ndarray, weight: jnp.ndarray, mask: jnp.ndarray, counted: jnp.ndarray) -> jnp.ndarray:
     """``-sum(weight * log p(target))`` over the real rows, divided by the
-    number of real rows that are ``counted`` (the tokens of the step: one
-    copy's rows). ``logp`` [N] is the vocabulary head's first column; a row
-    of weight 0 (unmasked, clean copy, padding) adds nothing, and its
-    ``logp`` is not looked at (so a NaN there stays out)."""
+    number of real rows that are ``counted`` (block diffusion: the tokens of
+    the step, one copy's rows; next-token training: the rows that have the
+    head's target). ``logp`` [N] is the vocabulary head's first column; a
+    row of weight 0 (unmasked, clean copy, past a document's end, padding)
+    adds nothing, and its ``logp`` is not looked at (so a NaN there stays
+    out)."""
     w = jnp.where(mask, weight, 0.0)
     tokens = jnp.maximum((mask & counted).sum().astype(jnp.float32), 1.0)
     return -jnp.where(w > 0, w * logp, 0.0).sum() / tokens
@@ -585,9 +640,13 @@ def model_loss(
                     f'head {name!r}: "cross_entropy" is for a node head over a vocabulary '
                     f"whose batch carries node_targets[{name + '_weight'!r}]"
                 )
-            head_loss = token_cross_entropy(
-                outputs[ihead][:, 0], batch.node_targets[name + "_weight"][:, 0], mask, batch.nodes[:, COPY] == 1
-            )
+            logp = outputs[ihead][:, 0]
+            weight = batch.node_targets[name + "_weight"][:, 0]
+            if cfg.token_objective == "block_diffusion":
+                counted = batch.nodes[:, COPY] == 1
+            else:
+                counted = weight > 0
+            head_loss = token_cross_entropy(logp, weight, mask, counted)
         elif cfg.loss_function_type in ("mse", "mae", "rmse"):
             head_loss = masked_loss(cfg.loss_function_type, outputs[ihead], target, mask)
         else:

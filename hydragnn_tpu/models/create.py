@@ -96,7 +96,10 @@ def model_config_from_dict(
             for key in (
                 "num_attention_heads", "num_key_value_heads", "head_dim", "num_experts",
                 "num_experts_per_tok", "moe_intermediate_size", "experts_held", "expert_offset",
-                "vocab_size", "rope_theta", "rms_norm_eps", "block_length",
+                "vocab_size", "rope_theta", "rms_norm_eps", "block_length", "q_lora_rank", "kv_lora_rank",
+                "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "first_k_dense_replace",
+                "intermediate_size", "n_shared_experts", "scoring_func", "routed_scaling_factor",
+                "bias_update_speed", "num_nextn_predict_layers",
             )
             if arch.get(key) is not None
         },

@@ -250,7 +250,7 @@ def device_memory_stats(device=None) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def linearize_heads(loss_fn, params, weights, remat: bool = False):
+def linearize_heads(loss_fn, params, weights, remat: bool = False, last_from_total: bool = False):
     """ONE forward and ONE ``jax.vjp`` linearisation of the stacked task
     losses, then H + 1 pulls through it: a one-hot cotangent per head
     (each head's UNWEIGHTED loss gradient w.r.t. the full parameter tree;
@@ -259,9 +259,16 @@ def linearize_heads(loss_fn, params, weights, remat: bool = False):
     the train step's backward is, so an update built from it is the train
     step's update to rounding.
 
+    ``last_from_total``: pull the first H - 1 heads only; the last head's
+    gradient is the total's less the others', weighted, which
+    :func:`head_diagnostics` uses through their dot products alone. One
+    gradient tree fewer is alive at once (the token stacks, whose
+    parameters are most of the device's memory).
+
     ``loss_fn``: ``models/base.py:train_loss_closure``'s ``params ->
     (loss, (tasks[H], mutated))``. Returns ``(loss, tasks, mutated, head_grads,
-    total_grad)``."""
+    total_grad)``; ``head_grads`` holds H - 1 trees under
+    ``last_from_total``."""
     import jax
     import jax.numpy as jnp
 
@@ -273,7 +280,7 @@ def linearize_heads(loss_fn, params, weights, remat: bool = False):
     tasks, vjp_fn, (loss, mutated) = jax.vjp(fn, params, has_aux=True)
     num_heads = tasks.shape[0]
     head_grads = []
-    for ihead in range(num_heads):
+    for ihead in range(num_heads - 1 if last_from_total and num_heads > 1 else num_heads):
         cot = jnp.zeros((num_heads,), tasks.dtype).at[ihead].set(1.0)
         (g,) = vjp_fn(cot)
         head_grads.append(g)
@@ -285,11 +292,14 @@ def linearize_heads(loss_fn, params, weights, remat: bool = False):
     return loss, tasks, mutated, head_grads, total_grad
 
 
-def head_diagnostics(tasks, head_grads, total_grad, params, updates) -> Dict[str, Any]:
+def head_diagnostics(tasks, head_grads, total_grad, params, updates, weights=None) -> Dict[str, Any]:
     """The diagnostics arithmetic: a pure function of one step's
     linearisation (:func:`linearize_heads`) and update, which the observer
     (:func:`make_diagnostics_step`) and the diagnosed train step
-    (``train/state.py:make_diagnosed_first_step``) both call. Returned
+    (``train/state.py:make_diagnosed_first_step``) both call. Where
+    ``head_grads`` holds one head fewer than ``tasks`` (``last_from_total``),
+    the last head's dot products come from the total's and the task
+    ``weights``: ``g_last = (total - sum_i w_i g_i) / w_last``. Returned
     (device) dict:
 
       - ``tasks_loss`` [H]: the forward's per-head losses;
@@ -313,7 +323,14 @@ def head_diagnostics(tasks, head_grads, total_grad, params, updates) -> Dict[str
         )
         return sum(jax.tree_util.tree_leaves(leaves), jnp.zeros((), jnp.float32))
 
-    dots = jnp.stack([jnp.stack([_tree_dot(gi, gj) for gj in head_grads]) for gi in head_grads])
+    num_heads = tasks.shape[0]
+    vectors = list(head_grads) + ([total_grad] if len(head_grads) < num_heads else [])
+    dots = jnp.stack([jnp.stack([_tree_dot(gi, gj) for gj in vectors]) for gi in vectors])
+    if len(head_grads) < num_heads:  # the heads' gram matrix from the pulled heads' and the total's
+        w = jnp.asarray(weights, jnp.float32)
+        last = jnp.concatenate([-w[:-1], jnp.ones((1,), jnp.float32)]) / w[-1]
+        coef = jnp.concatenate([jnp.eye(num_heads, dtype=jnp.float32)[: num_heads - 1], last[None, :]])
+        dots = coef @ dots @ coef.T
     norms = jnp.sqrt(jnp.clip(jnp.diagonal(dots), 0.0, None))
     denom = jnp.maximum(norms[:, None] * norms[None, :], 1e-30)
     param_norm = optax.global_norm(params)
@@ -361,9 +378,12 @@ def make_diagnostics_step(
         # is the gradient THIS step's update is built from
         _, dropout_rng = jax.random.split(state.rng)
         loss_fn = train_loss_closure(model, compute_dtype, state.batch_stats, batch, dropout_rng)
-        _, tasks, _, head_grads, grads = linearize_heads(loss_fn, state.params, weights, remat=remat)
+        lean = model.cfg.is_token_stack
+        _, tasks, _, head_grads, grads = linearize_heads(
+            loss_fn, state.params, weights, remat=remat, last_from_total=lean
+        )
         updates, _ = tx.update(grads, state.opt_state, state.params)
-        return head_diagnostics(tasks, head_grads, grads, state.params, updates)
+        return head_diagnostics(tasks, head_grads, grads, state.params, updates, weights)
 
     return jax.jit(diagnostics_step)
 

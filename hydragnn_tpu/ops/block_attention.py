@@ -16,8 +16,10 @@ from step to step, so the mask is no static function of the row index
 (which is what ``jax.experimental.pallas.ops.tpu.splash_attention`` wants);
 it is data.
 
-Two paths, one contract (``[N, Hq, D]`` queries, ``[N, Hkv, D]`` keys and
-values, grouped-query: ``Hq / Hkv`` query heads share a key-value head):
+Two paths, one contract (``[N, Hq, Dk]`` queries, ``[N, Hkv, Dk]`` keys,
+``[N, Hkv, Dv]`` values and ``[N, Hq, Dv]`` outputs, grouped-query: ``Hq /
+Hkv`` query heads share a key-value head; latent attention's heads score at
+one width and carry values at another, ``Dk != Dv``):
 
 - :func:`block_attention_xla`: dense scores ``[Hq, N, N]``; the CPU path and
   the one the kernels are tested against. Never at a size where that matters.
@@ -88,7 +90,7 @@ def block_attention_xla(q, k, v, doc, blk, cpy, scale: float):
     s = jnp.where(dense_mask(doc, blk, cpy)[None, None], s, NEG)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("hgij,jhd->ihgd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    return o.reshape(n, hq, d).astype(q.dtype)
+    return o.reshape(n, hq, v.shape[-1]).astype(q.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -244,10 +246,13 @@ def _params(interpret: bool):
         dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT)}
 
 
-def _specs(group: int, tile: int, d: int, q_side_major: bool):
-    """Block specs of a head-major call. ``a`` / ``b`` are the prefetched
+def _specs(group: int, tile: int, dk: int, dv: int, q_side_major: bool):
+    """Block specs of a head-major call: queries and keys at the scoring
+    width ``dk``, values and outputs (and their cotangents) at ``dv``; each
+    block takes the whole last dimension. ``a`` / ``b`` are the prefetched
     major / minor tile lists: with ``q_side_major`` the query tile is the
-    major one (forward, dq), else the key tile (dkv)."""
+    major one (forward, dq), else the key tile (dkv). Returns (q, k, v, o,
+    row, qm, km)."""
 
     def qi(g, s, a, b, *_):
         return (a if q_side_major else b)[s]
@@ -255,66 +260,70 @@ def _specs(group: int, tile: int, d: int, q_side_major: bool):
     def ki(g, s, a, b, *_):
         return (b if q_side_major else a)[s]
 
-    q = pl.BlockSpec((group, tile, d), lambda g, s, *p: (g, qi(g, s, *p), 0))
-    kv = pl.BlockSpec((None, tile, d), lambda g, s, *p: (g, ki(g, s, *p), 0))
+    def query_side(d):
+        return pl.BlockSpec((group, tile, d), lambda g, s, *p: (g, qi(g, s, *p), 0))
+
+    def key_side(d):
+        return pl.BlockSpec((None, tile, d), lambda g, s, *p: (g, ki(g, s, *p), 0))
+
     row = pl.BlockSpec((group, tile, 1), lambda g, s, *p: (g, qi(g, s, *p), 0))
     qm = pl.BlockSpec((tile, META), lambda g, s, *p: (qi(g, s, *p), 0))
     km = pl.BlockSpec((META, tile), lambda g, s, *p: (0, ki(g, s, *p)))
-    return q, kv, row, qm, km
+    return query_side(dk), key_side(dk), key_side(dv), query_side(dv), row, qm, km
 
 
 def _forward(q, k, v, qmeta, kmeta, pairs, scale, tile, interpret):
-    hq, n, d = q.shape
-    hkv = k.shape[0]
+    hq, n, dk = q.shape
+    hkv, _, dv = v.shape
     group = hq // hkv
-    qs, kvs, rows, qm, km = _specs(group, tile, d, True)
+    qs, ks, vs, os_, rows, qm, km = _specs(group, tile, dk, dv, True)
     steps = pairs[0].shape[0]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, group=group),
-        out_shape=(jax.ShapeDtypeStruct((hq, n, d), q.dtype), jax.ShapeDtypeStruct((hq, n, 1), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((hq, n, dv), q.dtype), jax.ShapeDtypeStruct((hq, n, 1), jnp.float32)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(hkv, steps),
-            in_specs=[qs, kvs, kvs, qm, km], out_specs=(qs, rows),
+            in_specs=[qs, ks, vs, qm, km], out_specs=(os_, rows),
             scratch_shapes=[pltpu.VMEM((group, tile, 1), jnp.float32), pltpu.VMEM((group, tile, 1), jnp.float32),
-                            pltpu.VMEM((group, tile, d), jnp.float32)],
+                            pltpu.VMEM((group, tile, dv), jnp.float32)],
         ),
         interpret=interpret, name="block_attention_fwd", **_params(interpret),
     )(*pairs, q, k, v, qmeta, kmeta)
 
 
 def _backward(q, k, v, do, lse, delta, qmeta, kmeta, pairs_q, pairs_k, scale, tile, interpret):
-    hq, n, d = q.shape
-    hkv = k.shape[0]
+    hq, n, dk = q.shape
+    hkv, _, dv = v.shape
     group = hq // hkv
     steps = pairs_q[0].shape[0]
-    qs, kvs, rows, qm, km = _specs(group, tile, d, True)
+    qs, ks, vs, os_, rows, qm, km = _specs(group, tile, dk, dv, True)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, group=group),
-        out_shape=jax.ShapeDtypeStruct((hq, n, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hq, n, dk), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(hkv, steps),
-            in_specs=[qs, kvs, kvs, qs, rows, rows, qm, km], out_specs=qs,
-            scratch_shapes=[pltpu.VMEM((group, tile, d), jnp.float32)],
+            in_specs=[qs, ks, vs, os_, rows, rows, qm, km], out_specs=qs,
+            scratch_shapes=[pltpu.VMEM((group, tile, dk), jnp.float32)],
         ),
         interpret=interpret, name="block_attention_dq", **_params(interpret),
     )(*pairs_q, q, k, v, do, lse, delta, qmeta, kmeta)
-    qs, kvs, rows, qm, km = _specs(group, tile, d, False)
-    dk, dv = pl.pallas_call(
+    qs, ks, vs, os_, rows, qm, km = _specs(group, tile, dk, dv, False)
+    dk_, dv_ = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, group=group),
-        out_shape=(jax.ShapeDtypeStruct((hkv, n, d), k.dtype), jax.ShapeDtypeStruct((hkv, n, d), v.dtype)),
+        out_shape=(jax.ShapeDtypeStruct((hkv, n, dk), k.dtype), jax.ShapeDtypeStruct((hkv, n, dv), v.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(hkv, steps),
-            in_specs=[qs, kvs, kvs, qs, rows, rows, qm, km], out_specs=(kvs, kvs),
-            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32), pltpu.VMEM((tile, d), jnp.float32)],
+            in_specs=[qs, ks, vs, os_, rows, rows, qm, km], out_specs=(ks, vs),
+            scratch_shapes=[pltpu.VMEM((tile, dk), jnp.float32), pltpu.VMEM((tile, dv), jnp.float32)],
         ),
         interpret=interpret, name="block_attention_dkv", **_params(interpret),
     )(*pairs_k, q, k, v, do, lse, delta, qmeta, kmeta)
-    return dq, dk, dv
+    return dq, dk_, dv_
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _attend(q, k, v, qmeta, pairs, scale, tile, interpret):
-    """Head-major: q ``[Hq, N, D]``, k / v ``[Hkv, N, D]``, ``N`` a multiple of ``tile``."""
+    """Head-major: q ``[Hq, N, Dk]``, k ``[Hkv, N, Dk]``, v ``[Hkv, N, Dv]``, ``N`` a multiple of ``tile``."""
     return _forward(q, k, v, qmeta, qmeta.T, pairs[0], scale, tile, interpret)[0]
 
 
@@ -347,9 +356,9 @@ def attention_plan(doc, blk, cpy, tile: int = TILE):
 
 
 def block_attention(q, k, v, doc, blk, cpy, scale: float, plan=None, tile: int = TILE) -> jnp.ndarray:
-    """``softmax(q k^T * scale + mask) v``: q ``[N, Hq, D]``, k and v
-    ``[N, Hkv, D]``, the three row integers ``[N]``; returns ``[N, Hq, D]``
-    in q's dtype. ``plan``: :func:`attention_plan` of the same rows, where
+    """``softmax(q k^T * scale + mask) v``: q ``[N, Hq, Dk]``, k ``[N, Hkv,
+    Dk]``, v ``[N, Hkv, Dv]``, the three row integers ``[N]``; returns
+    ``[N, Hq, Dv]`` in q's dtype. ``plan``: :func:`attention_plan` of the same rows, where
     the caller has several layers to run over them. On the chip float32
     operands are rounded to bfloat16 first (what a float32 product is at
     the default precision there); the softmax and every sum are float32."""

@@ -171,13 +171,14 @@ def maybe_sigterm(step: Optional[int] = None, epoch: Optional[int] = None) -> No
 _CHECKPOINT_SAVES = 0
 
 
-def maybe_kill_checkpoint(path: str, data: bytes) -> None:
+def maybe_kill_checkpoint(path: str, payload: str) -> None:
     """During the K-th checkpoint save: leave ``path`` TRUNCATED (half
-    the payload, written directly — deliberately bypassing the normal
-    tmp-file + atomic-replace discipline, like a filesystem that tears
-    writes on power loss) and SIGKILL the process. The restart must
-    then reject the truncated file and restore the previous good one —
-    the integrity-validation path this exists to prove."""
+    the payload, the file ``payload`` holds whole, written directly —
+    deliberately bypassing the normal tmp-file + atomic-replace
+    discipline, like a filesystem that tears writes on power loss) and
+    SIGKILL the process. The restart must then reject the truncated file
+    and restore the previous good one — the integrity-validation path
+    this exists to prove."""
     spec = _spec("HYDRAGNN_INJECT_KILL_CHECKPOINT")
     if spec is None:
         return
@@ -185,6 +186,8 @@ def maybe_kill_checkpoint(path: str, data: bytes) -> None:
     _CHECKPOINT_SAVES += 1
     if _CHECKPOINT_SAVES != int(spec):
         return
+    with open(payload, "rb") as f:
+        data = f.read()
     with open(path, "wb") as f:
         f.write(data[: max(len(data) // 2, 1)])
         f.flush()

@@ -246,11 +246,12 @@ def make_diagnosed_first_step(
         batch = jax.tree_util.tree_map(lambda x: x[i], stacked)
         rng, dropout_rng = jax.random.split(state.rng)
         loss_fn = train_loss_closure(model, compute_dtype, state.batch_stats, batch, dropout_rng)
+        weights, lean = model.cfg.normalized_weights, model.cfg.is_token_stack
         loss, tasks, mutated, head_grads, grads = linearize_heads(
-            loss_fn, state.params, model.cfg.normalized_weights, remat=remat
+            loss_fn, state.params, weights, remat=remat, last_from_total=lean
         )
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        diagnostics = head_diagnostics(tasks, head_grads, grads, state.params, updates)
+        diagnostics = head_diagnostics(tasks, head_grads, grads, state.params, updates, weights)
         count = batch.graph_mask.sum().astype(jnp.float32)
         landed = _land(state, rng, loss, tasks, mutated, grads, updates, opt_state, consec)
         if consec is None:

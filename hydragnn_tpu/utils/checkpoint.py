@@ -8,9 +8,9 @@ same single-name "continue" UX:
 
   - ``msgpack`` (default single-process): the whole ``TrainState``
     pytree (params, batch_stats, optimizer state, step, rng) in one
-    flax-msgpack file; process 0 writes, every process reads. Sharded
-    arrays are consolidated to host first (the ZeRO-consolidation
-    analog).
+    flax-msgpack file, written a leaf at a time (``_stream_msgpack``);
+    process 0 writes, every process reads. Sharded arrays are
+    consolidated to host first (the ZeRO-consolidation analog).
   - ``orbax`` (default multi-process): Orbax sharded checkpoint — every
     host writes its addressable shards in parallel and restore places
     shards directly onto the target sharding, so pod-scale ZeRO-1 state
@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -56,12 +57,18 @@ def _to_host(x: Any) -> np.ndarray:
     """Fetch one leaf to host. Leaves sharded across non-addressable
     devices (multi-host ZeRO-1 optimizer state) are first all-gathered to
     a replicated layout with an XLA collective — the ZeRO consolidation
-    step (reference: consolidate_state_dict, model.py:44-45)."""
+    step (reference: consolidate_state_dict, model.py:44-45). A leaf whole
+    on every device it lives on is read through a view of one device's
+    copy: ``np.asarray`` of an accelerator's array keeps the host copy on
+    that array object, which would then live as long as the state does
+    (a whole state's host copy during a save, seen on the chip)."""
     if isinstance(x, jax.Array) and not x.is_fully_addressable:
         from jax.sharding import NamedSharding, PartitionSpec
 
         mesh = x.sharding.mesh
         x = jax.jit(lambda a: a, out_shardings=NamedSharding(mesh, PartitionSpec()))(x)
+    if isinstance(x, jax.Array) and x.sharding.is_fully_replicated:
+        x = x.addressable_data(0)
     return np.asarray(x)
 
 
@@ -170,27 +177,71 @@ def save_model(
             ckptr.save(ckpt_dir, state, force=True)
         return ckpt_dir
     ckpt_path = _checkpoint_path(log_name, path)
-    host_state = jax.tree_util.tree_map(_to_host, state)
-    if jax.process_index() == 0:
-        os.makedirs(os.path.dirname(ckpt_path), exist_ok=True)
-        data = serialization.to_bytes(host_state)
-        if keep_last:
-            step = int(np.asarray(host_state.step)) if hasattr(host_state, "step") else 0
-            vp = _versioned_path(log_name, path, step)
-            _atomic_write(vp, data)
-            _atomic_write((vp + ".sha256"), _sha256_hex(data).encode())
-            _prune_versions(log_name, path, int(keep_last))
-        # deterministic torn-write fault injection (docs/RESILIENCE.md):
-        # under HYDRAGNN_INJECT_KILL_CHECKPOINT the K-th save leaves the
-        # latest-pointer file truncated and SIGKILLs the process — the
-        # scenario the validation + versioned fallback above recovers
-        from hydragnn_tpu.resilience.inject import maybe_kill_checkpoint
+    tree = serialization.to_state_dict(state)
+    if jax.process_index() != 0:
+        _stream_msgpack(tree, None)  # the gathers of sharded leaves need every process
+        return ckpt_path
+    os.makedirs(os.path.dirname(ckpt_path), exist_ok=True)
+    # atomic replace: a crash mid-write (the exact scenario per-epoch
+    # checkpointing exists for) must not destroy the previous good file
+    tmp = f"{ckpt_path}.tmp{os.getpid()}"
+    with open(tmp, "wb") as f:
+        digest = _stream_msgpack(tree, f)
+    if keep_last:
+        step = int(jax.device_get(state.step)) if hasattr(state, "step") else 0
+        vp = _versioned_path(log_name, path, step)
+        vtmp = f"{vp}.tmp{os.getpid()}"
+        shutil.copyfile(tmp, vtmp)
+        os.replace(vtmp, vp)
+        _atomic_write((vp + ".sha256"), digest.encode())
+        _prune_versions(log_name, path, int(keep_last))
+    # deterministic torn-write fault injection (docs/RESILIENCE.md):
+    # under HYDRAGNN_INJECT_KILL_CHECKPOINT the K-th save leaves the
+    # latest-pointer file truncated and SIGKILLs the process — the
+    # scenario the validation + versioned fallback above recovers
+    from hydragnn_tpu.resilience.inject import maybe_kill_checkpoint
 
-        maybe_kill_checkpoint(ckpt_path, data)
-        # atomic replace: a crash mid-write (the exact scenario per-epoch
-        # checkpointing exists for) must not destroy the previous good file
-        _atomic_write(ckpt_path, data)
+    maybe_kill_checkpoint(ckpt_path, tmp)
+    os.replace(tmp, ckpt_path)
     return ckpt_path
+
+
+def _stream_msgpack(tree, out) -> str:
+    """Write ``tree`` (``flax.serialization.to_state_dict`` of a state of
+    device or host arrays) to the file ``out`` byte for byte as
+    ``flax.serialization.to_bytes`` encodes it whole, one leaf at a time: a
+    leaf is fetched to the host (:func:`_to_host`), encoded and written
+    before the next, so the encoding of the whole state is never held
+    (``to_bytes`` holds the host state, every array's bytes and the packed
+    buffer at once: for a 5.9 GB state beside a training run's own host
+    copies, more than a 40 GiB host has). ``out`` None: fetch only (a
+    process that takes part in the gathers and writes nothing). Returns
+    the written bytes' sha256."""
+    import hashlib
+
+    import msgpack
+
+    packer = msgpack.Packer(default=serialization._msgpack_ext_pack, strict_types=True)
+    digest = hashlib.sha256()
+
+    def emit(data: bytes) -> None:
+        if out is not None:
+            digest.update(data)
+            out.write(data)
+
+    def walk(node) -> None:
+        if type(node) is dict:  # strict_types: what msgpack packs as a map
+            emit(packer.pack_map_header(len(node)))
+            for key, value in node.items():
+                emit(packer.pack(key))
+                walk(value)
+            return
+        leaf = None if node is None else _to_host(node)  # None is no pytree leaf: packed as nil
+        if out is not None:
+            emit(packer.pack(serialization._chunk_array_leaves_in_place(leaf)))
+
+    walk(tree)
+    return digest.hexdigest()
 
 
 def _restore_bytes_into(state: Any, data: bytes) -> Any:

@@ -167,37 +167,44 @@ def test_resident_stack_kernel_compiles_at_its_vmem_budget(one_chip):
     assert _kernels(fn, one_chip, *shapes) == 1
 
 
-# -- the token stack's kernels at the published widths of its cell ---------------
+# -- the token stacks' kernels at the published widths of their cells ------------
 # (benchmark/configs/sdar-30b-a3b-chat.json: 32 query and 4 key-value heads of
-# 128, 16 held experts 2,048 -> 768 -> 2,048; 16,896 rows = 33 tiles of 512 for the attention)
+# 128, 16 held experts 2,048 -> 768 -> 2,048; 16,896 rows = 33 tiles of 512 for
+# the attention; benchmark/configs/joyai-llm-flash.json: latent attention's 32
+# heads scoring at 128 + 64 and carrying values of 128, one key-value head a
+# query head; 8,704 rows = 17 tiles)
 
-ROWS, HQ, HKV, D = 16_896, 32, 4, 128
+ATTENTION = {"grouped_query": (16_896, 32, 4, 128, 128), "latent": (8_704, 32, 32, 192, 128)}
 
 
-def _attention_args(sharding):
+def _attention_args(sharding, rows, hq, hkv, dk, dv):
     ba = importlib.import_module("hydragnn_tpu.ops.block_attention")
-    nt = ROWS // ba.TILE
+    nt = rows // ba.TILE
     pair = [((nt * nt,), i32)] * 4 + [((1,), i32)]
-    shapes = [((HQ, ROWS, D), jnp.bfloat16), ((HKV, ROWS, D), jnp.bfloat16), ((HKV, ROWS, D), jnp.bfloat16),
-              ((ROWS, ba.META), i32)] + pair + pair
+    shapes = [((hq, rows, dk), jnp.bfloat16), ((hkv, rows, dk), jnp.bfloat16), ((hkv, rows, dv), jnp.bfloat16),
+              ((rows, ba.META), i32)] + pair + pair
     return ba, [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
 
 
-def test_block_attention_forward_kernel_compiles(one_chip):
-    ba, args = _attention_args(one_chip)
+@pytest.mark.parametrize("widths", sorted(ATTENTION))
+def test_block_attention_forward_kernel_compiles(one_chip, widths):
+    ba, args = _attention_args(one_chip, *ATTENTION[widths])
+    dk = ATTENTION[widths][3]
 
     def fn(q, k, v, meta, *pairs):
-        return ba._attend(q, k, v, meta, (pairs[:5], pairs[5:]), D**-0.5, ba.TILE, False)
+        return ba._attend(q, k, v, meta, (pairs[:5], pairs[5:]), dk**-0.5, ba.TILE, False)
 
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == 1 and "block_attention_fwd" in text
 
 
-def test_block_attention_backward_kernels_compile(one_chip):
-    ba, args = _attention_args(one_chip)
+@pytest.mark.parametrize("widths", sorted(ATTENTION))
+def test_block_attention_backward_kernels_compile(one_chip, widths):
+    ba, args = _attention_args(one_chip, *ATTENTION[widths])
+    dk = ATTENTION[widths][3]
 
     def fn(q, k, v, meta, *pairs):
-        out, pull = jax.vjp(lambda q, k, v: ba._attend(q, k, v, meta, (pairs[:5], pairs[5:]), D**-0.5, ba.TILE, False),
+        out, pull = jax.vjp(lambda q, k, v: ba._attend(q, k, v, meta, (pairs[:5], pairs[5:]), dk**-0.5, ba.TILE, False),
                             q, k, v)
         return pull(out)
 
