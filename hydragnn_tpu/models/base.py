@@ -178,14 +178,15 @@ class ModelConfig:
         training recalibrates (train/loop.py)."""
         return not self.is_token_stack
 
-    def manifest_block(self) -> Dict[str, Any]:
+    def manifest_block(self, rows: int) -> Dict[str, Any]:
         """What the flight record's manifest says of the model beyond its
-        ``config``: nothing for the conv models."""
+        ``config``, for a train batch of ``rows`` node slots: nothing for
+        the conv models."""
         if not self.is_token_stack:
             return {}
         from hydragnn_tpu.models.token_stack import manifest_block
 
-        return manifest_block(self)
+        return manifest_block(self, rows)
 
     def epoch_counters(self, train_samples) -> Optional[Callable[[Any], Dict[str, Any]]]:
         """``batch_stats -> {name: value}`` for the flight record's ``epoch``

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from hydragnn_tpu.data.tokens import COPY, INDEX, TOKEN
-from hydragnn_tpu.ops.block_attention import attention_plan, block_attention, kernel_mode
+from hydragnn_tpu.ops.block_attention import attention_grid, attention_plan, block_attention, kernel_mode
 
 HEAD_CHUNK_ROWS = 2048  # rows of logits alive at once: [2048, vocabulary] float32
 
@@ -496,25 +496,32 @@ class LatentStack(nn.Module):
         return outputs
 
 
-def manifest_block(cfg) -> Dict[str, Any]:
+def manifest_block(cfg, rows: int) -> Dict[str, Any]:
     """``manifest["model"]["token_stack"]``: which stack, the experts held
-    of how many, the vocabulary held; for the latent stack also the
-    attention's kind, ranks and widths, the dense layers, the shared
-    experts, the router's scoring and bias, and the prediction depths."""
+    of how many, the vocabulary held, and the attention kernels' grid for
+    ``rows`` row slots (``ops/block_attention.py:attention_grid``: the query
+    and key-value heads a grid step works, the grid steps of a call); for
+    the latent stack also the attention's kind, ranks and widths, the dense
+    layers, the shared experts, the router's scoring and bias, and the
+    prediction depths."""
     block = {
         "stack": cfg.model_type, "layers": cfg.num_conv_layers, "experts_held": cfg.experts_held,
         "experts": cfg.num_experts, "experts_per_token": cfg.num_experts_per_tok,
         "vocabulary_held": cfg.vocab_size, "block_length": cfg.block_length,
     }
+    heads = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.head_dim)
     if cfg.model_type == "LatentAttentionMoE":
+        d_qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        heads = (cfg.num_attention_heads, cfg.num_attention_heads, d_qk, cfg.v_head_dim)
         block.update({
             "block_length": 1, "attention": "latent", "q_lora_rank": cfg.q_lora_rank,
-            "kv_lora_rank": cfg.kv_lora_rank, "d_qk": cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+            "kv_lora_rank": cfg.kv_lora_rank, "d_qk": d_qk,
             "d_rope": cfg.qk_rope_head_dim, "d_v": cfg.v_head_dim,
             "dense_layers": cfg.first_k_dense_replace, "shared_experts": cfg.n_shared_experts,
             "scoring": cfg.scoring_func, "routed_scaling_factor": cfg.routed_scaling_factor,
             "bias_update_speed": cfg.bias_update_speed, "mtp_depth": cfg.num_nextn_predict_layers,
         })
+    block["attention_grid"] = attention_grid(*heads, rows)
     return {"model": {"token_stack": block}}
 
 

@@ -636,7 +636,7 @@ def _start_record(run: Run, loaders, config, run_config, parallel_block, graftch
         "preempt_handler": bool(preempt and preempt.available),
         "watchdog_stall_s": run.stall_s or None,
         "head_names": run.head_names,
-        **run.plan.cfg.manifest_block(),
+        **run.plan.cfg.manifest_block(train_loader.pad_nodes),
         "diagnostics": {
             "enabled": diag is not None,
             "diag_every": diag.every if diag is not None else None,

@@ -169,23 +169,62 @@ def test_interleaved_rotary_matches_the_reference():
     close(rotary(x[..., perm], cos, sin), rotary(x, cos, sin, interleaved=True)[..., perm], 1e-6)
 
 
-@pytest.mark.parametrize("tile", [16, 64])
-def test_attention_kernels_with_wider_queries_than_values(batch, tile, monkeypatch):
+@pytest.mark.parametrize("heads,tile,kv_per_step", [
+    pytest.param(4, 16, 4, id="16"),
+    pytest.param(4, 64, 4, id="64"),
+    pytest.param(8, 16, 8, id="8-heads-one-block"),
+    pytest.param(16, 32, 8, id="16-heads-two-blocks"),
+])
+def test_attention_kernels_with_wider_queries_than_values(batch, heads, tile, kv_per_step, monkeypatch):
     """Queries and keys of 48, values and outputs of 32, one key-value head
-    a query head, the document-causal mask: the interpreted kernels against
-    the dense path, forward and backward."""
+    a query head, the document-causal mask, ``kv_per_step`` heads a grid
+    step: the interpreted kernels against the dense path, forward and
+    backward."""
+    assert ba.kv_heads_per_step(heads, heads, 48, 32, tile) == kv_per_step
     n = batch.nodes.shape[0]
     kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(5), 4)
-    q, k = jax.random.normal(kq, (n, 4, 48)), jax.random.normal(kk, (n, 4, 48))
-    v, do = jax.random.normal(kv, (n, 4, 32)), jax.random.normal(kd, (n, 4, 32))
+    q, k = jax.random.normal(kq, (n, heads, 48)), jax.random.normal(kk, (n, heads, 48))
+    v, do = jax.random.normal(kv, (n, heads, 32)), jax.random.normal(kd, (n, heads, 32))
     doc, blk, cpy = batch.node_graph, batch.nodes[:, 1], batch.nodes[:, 2]
     want, pull = jax.vjp(lambda q, k, v: ba.block_attention_xla(q, k, v, doc, blk, cpy, 48**-0.5), q, k, v)
     monkeypatch.setenv("HYDRAGNN_PALLAS", "interpret")
     got, pull_k = jax.vjp(lambda q, k, v: ba.block_attention(q, k, v, doc, blk, cpy, 48**-0.5, tile=tile), q, k, v)
-    assert got.shape == (n, 4, 32)
+    assert got.shape == (n, heads, 32)
     close(got, want)
     for g, w in zip(pull_k(do), pull(do)):
         close(g, w)
+
+
+def test_heads_a_grid_step_follow_the_shapes(monkeypatch):
+    """The head block is the largest divisor of the key-value heads that
+    gives at most eight query heads and whose blocks fit the kernels' VMEM:
+    eight of latent attention's 32 one-head groups at the cell's widths,
+    one of the block-diffusion stack's four groups of eight."""
+    assert ba.kv_heads_per_step(32, 32, 192, 128, 512) == 8
+    assert ba.kv_heads_per_step(32, 4, 128, 128, 512) == 1
+    for hq, hkv in [(32, 32), (32, 4), (24, 6), (12, 12), (10, 10), (64, 2), (7, 7)]:
+        kvb = ba.kv_heads_per_step(hq, hkv, 192, 128, 512)
+        assert hkv % kvb == 0 and (kvb * (hq // hkv) <= ba.HEADS_PER_STEP or kvb == 1)
+    assert ba.kv_heads_per_step(10, 10, 192, 128, 512) == 5 and ba.kv_heads_per_step(7, 7, 192, 128, 512) == 7
+
+    # wide heads: eight do not fit the budget, so the next smaller divisor that does
+    kvb = ba.kv_heads_per_step(32, 32, 1024, 1024, 512)
+    assert kvb == 2
+    assert ba._vmem_bytes(2, 2, 512, 1024, 1024) <= ba._VMEM_LIMIT < ba._vmem_bytes(4, 4, 512, 1024, 1024)
+    monkeypatch.setattr(ba, "_VMEM_LIMIT", 1)  # nothing fits: one key-value head a step, as before the blocks
+    assert ba.kv_heads_per_step(32, 32, 192, 128, 512) == 1
+    assert ba.kv_heads_per_step(32, 4, 128, 128, 512) == 1
+
+
+def test_manifest_names_the_attention_grid():
+    """``attention_grid`` for the stack's own heads and a train batch's row
+    slots: what the kernels' grid will be, skipped steps included."""
+    grid = model_cfg().manifest_block(1000)["model"]["token_stack"]["attention_grid"]
+    assert grid == {"tile": 512, "query_heads_per_step": 4, "kv_heads_per_step": 4, "grid_steps_per_call": 4}
+    assert ba.attention_grid(32, 32, 192, 128, 8208) == {
+        "tile": 512, "query_heads_per_step": 8, "kv_heads_per_step": 8, "grid_steps_per_call": 4 * 17 * 17}
+    assert ba.attention_grid(32, 4, 128, 128, 16400) == {
+        "tile": 512, "query_heads_per_step": 8, "kv_heads_per_step": 1, "grid_steps_per_call": 4 * 33 * 33}
 
 
 # -- the layers against the reference ---------------------------------------------
@@ -484,6 +523,8 @@ def test_run_training_scans_diagnoses_saves_and_resumes(tmp_path, monkeypatch):
     assert (stack["dense_layers"], stack["shared_experts"], stack["scoring"], stack["mtp_depth"]) == (1, 1, "sigmoid", 1)
     assert stack["block_length"] == 1 and stack["experts_held"] == 8
     plan = manifest["pad_plans"]["train"]
+    assert stack["attention_grid"] == ba.attention_grid(4, 4, 20, 8, plan["pad_nodes"])
+    assert stack["attention_grid"]["query_heads_per_step"] == 4
     assert plan["plan"] == "fixed_membership" and plan["real_edges_max"] == 0
     losses = history["train_loss"]
     assert len(losses) == 3 and losses[-1] < losses[0] and np.isfinite(history["test_loss"]).all()

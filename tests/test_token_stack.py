@@ -203,9 +203,19 @@ def _qkv(batch, heads=4, kv=2, d=16, seed=0):
             jax.random.normal(kv_, (n, kv, d)), jax.random.normal(kd, (n, heads, d)))
 
 
-@pytest.mark.parametrize("tile", [16, 64])
-def test_block_attention_kernels_match_the_dense_path(batch, tile, monkeypatch):
-    q, k, v, do = _qkv(batch)
+@pytest.mark.parametrize("heads,kv_heads,tile,kv_per_step", [
+    pytest.param(4, 2, 16, 2, id="16"),
+    pytest.param(4, 2, 64, 2, id="64"),
+    pytest.param(16, 2, 16, 1, id="group8-one-kv-head-a-step"),
+    pytest.param(16, 8, 32, 4, id="group2-two-head-blocks"),
+])
+def test_block_attention_kernels_match_the_dense_path(batch, heads, kv_heads, tile, kv_per_step, monkeypatch):
+    """Grouped-query heads: a grid step works ``kv_per_step`` key-value
+    heads and their query heads (one where a group alone is eight query
+    heads); the interpreted kernels against the dense path, forward and the
+    three cotangents."""
+    assert ba.kv_heads_per_step(heads, kv_heads, 16, 16, tile) == kv_per_step
+    q, k, v, do = _qkv(batch, heads, kv_heads)
     doc, blk, cpy = batch.node_graph, batch.nodes[:, 1] // 4, batch.nodes[:, 2]
     dense = lambda q, k, v: ba.block_attention_xla(q, k, v, doc, blk, cpy, 0.25)  # noqa: E731
     want, pull = jax.vjp(dense, q, k, v)
@@ -448,9 +458,12 @@ def test_run_training_scans_diagnoses_saves_and_resumes(tmp_path, monkeypatch):
     assert mode["mode"] == "scan_epoch" and mode["diagnostics"]["path"] == "first_step"
     assert mode["test_split"]["path"] == "on_device"
     stack = manifest["model"]["token_stack"]
-    assert stack == {"stack": "BlockDiffusionMoE", "layers": 2, "experts_held": 4, "experts": 8,
-                     "experts_per_token": 2, "vocabulary_held": 64, "block_length": 4}
     plan = manifest["pad_plans"]["train"]
+    assert stack == {"stack": "BlockDiffusionMoE", "layers": 2, "experts_held": 4, "experts": 8,
+                     "experts_per_token": 2, "vocabulary_held": 64, "block_length": 4,
+                     "attention_grid": {"tile": 512, "query_heads_per_step": 4, "kv_heads_per_step": 2,
+                                        "grid_steps_per_call": 1}}
+    assert plan["pad_nodes"] <= 512  # one tile: one pair slot a head block
     assert plan["plan"] == "fixed_membership" and plan["real_edges_max"] == 0
     assert plan["real_nodes_max"] < plan["pad_nodes"]
     losses = history["train_loss"]
