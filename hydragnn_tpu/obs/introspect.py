@@ -269,8 +269,12 @@ def linearize_heads(loss_fn, params, weights, remat: bool = False, last_from_tot
     (loss, (tasks[H], mutated))``. Returns ``(loss, tasks, mutated, head_grads,
     total_grad)``; ``head_grads`` holds H - 1 trees under
     ``last_from_total``."""
+    import contextlib
+
     import jax
     import jax.numpy as jnp
+
+    from hydragnn_tpu.obs.spans import scope
 
     def tasks_fn(p):
         loss, (tasks, mutated) = loss_fn(p)
@@ -279,14 +283,17 @@ def linearize_heads(loss_fn, params, weights, remat: bool = False, last_from_tot
     fn = jax.checkpoint(tasks_fn) if remat else tasks_fn
     tasks, vjp_fn, (loss, mutated) = jax.vjp(fn, params, has_aux=True)
     num_heads = tasks.shape[0]
+    # one head of weight 1: its gradient IS the train loss's, and a second
+    # pull would hold a second tree of gradients for nothing; that one pull
+    # makes the update, so it is the backward and not the diagnostics
+    only = num_heads == 1 and float(weights[0]) == 1.0
     head_grads = []
-    for ihead in range(num_heads - 1 if last_from_total and num_heads > 1 else num_heads):
-        cot = jnp.zeros((num_heads,), tasks.dtype).at[ihead].set(1.0)
-        (g,) = vjp_fn(cot)
-        head_grads.append(g)
-    if num_heads == 1 and float(weights[0]) == 1.0:
-        # one head of weight 1: its gradient IS the train loss's, and a
-        # second pull would hold a second tree of gradients for nothing
+    with contextlib.nullcontext() if only else scope("diagnostics"):
+        for ihead in range(num_heads - 1 if last_from_total and num_heads > 1 else num_heads):
+            cot = jnp.zeros((num_heads,), tasks.dtype).at[ihead].set(1.0)
+            (g,) = vjp_fn(cot)
+            head_grads.append(g)
+    if only:
         return loss, tasks, mutated, head_grads, head_grads[0]
     (total_grad,) = vjp_fn(jnp.asarray(weights, tasks.dtype))
     return loss, tasks, mutated, head_grads, total_grad
@@ -370,6 +377,7 @@ def make_diagnostics_step(
     import jax
 
     from hydragnn_tpu.models.base import train_loss_closure
+    from hydragnn_tpu.obs.spans import scope
 
     weights = model.cfg.normalized_weights
 
@@ -379,11 +387,13 @@ def make_diagnostics_step(
         _, dropout_rng = jax.random.split(state.rng)
         loss_fn = train_loss_closure(model, compute_dtype, state.batch_stats, batch, dropout_rng)
         lean = model.cfg.is_token_stack
-        _, tasks, _, head_grads, grads = linearize_heads(
-            loss_fn, state.params, weights, remat=remat, last_from_total=lean
-        )
-        updates, _ = tx.update(grads, state.opt_state, state.params)
-        return head_diagnostics(tasks, head_grads, grads, state.params, updates, weights)
+        # the whole observer is diagnostics: its update is thrown away
+        with scope("diagnostics"):
+            _, tasks, _, head_grads, grads = linearize_heads(
+                loss_fn, state.params, weights, remat=remat, last_from_total=lean
+            )
+            updates, _ = tx.update(grads, state.opt_state, state.params)
+            return head_diagnostics(tasks, head_grads, grads, state.params, updates, weights)
 
     return jax.jit(diagnostics_step)
 

@@ -24,6 +24,13 @@ device trace's clock — and an entry (seconds, count, parent) in an
 in-memory table that the train loop flushes into the flight record at
 the end of set-up and at each epoch boundary. :func:`count` keeps
 counters beside it, flushed at the same boundaries.
+
+:func:`scope` is the device side of the same vocabulary: a
+``jax.named_scope`` for the passes of a compiled step that no flax module
+names (``SCOPES``). A scope is metadata only: every instruction under it
+carries the name in its ``op_name``, and the compiled program is the same
+with or without ``HYDRAGNN_TELEMETRY``. ``benchmark/device_scopes.py``
+reads them from the HLO a profiler capture holds.
 """
 
 from __future__ import annotations
@@ -43,6 +50,11 @@ _PHASES: Dict[str, list] = {}  # graftsync: guarded-by=spans._LOCK
 _COUNTS: Dict[str, float] = {}  # graftsync: guarded-by=spans._LOCK
 _OPEN = threading.local()  # .stack: names of this thread's open spans
 _NULL_SPAN = contextlib.nullcontext()
+
+# the program's device scopes: the optimizer update and its landing
+# (``train/state.py``, ``parallel/``), and what only the per-head
+# diagnostics compute (``obs/introspect.py``)
+SCOPES = ("optimizer", "diagnostics")
 
 
 class _Span:
@@ -78,6 +90,15 @@ def span(name: str, **attrs):
     if not telemetry_enabled():
         return _NULL_SPAN
     return _Span(name, attrs)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of ``SCOPES``; any other name
+    raises. Unconditional: a scope changes no instruction, only the
+    ``op_name`` of those traced under it."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not one of the program's device scopes {SCOPES}")
+    return jax.named_scope(name)
 
 
 def span_iter(iterable: Iterable, name: str) -> Iterator:

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from hydragnn_tpu.obs.spans import scope
 from hydragnn_tpu.parallel.mesh import DATA_AXIS
 
 from jax import shard_map
@@ -267,8 +268,9 @@ def make_dp_edge_train_step(
         (_, (loss, tasks, new_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1,
             params=params,

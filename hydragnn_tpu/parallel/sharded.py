@@ -41,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.base import HydraModel, model_loss
+from hydragnn_tpu.obs.spans import scope
 from hydragnn_tpu.parallel.mesh import DATA_AXIS
 from hydragnn_tpu.train.state import TrainState
 
@@ -247,8 +248,9 @@ def make_sharded_train_step(
         grads, new_stats, loss, tasks = sharded_grads(
             state.params, state.batch_stats, dropout_rng, batch
         )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             step=state.step + 1,
             params=params,

@@ -26,7 +26,7 @@ the weights were never touched by them.
 
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 
 class NonFiniteSentry:
@@ -54,6 +54,7 @@ class NonFiniteSentry:
         # consecutive bad steps ending at the current step
         self.consec = jnp.zeros((), jnp.int32)
         self._bads: List = []
+        self._finalized: Optional[Tuple[int, int]] = None
 
     def epoch_start(self) -> None:
         self._bads = []
@@ -72,7 +73,9 @@ class NonFiniteSentry:
 
     def epoch_finalize(self) -> Tuple[int, int]:
         """One host sync per epoch: returns (skipped_this_epoch,
-        consecutive_bad_at_epoch_end)."""
+        consecutive_bad_at_epoch_end) and keeps them for
+        :meth:`take_finalized`. The epoch's train phase calls it last,
+        under the span ``train.sentry`` (``train/loop.py``)."""
         import jax
         import jax.numpy as jnp
 
@@ -83,7 +86,15 @@ class NonFiniteSentry:
         consec_end = int(jax.device_get(self.consec))
         self.skipped_total += skipped
         self._bads = []
+        self._finalized = (skipped, consec_end)
         return skipped, consec_end
+
+    def take_finalized(self) -> Tuple[int, int]:
+        """The epoch's (skipped, consecutive bad at its end): what
+        :meth:`epoch_finalize` read, or read now where no train phase did."""
+        out = self._finalized if self._finalized is not None else self.epoch_finalize()
+        self._finalized = None
+        return out
 
     def needs_rollback(self, consec_end: int) -> bool:
         return consec_end >= self.patience

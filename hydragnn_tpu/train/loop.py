@@ -272,9 +272,18 @@ def train_epoch(
                 incidents.tick()
         with span("train.sync"):
             avg_loss, avg_tasks = acc.finalize()
+        _sentry_read_back(sentry)
     count("graphs", acc.graphs)
     count("steps", steps)
     return state, avg_loss, avg_tasks
+
+
+def _sentry_read_back(sentry) -> None:
+    """The epoch's one read-back of the guard's flags, the last work of the
+    train phase (``Run.close_nonfinite`` takes what it read)."""
+    if sentry is not None:
+        with span("train.sentry"):
+            sentry.epoch_finalize()
 
 
 def _finalize_scan(losses, tasks, counts) -> Tuple[float, np.ndarray, float]:
@@ -375,6 +384,7 @@ def train_epoch_scan(
                 state, losses, tasks, counts = out
         with span("train.sync"):
             avg_loss, avg_tasks, graphs = _finalize_scan(losses, tasks, counts)
+        _sentry_read_back(sentry)
     count("graphs", graphs)
     count("steps", nb)
     # steps whose update came from the program that diagnosed them
@@ -984,11 +994,13 @@ def _write_checkpoint(run, ckpt_state, epoch_next: int, early_stopped: bool) -> 
     with span("epoch.checkpoint"):
         keep_last = int(run.training.get("checkpoint_keep_last", 3))
         save_model(ckpt_state, run.log_name, run.log_dir, run.verbosity, keep_last=keep_last)
-        run.pod.checkpoint(ckpt_state, epoch_next)
-        step = int(jax.device_get(ckpt_state.step))
-        save_train_meta(
-            run.loop_state.snapshot(step, epoch_next, early_stopped), run.log_name, run.log_dir
-        )
+        with span("checkpoint.pod"):
+            run.pod.checkpoint(ckpt_state, epoch_next)
+        with span("checkpoint.meta"):
+            step = int(jax.device_get(ckpt_state.step))
+            save_train_meta(
+                run.loop_state.snapshot(step, epoch_next, early_stopped), run.log_name, run.log_dir
+            )
 
 
 def _preempt_exit(run, ckpt_state, epoch: int, coordinated_from: Optional[int] = None):

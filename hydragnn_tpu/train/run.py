@@ -110,8 +110,13 @@ class Run:
 
     def hold_late(self) -> None:
         phases = drain()
-        if phases:
-            self._late_phases.append({"epoch": self._in_epoch, "phases": phases})
+        # counters of the same spans (``checkpoint.leaves``, ``checkpoint.bytes``)
+        counts = drain_counts() if self._in_epoch is not None else {}
+        if phases or counts:
+            late: Dict[str, Any] = {"epoch": self._in_epoch, "phases": phases}
+            if counts:
+                late["counts"] = counts
+            self._late_phases.append(late)
 
     def _flush_late(self) -> Dict[str, Any]:
         held, self._late_phases = self._late_phases, []
@@ -147,7 +152,7 @@ class Run:
         if self.sentry is None:
             return None, None
         nonfinite = None
-        skipped, consec_end = self.sentry.epoch_finalize()
+        skipped, consec_end = self.sentry.take_finalized()
         if skipped:
             get_registry().counter("train.nonfinite_skipped").inc(skipped)
             nonfinite = {"skipped": skipped, "consec_end": consec_end}

@@ -28,6 +28,7 @@ from flax import struct
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.base import HydraModel, model_loss, train_loss_closure
 from hydragnn_tpu.obs.introspect import head_diagnostics, linearize_heads, make_diagnostics_step
+from hydragnn_tpu.obs.spans import scope
 
 
 class TrainState(struct.PyTreeNode):
@@ -143,8 +144,9 @@ def _train_step_body(
         (loss, (tasks, mutated)), grads = jax.value_and_grad(lf, has_aux=True)(
             state.params
         )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        return _land(state, rng, loss, tasks, mutated, grads, updates, opt_state, consec)
+        with scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            return _land(state, rng, loss, tasks, mutated, grads, updates, opt_state, consec)
 
     # two defs of one name: the name is the compiled program's (module docstring)
     if guarded:
@@ -250,10 +252,13 @@ def make_diagnosed_first_step(
         loss, tasks, mutated, head_grads, grads = linearize_heads(
             loss_fn, state.params, weights, remat=remat, last_from_total=lean
         )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        diagnostics = head_diagnostics(tasks, head_grads, grads, state.params, updates, weights)
+        with scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        with scope("diagnostics"):
+            diagnostics = head_diagnostics(tasks, head_grads, grads, state.params, updates, weights)
         count = batch.graph_mask.sum().astype(jnp.float32)
-        landed = _land(state, rng, loss, tasks, mutated, grads, updates, opt_state, consec)
+        with scope("optimizer"):
+            landed = _land(state, rng, loss, tasks, mutated, grads, updates, opt_state, consec)
         if consec is None:
             state, loss, tasks = landed
             return state, (loss, tasks, count), diagnostics

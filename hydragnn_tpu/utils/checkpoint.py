@@ -30,6 +30,7 @@ import jax
 import numpy as np
 from flax import serialization
 
+from hydragnn_tpu.obs.spans import count, span
 from hydragnn_tpu.utils.print_utils import print_distributed
 
 #: On-disk checkpoint format generation, stamped into the meta sidecar
@@ -166,7 +167,12 @@ def save_model(
     recent step-versioned copies (``<log_name>.step<N>.mp`` + sha256
     sidecar, pruned beyond K). Restore validates integrity and falls
     back down the retained set (:func:`load_existing_model`), so a
-    checkpoint torn by a crash mid-write never strands the run."""
+    checkpoint torn by a crash mid-write never strands the run.
+
+    Spans (children of the span open around the call, ``epoch.checkpoint``
+    in the train loop): ``checkpoint.stream`` (the state to the file, with
+    :func:`_stream_msgpack`'s ``checkpoint.fetch`` and ``checkpoint.write``
+    a leaf) and ``checkpoint.versioned`` (the copy, its sha256, pruning)."""
     if backend == "auto":
         backend = "orbax" if jax.process_count() > 1 else "msgpack"
     if backend == "orbax":
@@ -177,24 +183,26 @@ def save_model(
             ckptr.save(ckpt_dir, state, force=True)
         return ckpt_dir
     ckpt_path = _checkpoint_path(log_name, path)
-    tree = serialization.to_state_dict(state)
-    if jax.process_index() != 0:
-        _stream_msgpack(tree, None)  # the gathers of sharded leaves need every process
-        return ckpt_path
-    os.makedirs(os.path.dirname(ckpt_path), exist_ok=True)
-    # atomic replace: a crash mid-write (the exact scenario per-epoch
-    # checkpointing exists for) must not destroy the previous good file
-    tmp = f"{ckpt_path}.tmp{os.getpid()}"
-    with open(tmp, "wb") as f:
-        digest = _stream_msgpack(tree, f)
+    with span("checkpoint.stream"):
+        tree = serialization.to_state_dict(state)
+        if jax.process_index() != 0:
+            _stream_msgpack(tree, None)  # the gathers of sharded leaves need every process
+            return ckpt_path
+        os.makedirs(os.path.dirname(ckpt_path), exist_ok=True)
+        # atomic replace: a crash mid-write (the exact scenario per-epoch
+        # checkpointing exists for) must not destroy the previous good file
+        tmp = f"{ckpt_path}.tmp{os.getpid()}"
+        with open(tmp, "wb") as f:
+            digest = _stream_msgpack(tree, f)
     if keep_last:
-        step = int(jax.device_get(state.step)) if hasattr(state, "step") else 0
-        vp = _versioned_path(log_name, path, step)
-        vtmp = f"{vp}.tmp{os.getpid()}"
-        shutil.copyfile(tmp, vtmp)
-        os.replace(vtmp, vp)
-        _atomic_write((vp + ".sha256"), digest.encode())
-        _prune_versions(log_name, path, int(keep_last))
+        with span("checkpoint.versioned"):
+            step = int(jax.device_get(state.step)) if hasattr(state, "step") else 0
+            vp = _versioned_path(log_name, path, step)
+            vtmp = f"{vp}.tmp{os.getpid()}"
+            shutil.copyfile(tmp, vtmp)
+            os.replace(vtmp, vp)
+            _atomic_write((vp + ".sha256"), digest.encode())
+            _prune_versions(log_name, path, int(keep_last))
     # deterministic torn-write fault injection (docs/RESILIENCE.md):
     # under HYDRAGNN_INJECT_KILL_CHECKPOINT the K-th save leaves the
     # latest-pointer file truncated and SIGKILLs the process — the
@@ -216,18 +224,25 @@ def _stream_msgpack(tree, out) -> str:
     buffer at once: for a 5.9 GB state beside a training run's own host
     copies, more than a 40 GiB host has). ``out`` None: fetch only (a
     process that takes part in the gathers and writes nothing). Returns
-    the written bytes' sha256."""
+    the written bytes' sha256.
+
+    Spans (children of ``checkpoint.stream``, :func:`save_model`'s), one
+    of each a leaf: ``checkpoint.fetch`` (:func:`_to_host`) and
+    ``checkpoint.write`` (the leaf's encoding, hashing and writing);
+    counters ``checkpoint.leaves`` and ``checkpoint.bytes`` (written)."""
     import hashlib
 
     import msgpack
 
     packer = msgpack.Packer(default=serialization._msgpack_ext_pack, strict_types=True)
     digest = hashlib.sha256()
+    written = [0, 0]  # leaves, bytes
 
     def emit(data: bytes) -> None:
         if out is not None:
             digest.update(data)
             out.write(data)
+            written[1] += len(data)
 
     def walk(node) -> None:
         if type(node) is dict:  # strict_types: what msgpack packs as a map
@@ -236,11 +251,17 @@ def _stream_msgpack(tree, out) -> str:
                 emit(packer.pack(key))
                 walk(value)
             return
-        leaf = None if node is None else _to_host(node)  # None is no pytree leaf: packed as nil
+        with span("checkpoint.fetch"):
+            leaf = None if node is None else _to_host(node)  # None is no pytree leaf: packed as nil
         if out is not None:
-            emit(packer.pack(serialization._chunk_array_leaves_in_place(leaf)))
+            with span("checkpoint.write"):
+                emit(packer.pack(serialization._chunk_array_leaves_in_place(leaf)))
+            written[0] += 1
 
     walk(tree)
+    if out is not None:
+        count("checkpoint.leaves", written[0])
+        count("checkpoint.bytes", written[1])
     return digest.hexdigest()
 
 

@@ -367,6 +367,8 @@ def test_run_training_records_setup_and_epoch_phases(tmp_path, mode, batch_size)
         if mode == "scan_epoch"
         else {"train.loader_wait", "train.step", "train.sync"}
     )
+    if batch_size == 5:  # the loop's own step, guarded: the sentry reads its flags back last
+        inside_train.add("train.sentry")
     for ev in epochs:
         assert ev["graphs"] == plan["num_samples"] and ev["steps"] == plan["num_batches"]
         assert ev["compiles"]["seconds"] >= 0
@@ -378,6 +380,15 @@ def test_run_training_records_setup_and_epoch_phases(tmp_path, mode, batch_size)
         assert {"epoch.train", "epoch.validate", "epoch.test", "epoch.head_quality",
                 "epoch.diag_snapshot", "epoch.record"} <= set(children)
         assert ("epoch.checkpoint" in children) == (ev["epoch"] == 1)
+        if ev["epoch"] == 1:  # the save's parts, a fetch and a write a leaf under the stream
+            saved = {k for k, p in phases.items() if p["parent"] == "epoch.checkpoint"}
+            assert saved == {"checkpoint.stream", "checkpoint.versioned", "checkpoint.pod", "checkpoint.meta"}
+            leaves = phases["checkpoint.fetch"]["n"]
+            assert phases["checkpoint.fetch"]["parent"] == phases["checkpoint.write"]["parent"] == "checkpoint.stream"
+            assert leaves > 1 and phases["checkpoint.write"]["n"] == leaves
+            (late,) = [x for e in events for x in e.get("phases_late") or [] if x.get("counts")]
+            assert late["epoch"] == 1 and late["counts"]["checkpoint.leaves"] == leaves
+            assert late["counts"]["checkpoint.bytes"] == os.path.getsize(glob.glob(log_dir + "*/*.mp")[0])
         wall = phases["epoch"]["s"]
         assert sum(p["s"] for p in children.values()) == pytest.approx(wall, rel=0.05)
     assert epochs[1]["compiles"]["count"] == 0 and epochs[1]["compiles"]["seconds"] == 0
@@ -923,3 +934,26 @@ def test_crashed_training_leaves_failed_flight_record(tmp_path):
     from hydragnn_tpu.utils.time_utils import Timer
 
     assert Timer("train_validate_test")._start is None
+
+
+def test_the_sentry_reads_its_flags_back_once_under_train_sentry():
+    """The guard's flags are read back as the train phase's last work, under
+    ``train.sentry`` inside ``epoch.train``; ``Run.close_nonfinite`` then takes
+    what that read gave instead of reading again."""
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.obs.spans import drain, span
+    from hydragnn_tpu.resilience.sentry import NonFiniteSentry
+    from hydragnn_tpu.train.loop import _sentry_read_back
+
+    sentry = NonFiniteSentry(patience=2)
+    sentry.observe_scan(jnp.asarray([0.0, 1.0, 1.0]), jnp.asarray(2, jnp.int32))
+    drain()
+    with span("epoch.train"):
+        _sentry_read_back(sentry)
+    phases = drain()
+    assert phases["train.sentry"]["parent"] == "epoch.train" and phases["train.sentry"]["n"] == 1
+    assert sentry.take_finalized() == (2, 2) and sentry.skipped_total == 2
+    # nothing kept any more: a second take reads the (empty) flags now
+    assert sentry.take_finalized() == (0, 2) and sentry.skipped_total == 2
+    _sentry_read_back(None)  # no guard, nothing to read

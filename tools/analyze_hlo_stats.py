@@ -1,5 +1,5 @@
-"""Aggregate /tmp/hlo_stats.csv (tools/parse_trace.py output) into a
-per-component time/bytes table.
+"""Aggregate /tmp/hlo_stats.csv (xprof's hlo_stats table of a device
+trace, exported by its converter) into a per-component time/bytes table.
 
 Prints (a) top-K ops by total self time, (b) category rollup, and
 (c) trace-measured HBM bytes per step — self_time x measured BW summed

@@ -154,6 +154,37 @@ def render_phases(events: List[dict]) -> List[str]:
     return lines
 
 
+def render_checkpoints(events: List[dict]) -> List[str]:
+    """Each checkpoint written in an epoch: ``epoch.checkpoint`` and its
+    children (``checkpoint.stream`` with a ``checkpoint.fetch`` and a
+    ``checkpoint.write`` a leaf, ``checkpoint.versioned``,
+    ``checkpoint.pod``, ``checkpoint.meta``), which close after their
+    epoch's event and arrive under ``phases_late``, with the save's
+    ``checkpoint.leaves`` and ``checkpoint.bytes``."""
+    by_epoch = epoch_phases(events)
+    counts: Dict[int, dict] = {}
+    for ev in events:
+        for late in ev.get("phases_late") or []:
+            if late.get("epoch") is not None and late.get("counts"):
+                counts.setdefault(late["epoch"], {}).update(late["counts"])
+    saves = [(ep, ph) for ep, ph in sorted(by_epoch.items()) if "epoch.checkpoint" in ph]
+    if not saves:
+        return []
+    cols = sorted({k for _, ph in saves for k, p in ph.items() if p.get("parent") == "epoch.checkpoint"})
+    subs = sorted({k for _, ph in saves for k, p in ph.items() if p.get("parent") in cols})
+    lines = ["== checkpoints (ms) ==",
+             "  " + " ".join([f"{'ep':>4}", f"{'checkpoint':>10}"] + [f"{c.split('.', 1)[1][:10]:>10}" for c in cols + subs]
+                             + [f"{'covered':>8}", f"{'leaves':>7}", f"{'MB':>9}"])]
+    for ep, ph in saves:
+        total = ph["epoch.checkpoint"]["s"]
+        covered = sum(ph[c]["s"] for c in cols if c in ph) / total if total else 0.0
+        c = counts.get(ep, {})
+        mb = f"{c['checkpoint.bytes'] / 1e6:.3f}" if "checkpoint.bytes" in c else "-"
+        lines.append("  " + " ".join([f"{ep:>4}", f"{total * 1e3:10.1f}"] + [f"{_ms(ph.get(k)):>10}" for k in cols + subs]
+                                     + [f"{100 * covered:7.1f}%", f"{c.get('checkpoint.leaves', '-'):>7}", f"{mb:>9}"]))
+    return lines
+
+
 def _ms(phase: Optional[dict]) -> str:
     return "-" if not phase else f"{phase['s'] * 1e3:.1f}"
 
@@ -212,6 +243,7 @@ def render_report(events: List[dict]) -> str:
                 f"{comp.get('count', '-'):>8}{flag}"
             )
     lines += render_phases(events)
+    lines += render_checkpoints(events)
     ecache = _exec_cache_summary(events)
     if ecache:
         lines.append("== exec cache ==")
