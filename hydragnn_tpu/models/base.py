@@ -178,10 +178,19 @@ class ModelConfig:
         training recalibrates (train/loop.py)."""
         return not self.is_token_stack
 
-    def manifest_block(self, rows: int) -> Dict[str, Any]:
+    def manifest_block(self, rows: int, edges: int = 0, run_align: int = 0,
+                       dtype=None) -> Dict[str, Any]:
         """What the flight record's manifest says of the model beyond its
-        ``config``, for a train batch of ``rows`` node slots: nothing for
-        the conv models."""
+        ``config``, for a train batch of ``rows`` node and ``edges`` edge
+        slots laid out with ``run_align``, computed in ``dtype``: for PNA
+        the conv layers that take the fused gather-statistics kernels
+        (``models/convs.py:gather_presum_layers``); nothing for the other
+        conv models."""
+        if self.model_type == "PNA":
+            from hydragnn_tpu.models.convs import gather_presum_layers
+
+            return {"model": {"gather_presum": gather_presum_layers(
+                self, rows, edges, run_align, dtype)}}
         if not self.is_token_stack:
             return {}
         from hydragnn_tpu.models.token_stack import manifest_block

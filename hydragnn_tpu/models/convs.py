@@ -109,6 +109,34 @@ def _local_kernels(n_rows: int) -> bool:
     return n_rows >= local_min_rows() and local_kernel_active()
 
 
+def gather_presum_layers(cfg, rows: int, edges: int, run_align: int, dtype=None) -> dict:
+    """How many of a PNA model's conv layers run the fused gather-
+    statistics kernels on a batch of ``rows`` node and ``edges`` edge
+    slots laid out with ``run_align``, asking what :class:`PNAConv` asks
+    at trace time: the forward (``gather_stats``) by its gate, the
+    backward (``gather_stats_bwd``) where the kernel's accumulator also
+    fits (``ops/segment_pallas.py:presum_bwd_fits``). Set-up arithmetic
+    for the flight manifest; the loader always emits the sender windows."""
+    from hydragnn_tpu.ops.segment_pallas import (
+        gather_presum_layout_ok,
+        local_kernel_active,
+        presum_bwd_fits,
+    )
+
+    widths = [cfg.input_dim] + [cfg.hidden_dim] * (cfg.num_conv_layers - 1)
+    if cfg.node_head_type == "conv":
+        # a conv head's layers take the encoder's width, then their own
+        hidden = list(cfg.node_dim_headlayers[: cfg.node_num_headlayers])
+        widths += ([cfg.hidden_dim] + hidden) * list(cfg.output_type).count("node")
+    fwd = []
+    if run_align and not cfg.use_edge_attr and local_kernel_active():
+        fwd = [w for w in widths if gather_presum_layout_ok(edges, w, run_align)]
+    dt = jnp.float32 if dtype is None else dtype
+    bwd = [w for w in fwd if presum_bwd_fits(rows, w, run_align, dt)]
+    return {"conv_layers": len(widths), "forward_fused_layers": len(fwd),
+            "backward_fused_layers": len(bwd)}
+
+
 def _fused_active(ctx: EdgeContext) -> bool:
     """Trace-time gate for the fused conv kernel (ops/fused_conv.py):
     the config knob (EdgeContext.fused_conv <- Architecture.fused_conv)
@@ -535,9 +563,11 @@ class PNAConv(nn.Module):
                 # keeps v = bsend[senders] in VMEM and emits the four
                 # statistics at E/K rows directly — the [E, H] v array
                 # and its 4-6 full re-reads (the "fwd reduce_sum" block
-                # of the r05 trace) never touch HBM. Backward regathers
-                # v once and differentiates the identical composition
-                # (ops/segment_pallas.py:_presum_stats_ref). use_edge
+                # of the r05 trace) never touch HBM. The backward is the
+                # closed-form derivative of the identical composition
+                # (ops/segment_pallas.py:_presum_stats_ref), one
+                # gather_stats_bwd kernel where its accumulator fits
+                # VMEM (gather_presum_layers counts those). use_edge
                 # keeps the unfused path: the edge term breaks the
                 # pure-gather structure. fin % 128 == 0 by eligibility,
                 # so no lane split is needed in the slicing below.

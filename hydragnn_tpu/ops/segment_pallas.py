@@ -724,9 +724,26 @@ BW = 128  # table-window rows per DMA, one MXU pass wide. The one-hot
 # Measured against 256 and 528 on v5e: PERF.md section 6, PR 32.
 
 
-def _window_gather_acc(scal_ref, table_hbm, recv_ref, win_vmem, acc_ref, sems):
+def _window_onehot_t(ids, wstart, cstart):
+    """[BW, C] one-hot of a row of ids ([1, C]) against the window whose
+    logical rows are [wstart, wstart + BW) and whose DMA started at the
+    clamped ``cstart``: built along sublanes, so the ids stay a row. An
+    id outside the logical range (another window's, or -1) matches
+    nothing."""
+    in_range = (ids >= wstart) & (ids < wstart + BW)
+    local = jnp.where(in_range, ids - cstart, -1)
+    return jax.lax.broadcasted_iota(jnp.int32, (BW, ids.shape[1]), 0) == local
+
+
+def _window_gather_acc(scal_ref, table_hbm, recv_ref, win_vmem, acc_ref, sems,
+                       ids_as_row=False):
     """Shared windowed-gather loop: accumulate ``table[recv]`` for the
     current grid step's edge chunk into ``acc_ref`` (f32).
+
+    With ``ids_as_row`` (``recv_ref`` [1, C]) the one-hot is built
+    transposed (:func:`_window_onehot_t`), so the ids are never relaid
+    out into a column: 0.33 ms less a call of ``gather_stats_bwd`` on the
+    flagship batch (v5e). The forward kernels keep the column form.
 
     A chunk's ids may SPAN any row range (ids can skip nodes), so the
     chunk loops over as many BW-wide windows as its span needs —
@@ -771,6 +788,15 @@ def _window_gather_acc(scal_ref, table_hbm, recv_ref, win_vmem, acc_ref, sems):
 
         dma(slot, wstart).wait()
         cstart = jnp.minimum(wstart, n_clamp)
+        if ids_as_row:
+            win = win_vmem[slot]
+            onehot_t = _window_onehot_t(recv_ref[:], wstart, cstart).astype(win.dtype)
+            prec = jax.lax.Precision.HIGHEST if win.dtype == jnp.float32 else None
+            acc_ref[:] += jax.lax.dot_general(
+                onehot_t, win, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            )
+            return 0
         local = recv - cstart  # [CE]
         # fold the logical-range check into the index vector (Mosaic
         # cannot broadcast a 1-bit vector into a minor dim): ids outside
@@ -943,10 +969,11 @@ def _bcast_kernel_call(table, ids, interpret, sorted_ids=True):
 #   both  [E/K, 2H] dtype = [group-max(masked v) | group-max(masked -v)]
 #
 # exactly the layouts the downstream E/K segment ops consume. The
-# backward (jax.custom_vjp in :func:`gather_presum_stats`) REGATHERS v
-# once and differentiates the identical jnp composition, so gradient
-# semantics (incl. reshape-max tie handling) match the unfused path by
-# construction; grad_table is the windowed local scatter.
+# backward (jax.custom_vjp in :func:`gather_presum_stats`) is the closed-
+# form derivative of the identical jnp composition, so gradient semantics
+# (incl. reshape-max tie handling) match the unfused path: one kernel,
+# ``gather_stats_bwd``, where its accumulator fits VMEM (below), else
+# the composition of regather, E-level passes and the windowed scatter.
 
 
 def _gather_stats_kernel(scal_ref, table_hbm, recv_ref, mask_ref,
@@ -1074,20 +1101,28 @@ def local_min_rows() -> int:
     return knobs.get_int("HYDRAGNN_LOCAL_MIN_ROWS", 200_000)
 
 
+def gather_presum_layout_ok(n_edges: int, h: int, k_group: int) -> bool:
+    """The shape part of :func:`gather_presum_eligible`: lane-aligned
+    width, chunk divisibility at BOTH granularities (the call hard-
+    asserts them; an ineligible shape must fall back, not crash — e.g.
+    run_align=3 with an accidentally 1024-divisible E_pad, or a
+    hand-tuned HYDRAGNN_BCAST_CE K doesn't divide) and enough edges."""
+    return (
+        h % 128 == 0
+        and n_edges % _BCAST_CE == 0
+        and _BCAST_CE % k_group == 0
+        and n_edges >= local_min_rows()
+    )
+
+
 def gather_presum_eligible(table, ids, win, k_group) -> bool:
     """Kernel-path gate for :func:`gather_presum_stats`: TPU with the
-    local kernels active, host-emitted scatter windows present, lane-
-    aligned width, and chunk divisibility at BOTH granularities (the
-    call hard-asserts them; an ineligible shape must fall back, not
-    crash — e.g. run_align=3 with an accidentally 1024-divisible
-    E_pad, or a hand-tuned HYDRAGNN_BCAST_CE K doesn't divide)."""
+    local kernels active, host-emitted scatter windows present, and
+    :func:`gather_presum_layout_ok`."""
     return (
         win is not None
         and table.ndim == 2
-        and table.shape[1] % 128 == 0
-        and ids.shape[0] % _BCAST_CE == 0
-        and _BCAST_CE % k_group == 0
-        and ids.shape[0] >= local_min_rows()
+        and gather_presum_layout_ok(ids.shape[0], table.shape[1], k_group)
         and local_kernel_active()
     )
 
@@ -1110,11 +1145,45 @@ def _gather_presum_fwd(table, ids, mask, win, num_rows, k_group):
 
 
 def _gather_presum_bwd(num_rows, k_group, res, cots):
-    """Analytic backward: regather v once and assemble grad_v in closed
-    form from the SAVED forward outputs — an earlier jax.vjp-based
-    variant re-ran the whole forward composition inside the pullback
-    (the primal is evaluated by jax.vjp), costing ~2.3 ms/layer of
-    redundant E-level passes on the flagship trace.
+    """Analytic backward from the SAVED forward outputs (an earlier
+    jax.vjp-based variant re-ran the whole forward composition inside
+    the pullback, ~2.3 ms/layer of redundant E-level passes on the
+    flagship trace): the one ``gather_stats_bwd`` kernel where its
+    VMEM-resident accumulator fits (:func:`presum_bwd_fits`), else the
+    composition :func:`_gather_presum_bwd_composed`. Both round grad_v
+    to the table dtype and accumulate the scatter in f32; they differ
+    only in the f32 summation order."""
+    table, ids, mask, win, both_fwd = res
+    g_stats, g_both = cots
+    if local_kernel_active() and presum_bwd_fits(
+        table.shape[0], table.shape[1], k_group, table.dtype
+    ):
+        grad_table = _gather_stats_bwd_call(
+            table, ids, mask, both_fwd, g_stats, g_both, k_group,
+            interpret=_interpret_mode(),
+        ).astype(table.dtype)
+    else:
+        grad_table = _gather_presum_bwd_composed(
+            table, ids, mask, win, both_fwd, g_stats, g_both, num_rows, k_group
+        )
+    f0 = jax.dtypes.float0
+    return (
+        grad_table,
+        jnp.zeros(ids.shape, dtype=f0),
+        jnp.zeros(mask.shape, dtype=f0),
+        jnp.zeros(win.shape, dtype=f0),
+    )
+
+
+gather_presum_stats.defvjp(_gather_presum_fwd, _gather_presum_bwd)
+
+
+def _gather_presum_bwd_composed(table, ids, mask, win, both_fwd, g_stats,
+                                g_both, num_rows, k_group):
+    """The backward as E-level passes: regather v once (bcast kernel),
+    assemble grad_v in closed form, scatter it through the local-window
+    segment sum. The fallback where the fused kernel's accumulator does
+    not fit, and its reference.
 
     Semantics match plain AD of :func:`_presum_stats_ref`: the sum
     terms are linear (+ 2 v g for the square), the max terms follow
@@ -1122,8 +1191,6 @@ def _gather_presum_bwd(num_rows, k_group, res, cots):
     tie counts taken on the FILLED values (masked slots tie only in
     all-masked groups, where the mask factor zeroes them anyway).
     Share math runs f32 (the extremum-VJP contract, segment.py)."""
-    table, ids, mask, win, both_fwd = res
-    g_stats, g_both = cots
     h = table.shape[1]
     m = mask[:, None]
     v = gather_rows_local_fast(table, ids)
@@ -1156,19 +1223,202 @@ def _gather_presum_bwd(num_rows, k_group, res, cots):
         - (tie_n * rep(share_n)).astype(jnp.float32)
     )
     grad_v = jnp.where(m, grad, 0.0).astype(table.dtype)
-    grad_table = segment_sum_local_fast(
-        grad_v, ids, win, num_rows
-    ).astype(table.dtype)
-    f0 = jax.dtypes.float0
-    return (
-        grad_table,
-        jnp.zeros(ids.shape, dtype=f0),
-        jnp.zeros(mask.shape, dtype=f0),
-        jnp.zeros(win.shape, dtype=f0),
+    return segment_sum_local_fast(grad_v, ids, win, num_rows).astype(table.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The gather-statistics backward as one kernel: regather, tie counts,
+# grad_v and the sender scatter in VMEM
+# ---------------------------------------------------------------------------
+#
+# The composition above is five E-level trips through HBM a layer: the
+# regathered v written and read, the tie masks, grad_v written and read
+# back by the scatter. ``gather_stats_bwd`` runs the same formulas over
+# the forward's 1,024-id sender chunks and window plan: gather the chunk's
+# rows (the shared windowed loop, the ids kept a row: v is an exact f32
+# copy), read its E/K rows of the forward's extrema and of both
+# cotangents, form grad_v and round it to the table dtype, then add it
+# into an f32 [rows, H] accumulator that stays in VMEM for the whole
+# (sequential) grid — onehot^T @ grad_v over the gather's own windows,
+# neighbouring chunks adding into the same rows. The accumulator is copied
+# out once. It engages where the accumulator and its buffers fit the
+# kernel's VMEM limit (:func:`presum_bwd_fits`; 64,016 rows x 128 lanes is
+# 32.8 MB). On v5e it is bound by vector work on the chunk's [1,024, 128]
+# f32 arrays, not by HBM (PERF.md section 6, PR 37).
+
+_PRESUM_BWD_VMEM_LIMIT = 64 * 2**20  # the compiler's scoped VMEM for the kernel
+_PRESUM_BWD_SLACK = 16 * 2**20  # the chunk's [C, H] f32 temporaries and Mosaic's own
+
+
+def presum_bwd_fits(n_rows: int, h: int, k_group: int, dtype) -> bool:
+    """The shape rule of the fused gather-statistics backward: what the
+    kernel holds in VMEM for a table of ``n_rows`` x ``h`` in ``dtype``
+    (the f32 accumulator over the padded table; the table windows and the
+    gathered chunk; the double-buffered per-chunk blocks, which are the
+    E/K rows of the forward's extrema and of both cotangents and the
+    chunk's ids and mask rows padded to eight sublanes; the masked ids'
+    row), with room for the chunk's temporaries, fits the kernel's VMEM
+    limit. Where it does not, :func:`_gather_presum_bwd_composed` runs."""
+    it = jnp.dtype(dtype).itemsize
+    n_pad = max(((n_rows + ALIGN - 1) // ALIGN) * ALIGN, BW)
+    groups = _BCAST_CE // k_group
+    acc = n_pad * h * 4
+    gather = 2 * BW * h * it + _BCAST_CE * h * 4
+    blocks = 2 * groups * 2 * h * (it + 4 + it)
+    ids = (2 * 2 + 1) * 8 * _BCAST_CE * 4
+    return acc + gather + blocks + ids + _PRESUM_BWD_SLACK <= _PRESUM_BWD_VMEM_LIMIT
+
+
+def _gather_stats_bwd_kernel(scal_ref, table_hbm, recv_ref, mask_ref, both_ref,
+                             gstats_ref, gboth_ref, out_hbm,
+                             ids_ref, win_vmem, v_ref, acc_ref, sems, out_sem):
+    """Grid step k: chunk k's grad_v, added into the resident
+    accumulator; the last step copies the accumulator out.
+
+    The formulas are :func:`_gather_presum_bwd_composed`'s, in f32 on
+    exact copies (the v5e VPU has no bf16 compare). A masked slot's id
+    becomes -1, which no window matches: its gathered row is 0 (the
+    composition's where(m, v, 0)) and the scatter drops its grad_v
+    (where(m, grad, 0)). The compares see v + fill and fill - v, fill =
+    (1 - m) * finfo.min: the composition's where(m, ±v, finfo.min)
+    exactly. 2 g_sumsq is formed per group (doubling is exact); the
+    shares round to the table dtype as there."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    k = pl.program_id(0)
+
+    @pl.when(k == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    ids_ref[:] = jnp.where(mask_ref[:] > 0, recv_ref[:], -1)
+    _window_gather_acc(scal_ref, table_hbm, ids_ref, win_vmem, v_ref, sems,
+                       ids_as_row=True)
+    v = v_ref[:]  # [C, H]
+    c, h = v.shape
+    dt = win_vmem.dtype
+    groups = both_ref.shape[0]
+    kg = c // groups
+    f32 = jnp.float32
+
+    def rep(a):  # [C/K, H] -> [C, H]: each group's row for its K slots
+        return jnp.broadcast_to(a[:, None, :], (groups, kg, h)).reshape(c, h)
+
+    mf = mask_ref[0, :].astype(f32)[:, None]
+    fill = jnp.broadcast_to((1.0 - mf) * f32(jnp.finfo(dt).min), (c, h))
+    both = both_ref[:].astype(f32)
+    tie_x = ((v + fill) == rep(both[:, :h])).astype(f32)
+    tie_n = ((fill - v) == rep(both[:, h:])).astype(f32)
+    cnt_x = tie_x.reshape(groups, kg, h).sum(axis=1)
+    cnt_n = tie_n.reshape(groups, kg, h).sum(axis=1)
+    g_both = gboth_ref[:].astype(f32)
+    share_x = (g_both[:, :h] / jnp.maximum(cnt_x, 1.0)).astype(dt).astype(f32)
+    share_n = (g_both[:, h:] / jnp.maximum(cnt_n, 1.0)).astype(dt).astype(f32)
+    g_stats = gstats_ref[:].astype(f32)
+    grad_v = (
+        rep(g_stats[:, :h])
+        + v * rep(2.0 * g_stats[:, h:])
+        + tie_x * rep(share_x)
+        - tie_n * rep(share_n)
+    ).astype(dt)
+
+    # scatter: the transpose of the gather's one-hot over the same
+    # windows; each id lies in one window
+    astart = scal_ref[0, k]
+    wcnt = scal_ref[1, k]
+    n_clamp = scal_ref[2, 0]
+    prec = jax.lax.Precision.HIGHEST if dt == jnp.float32 else None
+
+    def window_body(w, _):
+        wstart = astart + w * BW
+        cstart = jnp.minimum(wstart, n_clamp)
+        onehot_t = _window_onehot_t(ids_ref[:], wstart, cstart).astype(dt)
+        rows = pl.ds(pl.multiple_of(cstart, ALIGN), BW)
+        acc_ref[rows, :] += jax.lax.dot_general(
+            onehot_t, grad_v, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32, precision=prec,
+        )
+        return 0
+
+    jax.lax.fori_loop(0, wcnt, window_body, 0)
+
+    @pl.when(k == pl.num_programs(0) - 1)
+    def _write():
+        cp = pltpu.make_async_copy(acc_ref, out_hbm, out_sem.at[0])
+        cp.start()
+        cp.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("k_group", "interpret"))
+def _gather_stats_bwd_call(table, ids, mask, both, g_stats, g_both, k_group,
+                           interpret):
+    """Invoke the fused gather-statistics backward: grad_table [N, H] f32
+    (the caller rounds it to the table dtype, as the composition does).
+    Same divisibility contract as :func:`_gather_stats_call`; the caller
+    checks :func:`presum_bwd_fits`. Jitted, so a program's many call
+    sites of one shape (every layer's backward and every head pull)
+    trace and lower the kernel once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    e = ids.shape[0]
+    n, h = table.shape
+    bce = _BCAST_CE
+    if e % bce != 0 or bce % k_group != 0:
+        raise ValueError(
+            "gather_stats_bwd divisibility contract violated: needs "
+            f"len(ids) % _BCAST_CE == 0 and _BCAST_CE % k_group == 0, got "
+            f"len(ids)={e}, _BCAST_CE={bce}, k_group={k_group}"
+        )
+    n_pad = max(((n + ALIGN - 1) // ALIGN) * ALIGN, BW)
+    if n_pad != n:
+        table = jnp.concatenate(
+            [table, jnp.zeros((n_pad - n, h), table.dtype)], axis=0
+        )
+    recv = ids.astype(jnp.int32)
+    n_chunks = e // bce
+    scal = _window_plan_local(recv, n_pad, n_chunks, ce=bce)
+    mask_i = mask.astype(jnp.int32)
+    vma = _vma_of(recv, table, mask_i, both, g_stats, g_both)
+    table, recv, mask_i, both, g_stats, g_both, scal = (
+        _match_vma(a, vma) for a in (table, recv, mask_i, both, g_stats, g_both, scal)
     )
-
-
-gather_presum_stats.defvjp(_gather_presum_fwd, _gather_presum_bwd)
+    groups = bce // k_group
+    row = lambda k, ptr: (0, k)  # noqa: E731
+    grp = lambda k, ptr: (k, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_chunks,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),  # table
+            pl.BlockSpec((1, bce), row),  # ids
+            pl.BlockSpec((1, bce), row),  # mask
+            pl.BlockSpec((groups, 2 * h), grp),  # forward extrema
+            pl.BlockSpec((groups, 2 * h), grp),  # g_stats
+            pl.BlockSpec((groups, 2 * h), grp),  # g_both
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((1, bce), jnp.int32),
+            pltpu.VMEM((2, BW, h), table.dtype),
+            pltpu.VMEM((bce, h), jnp.float32),
+            pltpu.VMEM((n_pad, h), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_PRESUM_BWD_VMEM_LIMIT)}
+    out = pl.pallas_call(
+        _gather_stats_bwd_kernel,
+        out_shape=jax.ShapeDtypeStruct((n_pad, h), jnp.float32, vma=vma),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        name="gather_stats_bwd",
+        **params,
+    )(scal, table, recv[None, :], mask_i[None, :], both, g_stats, g_both)
+    return out[:n]
 
 
 def _make_partitioned_bcast():

@@ -641,7 +641,10 @@ def _start_record(run: Run, loaders, config, run_config, parallel_block, graftch
         "preempt_handler": bool(preempt and preempt.available),
         "watchdog_stall_s": run.stall_s or None,
         "head_names": run.head_names,
-        **run.plan.cfg.manifest_block(train_loader.pad_nodes),
+        **run.plan.cfg.manifest_block(
+            train_loader.pad_nodes, getattr(train_loader, "pad_edges", 0),
+            getattr(train_loader, "run_align", 0) or 0, run.plan.compute_dtype,
+        ),
         "diagnostics": {
             "enabled": diag is not None,
             "diag_every": diag.every if diag is not None else None,

@@ -94,6 +94,18 @@ def test_gather_stats_kernel_compiles(one_chip, dt):
 
 
 @pytest.mark.parametrize("dt", DTYPES)
+def test_gather_stats_backward_kernel_compiles(one_chip, dt):
+    sp = _sp()
+    fn = lambda t, i, m, b, gs, gb: sp._gather_stats_bwd_call(t, i, m, b, gs, gb, 8, False)
+    shapes = (
+        ((N, H), dt), ((E,), i32), ((E,), b1), ((E // 8, 2 * H), dt),
+        ((E // 8, 2 * H), f32), ((E // 8, 2 * H), dt),
+    )
+    assert sp.presum_bwd_fits(N, H, 8, dt)
+    assert _kernels(fn, one_chip, *shapes) == 1
+
+
+@pytest.mark.parametrize("dt", DTYPES)
 def test_local_window_sum_kernel_compiles(one_chip, dt):
     sp = _sp()
     fn = lambda d, i, w: sp.segment_sum_local_pallas.__wrapped__(d, i, w, N, False)

@@ -695,3 +695,104 @@ def pytest_gather_presum_stats_matches_reference(monkeypatch):
     np.testing.assert_array_equal(
         np.asarray(b_f.astype(jnp.float32)), np.asarray(b_r.astype(jnp.float32))
     )
+
+
+# dtype, rows a sender chunk's ids span and the 128-row windows that
+# needs, table rows, width, whether the fused backward (gather_stats_bwd)
+# engages or the composition runs
+PRESUM_BWD_CASES = [
+    pytest.param(jnp.float32, 100, 1, 512, 128, True, id="f32"),
+    pytest.param(jnp.bfloat16, 100, 1, 512, 128, True, id="bf16"),
+    pytest.param(jnp.bfloat16, 300, 3, 700, 128, True, id="bf16-3-windows"),
+    pytest.param(jnp.float32, 100, 1, 12_400, 1024, False, id="f32-over-budget"),
+]
+
+
+@pytest.mark.parametrize("dt,span,windows,n_rows,h,fused", PRESUM_BWD_CASES)
+def pytest_gather_presum_bwd_kernel_matches_composition(monkeypatch, dt, span, windows, n_rows, h,
+                                                        fused):
+    """The gather-statistics backward: the one-kernel path (regather,
+    tie counts, grad_v and the sender scatter in VMEM) equals the E-level
+    composition it replaces to f32 summation order, and plain AD of
+    ``_presum_stats_ref`` over ``table[ids]``; a table whose accumulator
+    passes the kernel's VMEM budget takes the composition."""
+    from hydragnn_tpu.graph.batch import _block_windows
+    import importlib
+
+    sp = importlib.import_module("hydragnn_tpu.ops.segment_pallas")
+    monkeypatch.setenv("HYDRAGNN_PALLAS", "interpret")
+    monkeypatch.setenv("HYDRAGNN_LOCAL_MIN_ROWS", "0")
+
+    rng = np.random.default_rng(23)
+    ce, K = sp._BCAST_CE, 8
+    e = 2 * ce
+    # chunk c's senders over rows [c * span, (c + 1) * span), unsorted
+    send = np.concatenate(
+        [rng.integers(c * span, (c + 1) * span, ce) for c in range(2)]
+    ).astype(np.int32)
+    assert sp.window_counts(send, n_rows).max() == windows
+    # halves: group extrema tie (a sender repeats within a group) and
+    # every partial sum of grad_v is exact in f32; bf16 tables are never
+    # positive, so many group maxima are 0, what a masked slot gathers
+    table = np.round(rng.normal(size=(n_rows, h)) * 2) / 2
+    if dt == jnp.bfloat16:
+        table = -np.abs(table)
+    table = jnp.asarray(table, jnp.float32).astype(dt)
+    mask = rng.random(e) > 0.25
+    mask[64:72] = False  # a whole K-group masked
+    perm = np.argsort(send, kind="stable").astype(np.int32)
+    win = jnp.asarray(_block_windows(send, perm, n_rows))
+    ids, m = jnp.asarray(send), jnp.asarray(mask)
+    assert sp.presum_bwd_fits(n_rows, h, K, dt) == fused
+
+    (stats, both), pull = jax.vjp(
+        lambda t: sp.gather_presum_stats(t, ids, m, win, n_rows, K), table
+    )
+    g_stats = jnp.asarray(np.round(rng.normal(size=stats.shape) * 4) / 4, jnp.float32)
+    g_both = jnp.asarray(np.round(rng.normal(size=both.shape) * 4) / 4, jnp.float32).astype(dt)
+    jaxpr = str(jax.make_jaxpr(pull)((g_stats, g_both)))
+    assert ("gather_stats_bwd" in jaxpr) == fused
+    (g_kernel,) = pull((g_stats, g_both))
+    g_comp = g_kernel if not fused else sp._gather_presum_bwd_composed(
+        table, ids, m, win, both, g_stats, g_both, n_rows, K
+    )
+    _, ref_pull = jax.vjp(lambda t: sp._presum_stats_ref(t[ids], m, K), table)
+    (g_ref,) = ref_pull((g_stats, g_both))
+    assert g_kernel.dtype == g_comp.dtype == table.dtype
+    kernel, comp, ref = (np.asarray(a.astype(jnp.float32)) for a in (g_kernel, g_comp, g_ref))
+    np.testing.assert_allclose(kernel, comp, rtol=1e-6, atol=1e-6)
+    # plain AD rounds each branch's cotangent to the table dtype and
+    # adds them there: a bf16 ulp of the largest gradient
+    tol = 1e-5 if dt == jnp.float32 else 2.0**-7 * np.abs(ref).max()
+    np.testing.assert_allclose(kernel, ref, rtol=1e-5, atol=tol)
+    assert np.abs(kernel).max() > 0
+
+
+def pytest_gather_presum_layers_counts_fused_pna_layers(monkeypatch):
+    """The set-up counter behind the manifest's ``model.gather_presum``:
+    a PNA model's first conv (one input feature) takes the unfused path,
+    the 128-wide ones take both fused kernels, the backward only where
+    its accumulator fits; no kernel without the local kernels."""
+    from hydragnn_tpu.models.base import ModelConfig
+    from hydragnn_tpu.ops.segment_pallas import _BCAST_CE
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS", "interpret")
+    monkeypatch.setenv("HYDRAGNN_LOCAL_MIN_ROWS", "0")
+    cfg = ModelConfig(
+        model_type="PNA", input_dim=1, hidden_dim=128, output_dim=(1, 3),
+        output_type=("graph", "node"), output_names=("e", "f"), task_weights=(1.0, 1.0),
+        num_conv_layers=4,
+    )
+    edges = 4 * _BCAST_CE
+    block = cfg.manifest_block(2_000, edges, 8, jnp.bfloat16)["model"]["gather_presum"]
+    assert block == {"conv_layers": 4, "forward_fused_layers": 3, "backward_fused_layers": 3}
+    # 200k node slots: the f32 accumulator alone is 102 MB
+    block = cfg.manifest_block(200_000, edges, 8, jnp.bfloat16)["model"]["gather_presum"]
+    assert block == {"conv_layers": 4, "forward_fused_layers": 3, "backward_fused_layers": 0}
+    # conv node heads run PNA layers of their own
+    heads = ModelConfig(**{**cfg.__dict__, "node_head_type": "conv", "node_num_headlayers": 1,
+                           "node_dim_headlayers": (128,)})
+    assert heads.manifest_block(2_000, edges, 8)["model"]["gather_presum"]["backward_fused_layers"] == 5
+    assert cfg.manifest_block(2_000, edges, 0)["model"]["gather_presum"]["forward_fused_layers"] == 0
+    monkeypatch.setenv("HYDRAGNN_PALLAS", "0")
+    assert cfg.manifest_block(2_000, edges, 8)["model"]["gather_presum"]["backward_fused_layers"] == 0
